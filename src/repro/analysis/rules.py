@@ -315,7 +315,6 @@ _HOST_ZONE_FUNCTIONS = {
     "stack_layouts",
     "layout_for",
     "uniform_layout",
-    "prefill_max_slots_arrays",
     "build_plan",
 }
 

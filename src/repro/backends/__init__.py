@@ -8,7 +8,7 @@
     out, page_table = backend.decode(q, k, v, store, plan.layout(l), sparse)
 
 Registered backends: ``"dense"`` (full-attention oracle), ``"reference"``
-(pure jnp), ``"pallas"`` (interpret on CPU, Mosaic on TPU).
+(pure jnp), ``"pallas"`` (interpret off-TPU, Mosaic on TPU).
 """
 from repro.backends.base import (
     AttentionBackend,

@@ -228,23 +228,6 @@ class AttentionPlan:
             offs[l] = lay.offsets[:-1]
         return offs
 
-    @cached_property
-    def prefill_max_slots(self) -> int:
-        """Static per-(query-block, head) slot bound of the sparse prefill
-        kernel (max over layers) — sized once here so the layer scan can
-        pass it as a compile-time constant."""
-        sp = self.sparse
-        return max(
-            (
-                lay.prefill_max_slots(
-                    sp.prefill_block_q, sp.sink_pages, sp.local_pages,
-                    sp.prefill_topk_scale,
-                )
-                for lay in self.layouts
-            ),
-            default=0,
-        )
-
     def get_backend(self) -> "AttentionBackend":
         return get_backend(self.backend)
 
@@ -453,7 +436,6 @@ class AttentionBackend:
         n_valid: Optional[jax.Array] = None,  # [B] live tokens after chunk
         chunk_offset=0,                       # abs pos of q[..., 0, :]
         max_pages_per_block: Optional[int] = None,
-        max_slots: Optional[int] = None,
     ) -> Tuple[jax.Array, Optional[jax.Array]]:
         """Query-block sparse prefill attention: each query block attends
         forced (sink + local/diagonal) blocks plus its top-scored blocks.

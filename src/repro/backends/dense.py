@@ -35,7 +35,7 @@ class DenseBackend(ReferenceBackend):
     def prefill_attention(
         self, q, k, v, score_store, layout, sparse,
         n_valid=None, chunk_offset=0,
-        max_pages_per_block=None, max_slots=None,
+        max_pages_per_block=None,
     ):
         """Full-attention prefill oracle: every query attends its whole
         causal prefix; selection is ignored.  This is what the sparse

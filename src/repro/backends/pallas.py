@@ -1,4 +1,4 @@
-"""Pallas kernel backend: interpret mode on CPU, Mosaic lowering on TPU.
+"""Pallas kernel backend: interpret mode off-TPU, Mosaic kernels on TPU.
 
 Wraps the kernels in :mod:`repro.kernels` behind the backend protocol.
 Store quantization is inherited from the shared path (so page tables match
@@ -32,7 +32,8 @@ class PallasBackend(AttentionBackend):
     name = "pallas"
 
     def __init__(self, interpret: Optional[bool] = None):
-        #: None -> auto (interpret everywhere but TPU), resolved per call.
+        #: None -> auto (interpret everywhere but TPU), resolved per call;
+        #: a chip run pins False so nothing falls back to the interpreter.
         self.interpret = interpret
 
     def _interp(self) -> bool:
@@ -83,7 +84,7 @@ class PallasBackend(AttentionBackend):
     def prefill_attention(
         self, q, k, v, score_store, layout, sparse,
         n_valid=None, chunk_offset=0,
-        max_pages_per_block=None, max_slots=None,
+        max_pages_per_block=None,
     ) -> Tuple[jax.Array, Optional[jax.Array]]:
         """Query-block sparse flash prefill in ONE Pallas launch
         (:mod:`repro.kernels.sparse_prefill`); the base-class jnp oracle
@@ -104,7 +105,6 @@ class PallasBackend(AttentionBackend):
                 chunk_offset=chunk_offset,
                 max_pages_per_block=max_pages_per_block
                 or sparse.max_block_size // sparse.page_size,
-                max_slots=max_slots,
                 interpret=self._interp(),
             )
 

@@ -215,24 +215,6 @@ class RaggedLayout:
         """[n_heads] K_h — blocks each head selects in the fused kernel."""
         return np.asarray(self.top_k, dtype=np.int32)
 
-    # -- sparse-prefill query-block metadata ---------------------------------
-
-    def prefill_max_slots(
-        self,
-        block_q: int,
-        sink_pages: int,
-        local_pages: int,
-        topk_scale: float,
-    ) -> int:
-        """Static upper bound on blocks any (query-block, head) cell attends
-        (sizes the kernel's per-slot descriptor scratch).  Delegates to
-        :func:`prefill_max_slots_arrays` — the ONE definition of the bound,
-        shared with the LayoutArrays path."""
-        return prefill_max_slots_arrays(
-            self.block_sizes, self.top_k, self.n_blocks, self.page_size,
-            block_q, sink_pages, local_pages, topk_scale,
-        )
-
     # -- stats ----------------------------------------------------------------
 
     @property
@@ -247,36 +229,6 @@ class RaggedLayout:
         """Centroid-count overhead relative to a uniform block size."""
         uniform_rows = self.n_heads * (self.context_len // uniform_block)
         return self.total_centroid_rows_unpadded / uniform_rows
-
-
-def prefill_max_slots_arrays(
-    bsz, top_k, n_blocks, page_size, block_q, sink_pages, local_pages,
-    topk_scale,
-) -> int:
-    """Static slot bound of the sparse prefill kernel: scored top-K
-    (``ceil(K_h * topk_scale)``) plus the forced union (sink blocks + every
-    block overlapping the local window / causal diagonal of a query block).
-    The safety bound guarding the kernel's slot-descriptor reads — keep it
-    the single definition (both :meth:`RaggedLayout.prefill_max_slots` and
-    the ops-layer LayoutArrays path delegate here)."""
-    bsz = np.asarray(bsz)
-    n_blocks = np.asarray(n_blocks)
-    # float32 on purpose: the kernel's runtime k_sel is computed with
-    # jnp.float32 ceil, and the bound must round identically (f64 ceil can
-    # be one SMALLER when f32 rounds x*scale up across an integer).
-    ks = np.minimum(
-        n_blocks,
-        np.maximum(
-            1,
-            np.ceil(
-                np.asarray(top_k, np.float32) * np.float32(topk_scale)
-            ).astype(np.int64),
-        ),
-    )
-    sink_tok = sink_pages * page_size
-    n_sink = -(-sink_tok // bsz) if sink_tok else np.zeros_like(bsz)
-    n_local = (local_pages * page_size + block_q) // bsz + 1
-    return int(min(np.max(ks + n_sink + n_local), np.max(n_blocks)))
 
 
 def uniform_layout(

@@ -6,7 +6,7 @@ independent — the natural partitioning for a ``(data, model)`` serving mesh
 is therefore *batch over data, kv heads over model*.  GSPMD cannot
 partition a ``pallas_call`` (it is an opaque custom call and would be
 replicated, all-gathering the sharded KV pool every step), so this module
-wraps the kernel entry points in :func:`jax.experimental.shard_map.shard_map`:
+wraps the kernel entry points in :func:`jax.shard_map`:
 every device launches the SAME kernel over only its own batch rows and kv
 heads.
 
@@ -34,9 +34,9 @@ after the kernel (``with_sharding_constraint`` to a head-replicated spec).
 Downstream reductions over heads (``out_project``) therefore see the full
 head axis in the original order, making sharded serving token-identical to
 the single-device path (the acceptance oracle in
-``tests/test_distributed.py``).  Static kernel bounds (``seg``/``k_max``/
-``p_sel``/``prefill_max_slots``) are global maxima and identical on every
-shard, so all devices compile the same kernel.
+``tests/test_distributed.py``).  Static kernel bounds (``seg``/``p_sel``)
+are global maxima and identical on every shard, so all devices compile the
+same kernel.
 """
 from __future__ import annotations
 
@@ -44,7 +44,6 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.sparse_attention import as_paged
@@ -225,7 +224,7 @@ def fused_decode(
 
     qs = P(ba, ha, None)
     kvs = P(ba, ha, None, None, None)
-    out, table, valid = shard_map(
+    out, table, valid = jax.shard_map(
         local_call,
         mesh=mesh,
         in_specs=(
@@ -235,7 +234,7 @@ def fused_decode(
             P(ba),
         ),
         out_specs=(qs, P(ba, ha, None), P(ba, ha, None)),
-        check_rep=False,
+        check_vma=False,
     )(q, rq, kp, vp, store, la, seq_len)
     # head-gather for bitwise-identical downstream reductions (out_project
     # sums over the FULL head axis in the single-device order).
@@ -264,7 +263,6 @@ def sparse_prefill(
     n_valid: Optional[jax.Array] = None,
     chunk_offset=0,
     max_pages_per_block: Optional[int] = None,
-    max_slots: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Mesh-partitioned :func:`repro.kernels.ops.sparse_prefill` — same
@@ -288,7 +286,6 @@ def sparse_prefill(
             block_q=block_q, topk_scale=topk_scale,
             n_valid=n_valid, chunk_offset=chunk_offset,
             max_pages_per_block=max_pages_per_block,
-            max_slots=max_slots,
             interpret=interpret,
         )
     mesh = ctx.mesh
@@ -305,13 +302,12 @@ def sparse_prefill(
             block_q=block_q, topk_scale=topk_scale,
             n_valid=nv_l, chunk_offset=co_l,
             max_pages_per_block=max_pages_per_block,
-            max_slots=max_slots,
             interpret=interpret,
         )
 
     qs = P(ba, ha, None, None)
     kvs = P(ba, ha, None, None, None)
-    out, n_att = shard_map(
+    out, n_att = jax.shard_map(
         local_call,
         mesh=mesh,
         in_specs=(
@@ -322,7 +318,7 @@ def sparse_prefill(
             P(),
         ),
         out_specs=(qs, P(ba, ha, None)),
-        check_rep=False,
+        check_vma=False,
     )(q, rq, kp, vp, score_store, la, n_valid, chunk_offset)
     out = jax.lax.with_sharding_constraint(
         out, NamedSharding(mesh, P(ba, None, None, None))

@@ -8,5 +8,7 @@
 - block_centroid    fused rank-key pooling (cache build)
 
 ``ops`` holds the jit'd public wrappers; ``ref`` the pure-jnp oracles.
-All kernels validate in interpret mode on CPU; TPU (v5e) is the target.
+All kernels validate in interpret mode on CPU; the serving path's kernels
+(fused decode, sparse prefill, staged estimation / paged attention) also
+compile for TPU v5e (``tests/test_tpu_compile.py``).
 """

@@ -39,60 +39,49 @@ def dequant_rows(codes, scale, zero, bits: int, symmetric: bool):
     decode kernel so their numerics cannot drift apart."""
     if bits == 0:
         return codes.astype(jnp.float32)
+    # widen to int32 first: Mosaic has no uint8 -> f32 cast
+    c = codes.astype(jnp.int32)
     if bits == 4:
-        lo = (codes & jnp.uint8(0xF)).astype(jnp.float32)
-        hi = ((codes >> 4) & jnp.uint8(0xF)).astype(jnp.float32)
+        lo = (c & 0xF).astype(jnp.float32)
+        hi = ((c >> 4) & 0xF).astype(jnp.float32)
         q = jnp.concatenate([lo, hi], axis=-1)             # [R, Dp]
     else:
-        q = codes.astype(jnp.float32)
+        q = c.astype(jnp.float32)
     if symmetric:
         half = 2.0 ** (bits - 1) - 1.0
         return (q - half) * scale
     return q * scale + zero
 
 
-def _score_kernel_int4(
+def group_scores(rq, rk):
+    """Block scores of one GQA group: ``rq [g, Dp]`` x ``rk [R, Dp]`` ->
+    ``[1, R]`` (max over the group).  Lane-major so the row feeds the
+    in-kernel selection directly; shared by every estimation site so the
+    staged, fused and prefill paths score identically."""
+    s = jax.lax.dot_general(
+        rq, rk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )                                                      # [g, R]
+    return jnp.max(s, axis=0, keepdims=True)
+
+
+def _score_kernel_quantized(
     tile_head_ref,            # scalar prefetch [n_tiles]
-    codes_ref,                # [1, R, Dp//2] uint8
-    scale_ref,                # [1, 1, Dp] f32
-    zero_ref,                 # [1, 1, Dp] f32
-    rq_ref,                   # [1, g, Dp] f32
-    out_ref,                  # [1, R]
+    codes_ref,                # [1, R, Cw] uint8
+    scale_ref,                # [1, 1, 1, Dp] f32
+    zero_ref,                 # [1, 1, 1, Dp] f32
+    rq_ref,                   # [1, 1, g, Dp] f32
+    out_ref,                  # [1, 1, R]
     *, symmetric: bool, bits: int,
 ):
     rk = dequant_rows(
-        codes_ref[0], scale_ref[0], zero_ref[0], bits, symmetric
+        codes_ref[0], scale_ref[0, 0], zero_ref[0, 0], bits, symmetric
     )                                                      # [R, Dp]
-    rq = rq_ref[0, 0]                                      # [g, Dp]
-    scores = jax.lax.dot_general(
-        rk, rq, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )                                                      # [R, g]
-    out_ref[0] = jnp.max(scores, axis=-1)
+    out_ref[0] = group_scores(rq_ref[0, 0], rk)
 
 
-def _score_kernel_f32(
-    tile_head_ref, rk_ref, rq_ref, out_ref,
-):
+def _score_kernel_f32(tile_head_ref, rk_ref, rq_ref, out_ref):
     rk = rk_ref[0].astype(jnp.float32)                     # [R, Dp]
-    rq = rq_ref[0, 0].astype(jnp.float32)                  # [g, Dp]
-    scores = jax.lax.dot_general(
-        rk, rq, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    out_ref[0] = jnp.max(scores, axis=-1)
-
-
-def _score_kernel_int8(
-    tile_head_ref, codes_ref, scale_ref, zero_ref, rq_ref, out_ref,
-    *, symmetric: bool, bits: int,
-):
-    rk = dequant_rows(
-        codes_ref[0], scale_ref[0], zero_ref[0], bits, symmetric
-    )
-    rq = rq_ref[0, 0]
-    scores = jax.lax.dot_general(
-        rk, rq, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    out_ref[0] = jnp.max(scores, axis=-1)
+    out_ref[0] = group_scores(rq_ref[0, 0].astype(jnp.float32), rk)
 
 
 @functools.partial(
@@ -120,36 +109,34 @@ def centroid_scores_quantized(
     assert tile_head_arr.shape == (n_tiles,), (tile_head_arr.shape, n_tiles)
     rq3 = rq.reshape(B, n_kv, g, Dp)
 
-    if bits == 4:
-        kernel = functools.partial(
-            _score_kernel_int4, symmetric=symmetric, bits=bits
-        )
-        code_spec = pl.BlockSpec(
-            (1, tile_rows, Dp // 2), lambda b, t, th: (b, t, 0)
-        )
-    else:
-        kernel = functools.partial(
-            _score_kernel_int8, symmetric=symmetric, bits=bits
-        )
-        code_spec = pl.BlockSpec((1, tile_rows, Dp), lambda b, t, th: (b, t, 0))
-
+    kernel = functools.partial(
+        _score_kernel_quantized, symmetric=symmetric, bits=bits
+    )
+    cw = codes.shape[-1]
+    # per-head params get a unit axis so their block's last two dims equal
+    # the array's (the TPU (8, 128) block rule); the score row likewise.
+    param_spec = pl.BlockSpec((1, 1, 1, Dp), lambda b, t, th: (b, th[t], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, n_tiles),
         in_specs=[
-            code_spec,
-            pl.BlockSpec((1, 1, Dp), lambda b, t, th: (b, th[t], 0)),
-            pl.BlockSpec((1, 1, Dp), lambda b, t, th: (b, th[t], 0)),
+            pl.BlockSpec((1, tile_rows, cw), lambda b, t, th: (b, t, 0)),
+            param_spec,
+            param_spec,
             pl.BlockSpec((1, 1, g, Dp), lambda b, t, th: (b, th[t], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, tile_rows), lambda b, t, th: (b, t)),
+        out_specs=pl.BlockSpec((1, 1, tile_rows), lambda b, t, th: (b, 0, t)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, total_rows), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, 1, total_rows), jnp.float32),
         interpret=interpret,
-    )(tile_head_arr, codes, scale, zero, rq3)
+    )(
+        tile_head_arr, codes,
+        scale.reshape(B, n_kv, 1, Dp), zero.reshape(B, n_kv, 1, Dp), rq3,
+    )
+    return out.reshape(B, total_rows)
 
 
 @functools.partial(
@@ -176,11 +163,12 @@ def centroid_scores_f32(
             pl.BlockSpec((1, tile_rows, Dp), lambda b, t, th: (b, t, 0)),
             pl.BlockSpec((1, 1, g, Dp), lambda b, t, th: (b, th[t], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, tile_rows), lambda b, t, th: (b, t)),
+        out_specs=pl.BlockSpec((1, 1, tile_rows), lambda b, t, th: (b, 0, t)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _score_kernel_f32,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, total_rows), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, 1, total_rows), jnp.float32),
         interpret=interpret,
     )(tile_head_arr, rank_keys, rq3)
+    return out.reshape(B, total_rows)

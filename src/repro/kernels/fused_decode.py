@@ -7,20 +7,23 @@ Per ``(batch, kv-head)`` grid cell the kernel:
 1. **Scores** the head's quantized centroid segment in-register: the packed
    INT8/INT4 codes are DMA'd straight from the flattened ragged store (Dp/2
    bytes per centroid for INT4), dequantized in VREGs with the per-(head,
-   channel) affine params, and hit the MXU against the GQA rank-query group.
-   Neither a dequantized store nor the padded ``[B, n_kv, max_blocks]``
-   score tensor is ever materialized in HBM.
+   channel) affine params, and hit the MXU against the GQA rank-query group
+   (a lane-major ``[1, SEG]`` score row).  Neither a dequantized store nor
+   the padded ``[B, n_kv, max_blocks]`` score tensor is ever materialized
+   in HBM.
 2. **Selects** the head's top ``K_h`` blocks in-register via the exact
    k-th-value threshold (32-step binary search over the sortable-integer
-   encoding of f32 — same math as :mod:`repro.kernels.topk_threshold`),
+   encoding of f32 — :func:`repro.kernels.topk_threshold.topk_row_mask`),
    with the staged path's causal masking and sink/local pinning applied to
    the scores first.  Tie handling (index order) reproduces ``lax.top_k``'s
    selected SET exactly, so the fused and staged paths attend over
    identical tokens.
-3. **Attends** flash-style over ONLY the selected blocks: a double-buffered
-   DMA loop streams each block's pages from the paged KV pool in HBM into
-   VMEM while the previous block is on the MXU; the running (m, l, acc)
-   softmax state lives in registers.
+3. **Attends** flash-style over ONLY the selected blocks, walking them in
+   index order (each next block is one masked min over the selection row,
+   so DMA addresses stay scalars and the selection is never compacted): a
+   double-buffered DMA loop streams each block's pages from the paged KV
+   pool in HBM into VMEM while the previous block is on the MXU; the
+   running (m, l, acc) softmax state lives in registers.
 
 Raggedness rides a precomputed grid descriptor — per-head flat-row offsets,
 real block counts, ``K_h``, block sizes and pages-per-block — delivered via
@@ -28,8 +31,9 @@ scalar prefetch (``RaggedLayout.row_offsets_arr`` & co., stacked per layer
 in :class:`repro.core.stacked.LayoutArrays`), so heterogeneous head groups
 share one launch instead of one per distinct block size.
 
-Interpret mode on CPU (this container) validates the numerics; the same
-call lowers to Mosaic on TPU.
+Interpret mode validates the numerics on CPU; ``tests/test_tpu_compile.py``
+compiles the kernel for a v5e chip at llama3.2-3b widths, and
+``chip_smoke.py`` runs it there.
 """
 from __future__ import annotations
 
@@ -41,8 +45,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.centroid_score import dequant_rows
-from repro.kernels.topk_threshold import _to_sortable
+from repro.kernels.centroid_score import dequant_rows, group_scores
+from repro.kernels.topk_threshold import next_selected, topk_row_mask
 
 NEG_INF = -1e30
 POS_INF = 1e30
@@ -58,26 +62,25 @@ def _fused_decode_kernel(
     seq_len_ref,               # [B] int32
     # -- array inputs
     codes_ref,                 # [B, R, Cw] store codes (HBM/ANY)
-    scale_ref,                 # [1, 1, Dp] f32
-    zero_ref,                  # [1, 1, Dp] f32
+    scale_ref,                 # [1, 1, 1, Dp] f32
+    zero_ref,                  # [1, 1, 1, Dp] f32
     rq_ref,                    # [1, 1, g, Dp] f32 rank queries
     q_ref,                     # [1, 1, g, D]
     k_ref,                     # [B, H, n_pages, ps, D] paged pool (HBM/ANY)
     v_ref,                     # [B, H, n_pages, ps, D] (HBM/ANY)
     # -- outputs
     o_ref,                     # [1, 1, g, D]
-    tbl_ref,                   # [1, 1, P_sel] int32
-    vld_ref,                   # [1, 1, P_sel] int32
+    tbl_ref,                   # [1, 1, 1, P_sel] int32
+    vld_ref,                   # [1, 1, 1, P_sel] int32
     # -- scratch
     codes_scr,                 # VMEM [SEG, Cw]
     kbuf, vbuf,                # VMEM [2, ppb_max, ps, D] double buffers
-    slot_scr,                  # VMEM [K_max, 128] int32 per-slot descriptors
     csem,                      # DMA sem (codes)
     sem,                       # DMA sems [2, 2] (k/v double buffer)
     *,
-    bits: int, symmetric: bool, seg: int, k_max: int, p_sel: int,
+    bits: int, symmetric: bool, seg: int, p_sel: int,
     page_size: int, ppb_max: int, n_pages: int, total_rows: int,
-    sink_pages: int, local_pages: int, scale_qk: float,
+    sink_pages: int, local_pages: int, scale_qk: float, row_align: int,
 ):
     b = pl.program_id(0)
     h = pl.program_id(1)
@@ -92,7 +95,7 @@ def _fused_decode_kernel(
     # SEG-row window (static size) with a dynamic start; when the segment is
     # shorter than SEG the window is clamped left, and rows before the
     # segment (adj) belong to the previous head and are masked below.
-    start = jnp.minimum(row_off, total_rows - seg)
+    start = pl.multiple_of(jnp.minimum(row_off, total_rows - seg), row_align)
     adj = row_off - start
     cdma = pltpu.make_async_copy(
         codes_ref.at[b, pl.ds(start, seg)], codes_scr, csem
@@ -100,18 +103,11 @@ def _fused_decode_kernel(
     cdma.start()
     cdma.wait()
     rk = dequant_rows(
-        codes_scr[...], scale_ref[0], zero_ref[0], bits, symmetric
+        codes_scr[...], scale_ref[0, 0], zero_ref[0, 0], bits, symmetric
     )                                                      # [SEG, Dp]
-    rq = rq_ref[0, 0]                                      # [g, Dp]
-    s = jnp.max(
-        jax.lax.dot_general(
-            rk, rq, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ),
-        axis=-1,
-    )                                                      # [SEG]
+    s = group_scores(rq_ref[0, 0], rk)                     # [1, SEG]
 
-    jloc = jnp.arange(seg, dtype=jnp.int32) - adj          # block id in head
+    jloc = jax.lax.broadcasted_iota(jnp.int32, (1, seg), 1) - adj
     starts_tok = jloc * bsz
     in_seg = (jloc >= 0) & (jloc < nblk)
     valid = in_seg & (starts_tok < sl)
@@ -126,59 +122,24 @@ def _fused_decode_kernel(
         s = jnp.where(pin, POS_INF, s)
 
     # ---- phase 2: exact top-K_h selection in-register ----------------------
-    u = _to_sortable(s)                                    # [SEG] uint32
-
-    def bit_step(i, t):
-        cand = t | (jnp.uint32(1) << (jnp.uint32(31) - jnp.uint32(i)))
-        cnt = jnp.sum((u >= cand).astype(jnp.int32))
-        return jnp.where(cnt >= k_sel, cand, t)
-
-    thr = jax.lax.fori_loop(0, 32, bit_step, jnp.uint32(0))
-    n_gt = jnp.sum((u > thr).astype(jnp.int32))
-    is_tie = (u == thr).astype(jnp.int32)
-    tie_rank = jnp.cumsum(is_tie) - is_tie                 # exclusive
-    selected = (u > thr) | (
-        (is_tie > 0) & (tie_rank < k_sel - n_gt)
-    )                                                      # exactly K_h set
-    sel_rank = jnp.cumsum(selected.astype(jnp.int32))      # inclusive
-
-    # compact the selected block ids into K_max slots (one-hot expansion —
-    # slot i holds the (i+1)-th selected block in index order)
-    slot_ids = jnp.arange(k_max, dtype=jnp.int32)
-    onehot = selected[None, :] & (sel_rank[None, :] == slot_ids[:, None] + 1)
-    blk = jnp.sum(jnp.where(onehot, jloc[None, :], 0), axis=1)      # [K_max]
-    s_sel = jnp.sum(jnp.where(onehot, s[None, :], 0.0), axis=1)
-    slot_live = (slot_ids < k_sel) & (s_sel > NEG_INF / 2)
-
-    # per-slot DMA descriptors: page start (clamped so a full ppb_max-page
-    # window stays in bounds) and the block's token start for masking
-    pstart = jnp.clip(blk * ppb, 0, n_pages - ppb_max)
-    tok0 = blk * bsz
-    slot_scr[...] = jnp.concatenate(
-        [
-            pstart[:, None],
-            tok0[:, None],
-            jnp.zeros((k_max, 126), jnp.int32),
-        ],
-        axis=1,
-    )
-
-    # ---- emit the page table (parity instrumentation / staged interop) ----
-    pg_ids = jnp.arange(p_sel, dtype=jnp.int32)
-    pg_slot = pg_ids // ppb                                # [P_sel]
-    within = pg_ids - pg_slot * ppb
-    oh2 = pg_slot[:, None] == slot_ids[None, :]            # [P_sel, K_max]
-    blk_of = jnp.sum(jnp.where(oh2, blk[None, :], 0), axis=1)
-    live_of = jnp.sum(jnp.where(oh2, slot_live[None, :], False), axis=1)
-    tbl_ref[0, 0] = jnp.clip(blk_of * ppb + within, 0, n_pages - 1)
-    vld_ref[0, 0] = live_of.astype(jnp.int32)
+    selected = topk_row_mask(s, k_sel)                     # [1, SEG], K_h set
 
     # ---- phase 3: flash attention over the selected blocks -----------------
+    # Slot i attends the (i+1)-th selected block in index order; the walk
+    # finds each next block with one masked min, so the selection is never
+    # compacted and the DMA addresses stay scalars.
     q = q_ref[0, 0].astype(jnp.float32)                    # [g, D]
     g, D = q.shape
     W = ppb_max * page_size
+    pg_ids = jax.lax.broadcasted_iota(jnp.int32, (1, p_sel), 1)
+    tok_ids = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
 
-    def kv_dma(slot, pg):
+    def page_start(p):
+        # clamped so a full ppb_max-page window stays in bounds
+        return jnp.clip((p - adj) * ppb, 0, n_pages - ppb_max)
+
+    def kv_dma(slot, p):
+        pg = page_start(p)
         return (
             pltpu.make_async_copy(
                 k_ref.at[b, h, pl.ds(pg, ppb_max)], kbuf.at[slot],
@@ -190,62 +151,78 @@ def _fused_decode_kernel(
             ),
         )
 
-    # warm-up: first block's pages in flight before the loop
-    dk0, dv0 = kv_dma(0, slot_scr[0, 0])
+    # warm-up: first block's pages in flight before the loop (K_h >= 1)
+    p0 = next_selected(selected, -1)
+    dk0, dv0 = kv_dma(0, p0)
     dk0.start()
     dv0.start()
 
     def body(i, carry):
-        m, l, acc = carry
+        m, l, acc, p, tbl, vld = carry
         slot = i % 2
-        pg_i = slot_scr[i, 0]
-        t0 = slot_scr[i, 1]
+        p_next = next_selected(selected, p)
 
         @pl.when(i + 1 < k_sel)
         def _prefetch_next():
-            nslot = (i + 1) % 2
-            pg_n = slot_scr[jnp.minimum(i + 1, k_max - 1), 0]
-            dk, dv = kv_dma(nslot, pg_n)
+            dk, dv = kv_dma(1 - slot, p_next)
             dk.start()
             dv.start()
 
-        dk, dv = kv_dma(slot, pg_i)
+        dk, dv = kv_dma(slot, p)
         dk.wait()
         dv.wait()
         kf = kbuf[slot].reshape(W, D).astype(jnp.float32)
         vf = vbuf[slot].reshape(W, D).astype(jnp.float32)
 
-        pos = pg_i * page_size + jnp.arange(W, dtype=jnp.int32)
+        blk = p - adj
+        t0 = blk * bsz
+        pos = page_start(p) * page_size + tok_ids          # [1, W]
         ok = (pos >= t0) & (pos < t0 + bsz) & (pos < sl)
         logits = jax.lax.dot_general(
             q, kf, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale_qk                                       # [g, W]
-        logits = jnp.where(ok[None, :], logits, NEG_INF)
+        logits = jnp.where(ok, logits, NEG_INF)
 
         m_cur = jnp.max(logits, axis=-1, keepdims=True)
         m_new = jnp.maximum(m, m_cur)
         alpha = jnp.exp(m - m_new)
-        p = jnp.exp(logits - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        pr = jnp.exp(logits - m_new)
+        l_new = l * alpha + jnp.sum(pr, axis=-1, keepdims=True)
         acc_new = acc * alpha + jax.lax.dot_general(
-            p, vf, (((1,), (0,)), ((), ())),
+            pr, vf, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        return m_new, l_new, acc_new
+        # page table (parity instrumentation / staged interop): this
+        # slot's pages are table entries [i*ppb, (i+1)*ppb)
+        within = pg_ids - i * ppb
+        mine = (within >= 0) & (within < ppb)
+        tbl = jnp.where(
+            mine, jnp.clip(blk * ppb + within, 0, n_pages - 1), tbl
+        )
+        live = (blk >= 0) & (blk < nblk) & (t0 < sl)
+        vld = jnp.where(mine, live.astype(jnp.int32), vld)
+        return m_new, l_new, acc_new, p_next, tbl, vld
 
-    m0 = jnp.full((g, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((g, 1), jnp.float32)
-    acc0 = jnp.zeros((g, D), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, k_sel, body, (m0, l0, acc0))
+    carry = (
+        jnp.full((g, 1), NEG_INF, jnp.float32),
+        jnp.zeros((g, 1), jnp.float32),
+        jnp.zeros((g, D), jnp.float32),
+        p0,
+        jnp.zeros((1, p_sel), jnp.int32),
+        jnp.zeros((1, p_sel), jnp.int32),
+    )
+    _, l, acc, _, tbl, vld = jax.lax.fori_loop(0, k_sel, body, carry)
     o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    tbl_ref[0, 0] = tbl
+    vld_ref[0, 0] = vld
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
         "page_size", "ppb_max", "bits", "symmetric",
-        "sink_pages", "local_pages", "seg", "k_max", "p_sel", "interpret",
+        "sink_pages", "local_pages", "seg", "p_sel", "tile_rows", "interpret",
     ),
 )
 def fused_decode(
@@ -270,8 +247,8 @@ def fused_decode(
     sink_pages: int,
     local_pages: int,
     seg: int,
-    k_max: int,
     p_sel: int,
+    tile_rows: int,
     interpret: bool = False,
 ):
     """-> (out [B, n_q, D], page_table [B, H, P_sel] i32, page_valid bool).
@@ -294,7 +271,6 @@ def fused_decode(
         bits=bits,
         symmetric=symmetric,
         seg=seg,
-        k_max=k_max,
         p_sel=p_sel,
         page_size=page_size,
         ppb_max=ppb_max,
@@ -303,29 +279,33 @@ def fused_decode(
         sink_pages=sink_pages,
         local_pages=local_pages,
         scale_qk=1.0 / float(np.sqrt(D)),
+        row_align=tile_rows,
     )
+    # unit axes keep every VMEM block's last two dims equal to the array's
+    # (the TPU (8, 128) block rule) for the per-head params and the table.
+    param_spec = pl.BlockSpec((1, 1, 1, Dp), lambda b, h, *_: (b, h, 0, 0))
+    row_spec = pl.BlockSpec((1, 1, 1, p_sel), lambda b, h, *_: (b, h, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=(B, n_kv),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),          # codes
-            pl.BlockSpec((1, 1, Dp), lambda b, h, *_: (b, h, 0)),
-            pl.BlockSpec((1, 1, Dp), lambda b, h, *_: (b, h, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),          # codes
+            param_spec,
+            param_spec,
             pl.BlockSpec((1, 1, g, Dp), lambda b, h, *_: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, g, D), lambda b, h, *_: (b, h, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),          # k pages
-            pl.BlockSpec(memory_space=pltpu.ANY),          # v pages
+            pl.BlockSpec(memory_space=pl.ANY),          # k pages
+            pl.BlockSpec(memory_space=pl.ANY),          # v pages
         ],
         out_specs=[
             pl.BlockSpec((1, 1, g, D), lambda b, h, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, p_sel), lambda b, h, *_: (b, h, 0)),
-            pl.BlockSpec((1, 1, p_sel), lambda b, h, *_: (b, h, 0)),
+            row_spec,
+            row_spec,
         ],
         scratch_shapes=[
             pltpu.VMEM((seg, codes.shape[-1]), codes.dtype),
             pltpu.VMEM((2, ppb_max, page_size, D), k_pages.dtype),
             pltpu.VMEM((2, ppb_max, page_size, D), v_pages.dtype),
-            pltpu.VMEM((k_max, 128), jnp.int32),
             pltpu.SemaphoreType.DMA(()),
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
@@ -335,9 +315,10 @@ def fused_decode(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, n_kv, g, D), q.dtype),
-            jax.ShapeDtypeStruct((B, n_kv, p_sel), jnp.int32),
-            jax.ShapeDtypeStruct((B, n_kv, p_sel), jnp.int32),
+            jax.ShapeDtypeStruct((B, n_kv, 1, p_sel), jnp.int32),
+            jax.ShapeDtypeStruct((B, n_kv, 1, p_sel), jnp.int32),
         ],
+        name="absparse_fused_decode",
         interpret=interpret,
     )(
         row_off.astype(jnp.int32),
@@ -347,11 +328,13 @@ def fused_decode(
         ppb.astype(jnp.int32),
         seq_len.astype(jnp.int32),
         codes,
-        scale.astype(jnp.float32),
-        zero.astype(jnp.float32),
+        scale.astype(jnp.float32).reshape(B, n_kv, 1, Dp),
+        zero.astype(jnp.float32).reshape(B, n_kv, 1, Dp),
         rq4,
         q4,
         k_pages,
         v_pages,
     )
+    table = table.reshape(B, n_kv, p_sel)
+    valid = valid.reshape(B, n_kv, p_sel)
     return out.reshape(B, n_q, D), table, valid > 0

@@ -1,9 +1,13 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 These are the execute-stage primitives consumed by the ``"pallas"``
-attention backend (:mod:`repro.backends.pallas`).  On CPU (this container)
-every kernel runs in ``interpret=True`` mode — the kernel body executes in
-Python for correctness validation; on TPU the same calls lower to Mosaic.
+attention backend (:mod:`repro.backends.pallas`).  Off-TPU every kernel
+runs in ``interpret=True`` mode — the kernel body executes as jnp for
+correctness validation.  On TPU the serving path's kernels (fused decode,
+sparse prefill, staged estimation and paged attention) compile to Mosaic:
+``tests/test_tpu_compile.py`` compiles them for v5e and ``chip_smoke.py``
+runs them there.  ``flash_attention``, ``topk_threshold`` and the pooling
+kernel are off that path and have not been compiled for a chip.
 
 Store construction and orchestration live in :mod:`repro.backends`; the
 unified :class:`repro.backends.CentroidStore` byte layout (flattened ragged
@@ -17,7 +21,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.ragged import RaggedLayout, prefill_max_slots_arrays
+from repro.core.ragged import RaggedLayout
 from repro.core.sparse_attention import as_paged
 from repro.kernels import (
     centroid_score,
@@ -138,6 +142,30 @@ def paged_attention(
 
 
 # ---------------------------------------------------------------------------
+def _dma_window(layout, la, max_pages_per_block, kernel: str) -> int:
+    """Static DMA window (pages) of a sparse kernel's inner loop: the
+    caller's bound reconciled with the layout's own maximum wherever that is
+    statically known — a window smaller than the largest assigned block
+    would silently truncate its attention span.  Inside a layer scan the
+    layout arrays are traced and the caller must size the window."""
+    if isinstance(layout, RaggedLayout):
+        return max(max_pages_per_block or 0, max(layout.pages_per_block))
+    import numpy as np
+
+    try:
+        layout_max = int(np.max(jax.device_get(la.pages_per_block)))
+    except jax.errors.ConcretizationTypeError:
+        if max_pages_per_block is None:
+            raise ValueError(
+                f"{kernel} needs a static max_pages_per_block when the layout "
+                "arrays are traced (e.g. inside a layer scan); pass it "
+                "explicitly"
+            ) from None
+        return max_pages_per_block
+    return max(max_pages_per_block or 0, layout_max)
+
+
+# ---------------------------------------------------------------------------
 # Fused decode: kernels 1+2+3 in one launch
 # ---------------------------------------------------------------------------
 
@@ -176,27 +204,9 @@ def fused_decode(
         seq_len = jnp.full((B,), la.context_len, jnp.int32)
     else:
         seq_len = jnp.broadcast_to(jnp.asarray(seq_len, jnp.int32), (B,))
-    # Reconcile the static DMA window with the layout's true maximum
-    # wherever that is statically known — a window smaller than the largest
-    # assigned block would silently truncate its attention span.
-    layout_max: Optional[int] = None
-    if isinstance(layout, RaggedLayout):
-        layout_max = max(layout.pages_per_block)
-    else:
-        import numpy as np
-
-        try:
-            layout_max = int(np.max(jax.device_get(la.pages_per_block)))
-        except jax.errors.ConcretizationTypeError:
-            pass                      # traced (layer scan): caller must size it
-    if layout_max is not None:
-        max_pages_per_block = max(max_pages_per_block or 0, layout_max)
-    elif max_pages_per_block is None:
-        raise ValueError(
-            "fused_decode needs a static max_pages_per_block when the "
-            "layout arrays are traced (e.g. inside a layer scan); pass it "
-            "explicitly"
-        )
+    max_pages_per_block = _dma_window(
+        layout, la, max_pages_per_block, "fused_decode"
+    )
     Dp = rq.shape[-1]
     if store.bits == 0:
         scale = jnp.ones((B, la.n_heads, Dp), jnp.float32)
@@ -214,8 +224,8 @@ def fused_decode(
         sink_pages=sink_pages,
         local_pages=local_pages,
         seg=la.max_blocks,
-        k_max=la.max_top_k,
         p_sel=la.selected_pages,
+        tile_rows=la.tile_rows,
         interpret=interpret,
     )
 
@@ -313,7 +323,6 @@ def sparse_prefill(
     n_valid: Optional[jax.Array] = None,
     chunk_offset=0,             # absolute pos of q[..., 0, :]; block_q-aligned
     max_pages_per_block: Optional[int] = None,
-    max_slots: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Single-launch query-block sparse prefill over the ragged layout.
@@ -326,7 +335,7 @@ def sparse_prefill(
     -> (out [B, Hq, Sq, D], n_attended [B, n_kv, nQB]).
     """
     from repro.core.stacked import as_arrays
-    from repro.kernels import sparse_prefill as sp
+    from repro.kernels import ref, sparse_prefill as sp
 
     if interpret is None:
         interpret = default_interpret()
@@ -337,63 +346,27 @@ def sparse_prefill(
     q6, rq6, k_sel, n_valid, qb0, _ = _prefill_query_blocks(
         q, rq, kp, la, block_q, topk_scale, n_valid, chunk_offset
     )
-
-    # static DMA window / slot bound: from the concrete layout when
-    # available, else the caller must size them (layer-scan case).
-    import numpy as np
-
-    if isinstance(layout, RaggedLayout):
-        max_pages_per_block = max(
-            max_pages_per_block or 0, max(layout.pages_per_block)
-        )
-        max_slots = max(
-            max_slots or 0,
-            layout.prefill_max_slots(
-                block_q, sink_pages, local_pages, topk_scale
-            ),
-        )
-    else:
-        try:
-            max_pages_per_block = max(
-                max_pages_per_block or 0,
-                int(np.max(jax.device_get(la.pages_per_block))),
-            )
-            max_slots = max(
-                max_slots or 0,
-                prefill_max_slots_arrays(
-                    jax.device_get(la.block_sizes),
-                    jax.device_get(la.top_k),
-                    jax.device_get(la.n_blocks),
-                    la.page_size, block_q, sink_pages, local_pages,
-                    topk_scale,
-                ),
-            )
-        except jax.errors.ConcretizationTypeError:
-            if not (max_pages_per_block and max_slots):
-                raise ValueError(
-                    "sparse_prefill needs static max_pages_per_block and "
-                    "max_slots when the layout arrays are traced (e.g. "
-                    "inside a layer scan); pass them explicitly"
-                ) from None
-
-    bits = score_store.bits
-    # score stores always carry concrete per-row params (identity arrays
-    # when unquantized — see store._encode_score_rows).
-    scale, zero = score_store.scale, score_store.zero
-
+    max_pages_per_block = _dma_window(
+        layout, la, max_pages_per_block, "sparse_prefill"
+    )
+    # the score segment's per-ROW affine params sit on the sublane axis,
+    # where a kernel cannot slice them; dequantizing here (the oracle's own
+    # function) hands the kernel the exact rank rows the oracle scores.
+    rank_rows = ref.dequant_score_rows(
+        score_store.codes, score_store.scale, score_store.zero,
+        score_store.bits, score_store.symmetric,
+    )
     out6, nsel = sp.sparse_prefill(
-        q6, rq6, kp, vp, score_store.codes, scale, zero,
+        q6, rq6, kp, vp, rank_rows,
         la.row_offsets, la.n_blocks, k_sel,
         la.block_sizes, la.pages_per_block, n_valid, qb0,
         page_size=la.page_size,
         ppb_max=max_pages_per_block,
-        bits=bits,
-        symmetric=score_store.symmetric,
         block_q=block_q,
         sink_pages=sink_pages,
         local_pages=local_pages,
         seg=la.max_blocks,
-        l_max=max_slots,
+        tile_rows=la.tile_rows,
         interpret=interpret,
     )
     return _from_blocks(out6, Sq), nsel

@@ -3,13 +3,12 @@ to the prefill phase in ONE Pallas launch per layer.
 
 Per ``(batch, kv-head, query-block)`` grid cell the kernel:
 
-1. **Scores** the head's running centroid segment in-register: the packed
-   INT4/INT8 score rows are DMA'd from the flattened ragged segment and
-   dequantized with their per-ROW affine params (same ``dequant_rows``
-   wire-format code as the fused decode kernel — per-row scalars broadcast
-   where the decode store uses per-head channel vectors), then hit the MXU
-   against the query block's rank queries; the block score is the max over
-   the block's (live) queries and the GQA group.
+1. **Scores** the head's running centroid segment: its rank rows are DMA'd
+   from the flattened ragged segment (dequantized by the caller with the
+   oracle's own :func:`repro.kernels.ref.dequant_score_rows` — the per-ROW
+   affine params sit on the sublane axis, where a kernel cannot slice
+   them) and hit the MXU against the query block's rank queries; the block
+   score is the max over the block's (live) queries and the GQA group.
 2. **Selects** the union of
    - *forced* blocks — sink blocks plus every block overlapping the query
      block's local window / causal diagonal (these are never scored, so a
@@ -22,9 +21,10 @@ Per ``(batch, kv-head, query-block)`` grid cell the kernel:
      fused decode kernel.
    Early query blocks have no scoreable candidates and therefore stay
    EXACT (every causal block is forced).
-3. **Attends** flash-style over only the selected blocks: double-buffered
-   page DMA, per-token causal masking inside the diagonal blocks, running
-   (m, l, acc) softmax state in registers.
+3. **Attends** flash-style over only the selected blocks, walked in index
+   order as in :mod:`repro.kernels.fused_decode`: double-buffered page DMA,
+   per-token causal masking inside the diagonal blocks, running (m, l, acc)
+   softmax state in registers.
 
 Raggedness rides the same scalar-prefetched grid descriptor as decode
 (per-head flat-row offsets, block counts, block sizes, pages-per-block), so
@@ -32,7 +32,8 @@ heterogeneous per-head block sizes share one launch.  A scalar ``qb0``
 offsets the query-block index, which is how chunked prefill replays later
 chunks through the identical kernel.
 
-Interpret mode on CPU validates numerics; the same call lowers to Mosaic.
+Interpret mode validates the numerics on CPU; ``tests/test_tpu_compile.py``
+compiles the kernel for a v5e chip, and ``chip_smoke.py`` runs it there.
 """
 from __future__ import annotations
 
@@ -44,8 +45,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.centroid_score import dequant_rows
-from repro.kernels.topk_threshold import _to_sortable
+from repro.kernels.topk_threshold import next_selected, topk_row_mask
 
 NEG_INF = -1e30
 POS_INF = 1e30
@@ -61,26 +61,21 @@ def _sparse_prefill_kernel(
     n_valid_ref,               # [B] int32 live tokens (queries AND keys)
     qb0_ref,                   # [1] int32 absolute index of query block 0
     # -- array inputs
-    codes_ref,                 # [B, R, Cw] score-segment codes (HBM/ANY)
-    scale_ref,                 # [B, R, 1] f32 per-row scale (HBM/ANY)
-    zero_ref,                  # [B, R, 1] f32 per-row zero (HBM/ANY)
+    rows_ref,                  # [B, R, Dp] f32 rank rows (HBM/ANY)
     rq_ref,                    # [1, 1, 1, g, BQ, Dp] rank queries
     q_ref,                     # [1, 1, 1, g, BQ, D]
     k_ref,                     # [B, H, n_pages, ps, D] paged pool (HBM/ANY)
     v_ref,                     # [B, H, n_pages, ps, D]
     # -- outputs
     o_ref,                     # [1, 1, 1, g, BQ, D]
-    nsel_ref,                  # [1, 1, 1] int32 blocks attended (stats)
+    nsel_ref,                  # [B, H, nQB] int32 blocks attended (SMEM)
     # -- scratch
-    codes_scr,                 # VMEM [SEG, Cw]
-    pscale_scr,                # VMEM [SEG, 1]
-    pzero_scr,                 # VMEM [SEG, 1]
+    rows_scr,                  # VMEM [SEG, Dp]
     kbuf, vbuf,                # VMEM [2, ppb_max, ps, D] double buffers
-    slot_scr,                  # VMEM [LMAX, 128] int32 per-slot descriptors
-    csem,                      # DMA sems (3,) codes/scale/zero
+    csem,                      # DMA sem (rank rows)
     sem,                       # DMA sems [2, 2] (k/v double buffer)
     *,
-    bits: int, symmetric: bool, seg: int, l_max: int, block_q: int,
+    seg: int, block_q: int, row_align: int,
     page_size: int, ppb_max: int, n_pages: int, total_rows: int,
     sink_pages: int, local_pages: int, scale_qk: float,
 ):
@@ -97,40 +92,27 @@ def _sparse_prefill_kernel(
     q_end = jnp.minimum(q_start + block_q, nv) - 1     # last live query pos
 
     # ---- phase 1: score the head's centroid segment ------------------------
-    start = jnp.minimum(row_off, total_rows - seg)
+    start = pl.multiple_of(jnp.minimum(row_off, total_rows - seg), row_align)
     adj = row_off - start
-    dmas = [
-        pltpu.make_async_copy(
-            codes_ref.at[b, pl.ds(start, seg)], codes_scr, csem.at[0]
-        ),
-        pltpu.make_async_copy(
-            scale_ref.at[b, pl.ds(start, seg)], pscale_scr, csem.at[1]
-        ),
-        pltpu.make_async_copy(
-            zero_ref.at[b, pl.ds(start, seg)], pzero_scr, csem.at[2]
-        ),
-    ]
-    for d in dmas:
-        d.start()
-    for d in dmas:
-        d.wait()
-    rk = dequant_rows(
-        codes_scr[...], pscale_scr[...], pzero_scr[...], bits, symmetric
-    )                                                  # [SEG, Dp]
+    cdma = pltpu.make_async_copy(
+        rows_ref.at[b, pl.ds(start, seg)], rows_scr, csem
+    )
+    cdma.start()
+    cdma.wait()
     g, BQ, Dp = rq_ref.shape[3:]
     rq = rq_ref[0, 0, 0].reshape(g * BQ, Dp)           # [gBQ, Dp]
-    qpos = q_start + (
-        jnp.arange(g * BQ, dtype=jnp.int32) % BQ
-    )                                                  # [gBQ] absolute pos
+    qpos = q_start + jax.lax.rem(
+        jax.lax.broadcasted_iota(jnp.int32, (g * BQ, 1), 0), BQ
+    )                                                  # [gBQ, 1] absolute pos
     s_all = jax.lax.dot_general(
-        rk, rq, (((1,), (1,)), ((), ())),
+        rq, rows_scr[...], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
-    )                                                  # [SEG, gBQ]
-    s_all = jnp.where(qpos[None, :] < nv, s_all, NEG_INF)
-    s = jnp.max(s_all, axis=-1)                        # [SEG]
+    )                                                  # [gBQ, SEG]
+    s_all = jnp.where(qpos < nv, s_all, NEG_INF)
+    s = jnp.max(s_all, axis=0, keepdims=True)          # [1, SEG]
 
     # ---- phase 2: forced union + exact top-K over scored candidates --------
-    jloc = jnp.arange(seg, dtype=jnp.int32) - adj      # block id in head
+    jloc = jax.lax.broadcasted_iota(jnp.int32, (1, seg), 1) - adj
     starts_tok = jloc * bsz
     in_seg = (jloc >= 0) & (jloc < nblk)
     causal = in_seg & (starts_tok <= q_end) & (starts_tok < nv)
@@ -139,51 +121,28 @@ def _sparse_prefill_kernel(
     forced = forced | (causal & (starts_tok + bsz > lo))
     cand = causal & jnp.logical_not(forced)
     s_m = jnp.where(cand, s, NEG_INF)
-
-    u = _to_sortable(s_m)                              # [SEG] uint32
-
-    def bit_step(i, t):
-        c = t | (jnp.uint32(1) << (jnp.uint32(31) - jnp.uint32(i)))
-        cnt = jnp.sum((u >= c).astype(jnp.int32))
-        return jnp.where(cnt >= k_sel, c, t)
-
-    thr = jax.lax.fori_loop(0, 32, bit_step, jnp.uint32(0))
-    n_gt = jnp.sum((u > thr).astype(jnp.int32))
-    is_tie = (u == thr).astype(jnp.int32)
-    tie_rank = jnp.cumsum(is_tie) - is_tie             # exclusive
-    scored = (u > thr) | ((is_tie > 0) & (tie_rank < k_sel - n_gt))
     # drop -inf "candidates" (dead query blocks / fewer candidates than K)
-    scored = scored & cand & (s_m > NEG_INF / 2)
+    scored = topk_row_mask(s_m, k_sel) & cand & (s_m > NEG_INF / 2)
     # fully-dead query blocks (chunk padding past nv) select nothing: their
     # outputs are discarded, so attending their forced blocks would only
     # burn DMA and overstate the attended-block count (parity: the
     # reference oracle masks identically).
     selected = (forced | scored) & (q_start < nv)
-    sel_rank = jnp.cumsum(selected.astype(jnp.int32))  # inclusive
-    n_live = sel_rank[-1]
-    nsel_ref[0, 0, 0] = n_live
-
-    # compact selected block ids into LMAX slots (index order)
-    slot_ids = jnp.arange(l_max, dtype=jnp.int32)
-    onehot = selected[None, :] & (sel_rank[None, :] == slot_ids[:, None] + 1)
-    blk = jnp.sum(jnp.where(onehot, jloc[None, :], 0), axis=1)      # [LMAX]
-    pstart = jnp.clip(blk * ppb, 0, n_pages - ppb_max)
-    tok0 = blk * bsz
-    slot_scr[...] = jnp.concatenate(
-        [
-            pstart[:, None],
-            tok0[:, None],
-            jnp.zeros((l_max, 126), jnp.int32),
-        ],
-        axis=1,
-    )
+    n_live = jnp.sum(selected.astype(jnp.int32))
+    nsel_ref[b, h, qb] = n_live
 
     # ---- phase 3: flash attention over the selected blocks -----------------
+    # selected blocks are walked in index order (see fused_decode)
     q = q_ref[0, 0, 0].reshape(g * BQ, -1).astype(jnp.float32)      # [gBQ, D]
     D = q.shape[-1]
     W = ppb_max * page_size
+    tok_ids = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
 
-    def kv_dma(slot, pg):
+    def page_start(p):
+        return jnp.clip((p - adj) * ppb, 0, n_pages - ppb_max)
+
+    def kv_dma(slot, p):
+        pg = page_start(p)
         return (
             pltpu.make_async_copy(
                 k_ref.at[b, h, pl.ds(pg, ppb_max)], kbuf.at[slot],
@@ -199,61 +158,64 @@ def _sparse_prefill_kernel(
     # select no blocks at all): the loop below then never runs, so starting
     # the warm-up DMA unconditionally would leak un-awaited semaphore
     # signals into the next grid cell on real hardware.
+    p0 = next_selected(selected, -1)
+
     @pl.when(n_live > 0)
     def _warmup():
-        dk0, dv0 = kv_dma(0, slot_scr[0, 0])
+        dk0, dv0 = kv_dma(0, p0)
         dk0.start()
         dv0.start()
 
     def body(i, carry):
-        m, l, acc = carry
+        m, l, acc, p = carry
         slot = i % 2
-        pg_i = slot_scr[i, 0]
-        t0 = slot_scr[i, 1]
+        p_next = next_selected(selected, p)
 
         @pl.when(i + 1 < n_live)
         def _prefetch_next():
-            nslot = (i + 1) % 2
-            pg_n = slot_scr[jnp.minimum(i + 1, l_max - 1), 0]
-            dk, dv = kv_dma(nslot, pg_n)
+            dk, dv = kv_dma(1 - slot, p_next)
             dk.start()
             dv.start()
 
-        dk, dv = kv_dma(slot, pg_i)
+        dk, dv = kv_dma(slot, p)
         dk.wait()
         dv.wait()
         kf = kbuf[slot].reshape(W, D).astype(jnp.float32)
         vf = vbuf[slot].reshape(W, D).astype(jnp.float32)
 
-        pos = pg_i * page_size + jnp.arange(W, dtype=jnp.int32)
+        t0 = (p - adj) * bsz
+        pos = page_start(p) * page_size + tok_ids      # [1, W]
         ok_k = (pos >= t0) & (pos < t0 + bsz) & (pos < nv)
         logits = jax.lax.dot_general(
             q, kf, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale_qk                                   # [gBQ, W]
-        ok = ok_k[None, :] & (pos[None, :] <= qpos[:, None])
+        ok = ok_k & (pos <= qpos)
         logits = jnp.where(ok, logits, NEG_INF)
 
         m_cur = jnp.max(logits, axis=-1, keepdims=True)
         m_new = jnp.maximum(m, m_cur)
         alpha = jnp.exp(m - m_new)
-        p = jnp.exp(logits - m_new)
+        pr = jnp.exp(logits - m_new)
         # fully-masked rows (no visible key in this block) contribute
         # nothing: their p row is exp(NEG_INF - m) == 0 once any real key
         # has been seen; before that m == NEG_INF and p == exp(0) == 1 for
         # masked lanes, so zero those rows explicitly.
-        p = jnp.where(ok, p, 0.0)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        pr = jnp.where(ok, pr, 0.0)
+        l_new = l * alpha + jnp.sum(pr, axis=-1, keepdims=True)
         acc_new = acc * alpha + jax.lax.dot_general(
-            p, vf, (((1,), (0,)), ((), ())),
+            pr, vf, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        return m_new, l_new, acc_new
+        return m_new, l_new, acc_new, p_next
 
-    m0 = jnp.full((g * BQ, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((g * BQ, 1), jnp.float32)
-    acc0 = jnp.zeros((g * BQ, D), jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, n_live, body, (m0, l0, acc0))
+    carry = (
+        jnp.full((g * BQ, 1), NEG_INF, jnp.float32),
+        jnp.zeros((g * BQ, 1), jnp.float32),
+        jnp.zeros((g * BQ, D), jnp.float32),
+        p0,
+    )
+    _, l, acc, _ = jax.lax.fori_loop(0, n_live, body, carry)
     out = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
     o_ref[0, 0, 0] = out.reshape(g, BQ, D)
 
@@ -261,8 +223,8 @@ def _sparse_prefill_kernel(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "page_size", "ppb_max", "bits", "symmetric", "block_q",
-        "sink_pages", "local_pages", "seg", "l_max", "interpret",
+        "page_size", "ppb_max", "block_q", "sink_pages", "local_pages",
+        "seg", "tile_rows", "interpret",
     ),
 )
 def sparse_prefill(
@@ -270,9 +232,7 @@ def sparse_prefill(
     rq: jax.Array,             # [B, n_kv, nQB, g, BQ, Dp] rank queries
     k_pages: jax.Array,        # [B, n_kv, n_pages, page, D]
     v_pages: jax.Array,        # [B, n_kv, n_pages, page, D]
-    codes: jax.Array,          # [B, total_rows, Cw] score-segment codes
-    scale: jax.Array,          # [B, total_rows, 1] f32
-    zero: jax.Array,           # [B, total_rows, 1] f32
+    rank_rows: jax.Array,      # [B, total_rows, Dp] f32 dequantized rows
     row_off: jax.Array,        # [H] int32 descriptor arrays ----------------
     n_blocks: jax.Array,       # [H] int32
     k_sel: jax.Array,          # [H] int32 prefill-scaled top-K
@@ -283,13 +243,11 @@ def sparse_prefill(
     *,
     page_size: int,
     ppb_max: int,
-    bits: int,
-    symmetric: bool,
     block_q: int,
     sink_pages: int,
     local_pages: int,
     seg: int,
-    l_max: int,
+    tile_rows: int,
     interpret: bool = False,
 ):
     """-> (out [B, n_kv, nQB, g, BQ, D], n_attended [B, n_kv, nQB] int32).
@@ -302,15 +260,13 @@ def sparse_prefill(
     B, n_kv, nQB, g, BQ, D = q.shape
     n_pages = k_pages.shape[2]
     Dp = rq.shape[-1]
-    total_rows = codes.shape[1]
+    total_rows = rank_rows.shape[1]
 
     kernel = functools.partial(
         _sparse_prefill_kernel,
-        bits=bits,
-        symmetric=symmetric,
         seg=seg,
-        l_max=l_max,
         block_q=block_q,
+        row_align=tile_rows,
         page_size=page_size,
         ppb_max=ppb_max,
         n_pages=n_pages,
@@ -323,32 +279,27 @@ def sparse_prefill(
         num_scalar_prefetch=7,
         grid=(B, n_kv, nQB),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),      # codes
-            pl.BlockSpec(memory_space=pltpu.ANY),      # per-row scale
-            pl.BlockSpec(memory_space=pltpu.ANY),      # per-row zero
+            pl.BlockSpec(memory_space=pl.ANY),      # rank rows
             pl.BlockSpec(
                 (1, 1, 1, g, BQ, Dp), lambda b, h, qb, *_: (b, h, qb, 0, 0, 0)
             ),
             pl.BlockSpec(
                 (1, 1, 1, g, BQ, D), lambda b, h, qb, *_: (b, h, qb, 0, 0, 0)
             ),
-            pl.BlockSpec(memory_space=pltpu.ANY),      # k pages
-            pl.BlockSpec(memory_space=pltpu.ANY),      # v pages
+            pl.BlockSpec(memory_space=pl.ANY),      # k pages
+            pl.BlockSpec(memory_space=pl.ANY),      # v pages
         ],
         out_specs=[
             pl.BlockSpec(
                 (1, 1, 1, g, BQ, D), lambda b, h, qb, *_: (b, h, qb, 0, 0, 0)
             ),
-            pl.BlockSpec((1, 1, 1), lambda b, h, qb, *_: (b, h, qb)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),     # whole counter array
         ],
         scratch_shapes=[
-            pltpu.VMEM((seg, codes.shape[-1]), codes.dtype),
-            pltpu.VMEM((seg, 1), jnp.float32),
-            pltpu.VMEM((seg, 1), jnp.float32),
+            pltpu.VMEM((seg, Dp), jnp.float32),
             pltpu.VMEM((2, ppb_max, page_size, D), k_pages.dtype),
             pltpu.VMEM((2, ppb_max, page_size, D), v_pages.dtype),
-            pltpu.VMEM((l_max, 128), jnp.int32),
-            pltpu.SemaphoreType.DMA((3,)),
+            pltpu.SemaphoreType.DMA(()),
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
@@ -359,6 +310,7 @@ def sparse_prefill(
             jax.ShapeDtypeStruct((B, n_kv, nQB, g, BQ, D), q.dtype),
             jax.ShapeDtypeStruct((B, n_kv, nQB), jnp.int32),
         ],
+        name="absparse_sparse_prefill",
         interpret=interpret,
     )(
         row_off.astype(jnp.int32),
@@ -368,9 +320,7 @@ def sparse_prefill(
         ppb.astype(jnp.int32),
         n_valid.astype(jnp.int32),
         qb0.astype(jnp.int32),
-        codes,
-        scale.astype(jnp.float32),
-        zero.astype(jnp.float32),
+        rank_rows.astype(jnp.float32),
         rq,
         q,
         k_pages,

@@ -44,6 +44,62 @@ def _from_sortable(u: jax.Array) -> jax.Array:
     return jax.lax.bitcast_convert_type(ui ^ mask, jnp.float32)
 
 
+_SIGN = -(2**31)
+
+
+def sortable_key(x_f32: jax.Array) -> jax.Array:
+    """Signed int32 key whose order is the float order: the
+    :func:`_to_sortable` encoding with its sign bit flipped, so code inside
+    a Mosaic kernel (which has no unsigned vector compares) can rank
+    scores with signed ops only."""
+    x = jax.lax.bitcast_convert_type(x_f32, jnp.int32)
+    return x ^ (jax.lax.shift_right_arithmetic(x, 31) & jnp.int32(0x7FFFFFFF))
+
+
+def topk_row_mask(s: jax.Array, k) -> jax.Array:
+    """In-kernel exact top-``k`` of one score row ``s [1, N]`` f32 ->
+    bool ``[1, N]`` with exactly ``min(k, N)`` entries set.
+
+    The k-th largest value comes from the 32-step bit search over the
+    sortable encoding (as in :func:`_kth_kernel`); ties at that value are
+    taken in index order, which reproduces ``lax.top_k``'s selected SET.
+    The first ``k - count_gt`` ties are found by bisecting on the index
+    (no prefix sum: Mosaic has no ``cumsum``)."""
+    n = s.shape[-1]
+    key = sortable_key(s)
+    sign = jnp.int32(_SIGN)
+
+    def bit_step(i, t):                    # t: the threshold's unsigned bits
+        cand = t | jax.lax.shift_left(jnp.int32(1), 31 - i)
+        cnt = jnp.sum((key >= (cand ^ sign)).astype(jnp.int32))
+        return jnp.where(cnt >= k, cand, t)
+
+    thr = jax.lax.fori_loop(0, 32, bit_step, jnp.int32(0)) ^ sign
+    gt = key > thr
+    tie = key == thr
+    need = k - jnp.sum(gt.astype(jnp.int32))
+    idx = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+
+    def bisect(_, lohi):                   # smallest c: #ties at idx <= c >= need
+        lo, hi = lohi
+        mid = (lo + hi) // 2
+        ok = jnp.sum((tie & (idx <= mid)).astype(jnp.int32)) >= need
+        return jnp.where(ok, lo, mid + 1), jnp.where(ok, mid, hi)
+
+    cut, _ = jax.lax.fori_loop(
+        0, int(n).bit_length(), bisect, (jnp.int32(0), jnp.int32(n - 1))
+    )
+    return gt | (tie & (idx <= cut) & (need > 0))
+
+
+def next_selected(sel: jax.Array, after) -> jax.Array:
+    """Smallest index of ``sel [1, N]`` greater than ``after`` (a scalar),
+    or ``N`` when none is left — walks a selection in index order without
+    compacting it."""
+    idx = jax.lax.broadcasted_iota(jnp.int32, sel.shape, 1)
+    return jnp.min(jnp.where(sel & (idx > after), idx, sel.shape[-1]))
+
+
 def _kth_kernel(k_ref, scores_ref, thr_ref, cnt_ref):
     h = pl.program_id(1)
     k = k_ref[h]

@@ -19,7 +19,7 @@ from __future__ import annotations
 from typing import Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def derive_mesh_shape(
@@ -43,6 +43,13 @@ def derive_mesh_shape(
     return (pod, data, model) if multi_pod else (data, model)
 
 
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
+    """``jax.make_mesh`` with ``Auto`` axes: the sharding code places
+    arrays with ``with_sharding_constraint`` and lets GSPMD propagate,
+    which ``Explicit`` axes (the newer ``make_mesh`` default) refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(
     *,
     multi_pod: bool = False,
@@ -63,7 +70,7 @@ def make_production_mesh(
     shape = tuple(shape)
     axes = ("pod", "data", "model") if len(shape) == 3 else ("data", "model")
     assert len(shape) == len(axes), (shape, axes)
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_serving_mesh(
@@ -82,7 +89,7 @@ def make_serving_mesh(
         shape = derive_mesh_shape(model_cap=n_kv_heads)
     shape = tuple(shape)
     assert len(shape) == 2, f"serving mesh is (data, model), got {shape}"
-    return jax.make_mesh(shape, ("data", "model"))
+    return _auto_mesh(shape, ("data", "model"))
 
 
 def parse_mesh_arg(arg: str) -> Tuple[int, int]:

@@ -442,12 +442,9 @@ class Transformer:
         pat = self.plan.pattern
         sparse = self.use_sparse(max_context)
         quant = cfg.sparse.quant if quant is None else quant
-        # static kernel bounds for the sparse prefill launch, derived from
-        # the concrete plan here so the layer scan sees Python ints.
-        sp_max_slots = sp_ppb_max = None
-        if sparse and cfg.sparse.sparse_prefill:
-            sp_max_slots = self.attention_plan(max_context).prefill_max_slots
-            sp_ppb_max = cfg.sparse.max_block_size // cfg.sparse.page_size
+        # static DMA window of the sparse prefill launch (a Python int, so
+        # the layer scan sees a compile-time constant).
+        sp_ppb_max = cfg.sparse.max_block_size // cfg.sparse.page_size
 
         def run_layer(p, kind, x, entry, layer_layout, layer_offs):
             cfgl = self.cfg
@@ -499,7 +496,6 @@ class Transformer:
                         layer_layout, cfgl.sparse,
                         n_valid=jnp.full((B,), S_tot, jnp.int32),
                         max_pages_per_block=sp_ppb_max,
-                        max_slots=sp_max_slots,
                     )
                     h = layers.out_project(
                         p["attn"], jnp.moveaxis(attn_o, 1, 2), cfgl
@@ -655,7 +651,6 @@ class Transformer:
         # (query block, key block) pairs its kernel actually attended.
         collect_ptel = use_sp and "_ptel" in cache
         if use_sp:
-            sp_max_slots = self.attention_plan(S_max).prefill_max_slots
             sp_ppb_max = cfg.sparse.max_block_size // cfg.sparse.page_size
             bmax = cfg.sparse.max_block_size
             sp_window = min(-(-(C + 2 * bmax) // bmax) * bmax, S_max)
@@ -714,7 +709,6 @@ class Transformer:
                     lay, cfg.sparse,
                     n_valid=offset + n_valid, chunk_offset=offset,
                     max_pages_per_block=sp_ppb_max,
-                    max_slots=sp_max_slots,
                 )
                 if collect_ptel:
                     new_entry["_ptelq"] = jnp.sum(n_att).astype(jnp.int32)

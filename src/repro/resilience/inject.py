@@ -49,6 +49,7 @@ from dataclasses import asdict, dataclass
 from typing import Dict, List, Optional, Sequence, Union
 
 import numpy as np
+from jax.errors import JaxRuntimeError
 
 from repro.cache.paged_kv import PoolExhausted
 
@@ -85,27 +86,15 @@ class HostIOError(PoolExhausted):
     tier_bound = True
 
 
-#: exception types a *real* jit dispatch can raise at run time — the
-#: degradation ladder treats these exactly like injected device errors.
-def _runtime_error_types() -> tuple:
-    try:
-        from jax.errors import JaxRuntimeError
-
-        return (JaxRuntimeError,)
-    except ImportError:
-        pass
-    try:
-        from jaxlib.xla_extension import XlaRuntimeError
-
-        return (XlaRuntimeError,)
-    except ImportError:
-        return ()
-
-
+#: exception types a step can raise at run time — injected, or what a real
+#: jit dispatch raises (``JaxRuntimeError``); the degradation ladder treats
+#: them alike.  Compile and lowering errors never get here: the engine
+#: compiles a rung's steps outside the ladder (``Engine._rung_step_fns``).
 DEVICE_FAULTS: tuple = (
     InjectedDeviceError,
     FloatingPointError,
-) + _runtime_error_types()
+    JaxRuntimeError,
+)
 
 
 @dataclass

@@ -102,6 +102,59 @@ class EngineStalled(RuntimeError):
 _STEP_FAULTS = DEVICE_FAULTS + (SamplerAnomaly,)
 
 
+class StepCompileError(RuntimeError):
+    """An engine step failed to trace, lower or compile (a kernel the chip's
+    compiler refuses, a program that does not fit device memory).  That is
+    a program error, not a step fault: it propagates instead of moving the
+    engine down the degradation ladder."""
+
+
+def _signature(args) -> tuple:
+    leaves, tree = jax.tree.flatten(args)
+    return (tree,) + tuple(
+        (
+            np.shape(x), np.result_type(x),
+            getattr(x, "weak_type", False), getattr(x, "sharding", None),
+        )
+        for x in leaves
+    )
+
+
+class CompiledStep:
+    """A jit'd engine step that compiles ahead of its first call for each
+    argument signature, inside the engine's sharding context, so a failure
+    to compile raises :class:`StepCompileError` and only a failure to RUN
+    reaches the ladder as a step fault.  ``compile_seconds`` accumulates
+    the time spent compiling."""
+
+    def __init__(self, name: str, fn, ctx, donate_argnums=()):
+        self._jit = jax.jit(fn, donate_argnums=donate_argnums)
+        self._ctx = ctx
+        self._name = name
+        self._compiled: Dict[tuple, Callable] = {}
+        self.compile_seconds = 0.0
+
+    def __call__(self, *args):
+        key = _signature(args)
+        exe = self._compiled.get(key)
+        if exe is None:
+            t0 = time.perf_counter()
+            try:
+                with self._ctx():
+                    exe = self._jit.lower(*args).compile()
+            except Exception as exc:
+                raise StepCompileError(
+                    f"engine step {self._name!r} failed to compile: {exc}"
+                ) from exc
+            self.compile_seconds += time.perf_counter() - t0
+            self._compiled[key] = exe
+        return exe(*args)
+
+    def compiled_texts(self) -> List[str]:
+        """HLO text of every program compiled so far (one per signature)."""
+        return [exe.as_text() for exe in self._compiled.values()]
+
+
 def _fault_reason(exc: BaseException) -> str:
     return FAIL_SAMPLER if isinstance(exc, SamplerAnomaly) else FAIL_DEVICE
 
@@ -232,6 +285,9 @@ class Engine:
                 page_size=self.serve.page_size,
             )
         self.key = jax.random.PRNGKey(seed)
+        #: the engine's compiled steps by name (``decode``,
+        #: ``prefill_chunk``, ... and ``<step>@<rung>`` for degraded rungs).
+        self.steps: Dict[str, CompiledStep] = {}
 
         self.cache = self.model.init_cache(self.max_batch, self.max_context)
         if mesh is not None:
@@ -285,20 +341,16 @@ class Engine:
         # reference (it reassigns ``self.cache`` from each step's result),
         # and ``init_cache`` gives the cache private copies of the shared
         # plan descriptors, so donation is safe.
-        # jit'd steps trace (and re-trace) under the serving sharding
-        # context so model-level ``constrain`` calls and the backend's
-        # shard_map'd kernel launches see the mesh.
-        self._decode = self._under_mesh(
-            jax.jit(self.model.decode_step, donate_argnums=(1,))
+        # jit'd steps trace under the serving sharding context so
+        # model-level ``constrain`` calls and the backend's shard_map'd
+        # kernel launches see the mesh (see CompiledStep).
+        self._decode = self._step("decode", self.model.decode_step, (1,))
+        self._chunk = self._step("prefill_chunk", self.model.prefill_chunk, (1,))
+        self._refresh = self._step(
+            "refresh_store", self.model.refresh_slot_store, (0,)
         )
-        self._chunk = self._under_mesh(
-            jax.jit(self.model.prefill_chunk, donate_argnums=(1,))
-        )
-        self._refresh = self._under_mesh(
-            jax.jit(self.model.refresh_slot_store, donate_argnums=(0,))
-        )
-        self._refresh_scores = self._under_mesh(
-            jax.jit(self.model.refresh_slot_score_rows, donate_argnums=(0,))
+        self._refresh_scores = self._step(
+            "refresh_score_rows", self.model.refresh_slot_score_rows, (0,)
         )
         self._chunk_len = min(serve_cfg.prefill_chunk, self.max_context)
         self._tokens_buf = np.zeros((self.max_batch,), np.int32)
@@ -309,7 +361,7 @@ class Engine:
         # tick scheduling (stalls, preemption order, batch composition).
         # This is what makes an overcommitted tiered-memory run
         # token-identical to an all-HBM run.
-        self._sample = self._under_mesh(jax.jit(self._sample_batch))
+        self._sample = self._step("sample", self._sample_batch)
         self.memory: Optional[MemoryManager] = None
         if isinstance(self.pool, TieredPagePool):
             nP = self.max_context // self.serve.page_size
@@ -422,7 +474,9 @@ class Engine:
         return [(sp.backend, None)]
 
     def _rung_step_fns(self, rung: int) -> Tuple:
-        """(decode_step, prefill_chunk) jit'd for ``rung``, built lazily.
+        """(decode_step, prefill_chunk) jit'd for ``rung``, built lazily and
+        compiled before their first call (:class:`CompiledStep`): a rung
+        that cannot compile raises instead of degrading further.
         All rungs share the engine's params and cache: the paged KV / store
         layout is backend-independent (PR 1's byte-identical stores), so a
         degraded re-run picks up the exact device state the failed attempt
@@ -433,13 +487,10 @@ class Engine:
                 self.cfg, sparse=dataclasses.replace(self.cfg.sparse, **over)
             )
             model = Transformer(cfg)
+            name = self._ladder[rung][0]
             self._rung_fns[rung] = (
-                self._under_mesh(
-                    jax.jit(model.decode_step, donate_argnums=(1,))
-                ),
-                self._under_mesh(
-                    jax.jit(model.prefill_chunk, donate_argnums=(1,))
-                ),
+                self._step(f"decode@{name}", model.decode_step, (1,)),
+                self._step(f"prefill_chunk@{name}", model.prefill_chunk, (1,)),
             )
         return self._rung_fns[rung]
 
@@ -596,15 +647,15 @@ class Engine:
             return nullcontext()
         return sharding_rules(self.mesh, self.shard_rules)
 
-    def _under_mesh(self, fn):
-        """Run ``fn`` inside the engine's sharding context (identity when
-        the engine is mesh-less)."""
+    def _step(self, name: str, fn, donate_argnums=()) -> CompiledStep:
+        step = CompiledStep(name, fn, self._shard_ctx, donate_argnums)
+        self.steps[name] = step
+        return step
 
-        def wrapped(*args, **kwargs):
-            with self._shard_ctx():
-                return fn(*args, **kwargs)
-
-        return wrapped
+    @property
+    def compile_seconds(self) -> float:
+        """Seconds this engine has spent compiling its steps."""
+        return sum(s.compile_seconds for s in self.steps.values())
 
     @property
     def max_batch(self) -> int:

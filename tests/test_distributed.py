@@ -178,7 +178,10 @@ DRYRUN_SNIPPET = textwrap.dedent(
 
     cfg = smoke_variant(get_config("llama3.2-3b"))
     shape = ShapeConfig("t", 256, 8, "%KIND%")
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh(
+        (4, 2), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
+    )
     plan = MeshPlan()
     rules = pshard.rules_for(cfg, shape, plan)
     cell = build_cell(cfg, shape, plan)

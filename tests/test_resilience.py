@@ -301,3 +301,28 @@ def test_ladder_degrades_and_repromotes(setup):
     assert snap["degradations_by_rung"] == {"reference": 1}
     assert snap["retries"] == 0, "the ladder absorbed the fault"
     assert snap["repromotions"] == 1 and eng._rung == 0
+
+
+def test_compile_failure_raises_instead_of_degrading(setup):
+    """A step that fails to COMPILE (here: a simulated compile-time HBM
+    exhaustion, the error type a real run-time device fault also has) is a
+    program error: it propagates as StepCompileError instead of being
+    charged to the ladder or the failure budget."""
+    from jax.errors import JaxRuntimeError
+
+    from repro.serving.engine import StepCompileError
+
+    cfg, params = setup
+
+    class _Refused:
+        def lower(self, *args):
+            raise JaxRuntimeError("RESOURCE_EXHAUSTED: simulated at compile")
+
+    eng = Engine(cfg, params, ServeConfig(max_batch=1, max_context=256))
+    eng.steps["decode"]._jit = _Refused()
+    eng.submit(Request(0, np.arange(40, dtype=np.int32), max_new_tokens=4))
+    with pytest.raises(StepCompileError, match="'decode' failed to compile"):
+        eng.run_until_done(max_ticks=20)
+    snap = eng.metrics.snapshot()
+    assert snap["degradations"] == 0 and snap["retries"] == 0
+    assert snap["requests_failed"] == 0
