@@ -82,7 +82,7 @@ def _tick_engine(cfg, params, serve_kw, trace=None):
     eng = Engine(
         cfg, params, ServeConfig(**serve_kw),
         clock=lambda: float(state["eng"].metrics.ticks) if state else 0.0,
-        trace=trace,
+        trace=trace, telemetry=trace is not None,
     )
     state["eng"] = eng
     return eng

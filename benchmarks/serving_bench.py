@@ -156,8 +156,10 @@ def trace_overhead(
     floor estimate is the sum over tick positions of the per-position
     minimum across reps (machine-load jitter lands on different ticks in
     different reps and is filtered out, which a whole-run best-of-N cannot
-    do).  The floor ratio is the per-tick cost of the trace recorder +
-    device-side telemetry readback.  -> dict with ``trace_overhead_frac``
+    do).  The traced mode is what ``serve --trace`` runs: the recorder (the
+    engine's spans, metadata and counter reads) plus the opt-in
+    device-side telemetry and its readback, so the floor ratio is the
+    per-tick cost of both.  -> dict with ``trace_overhead_frac``
     (traced slowdown; the CI ceiling is 5%).
     """
     from repro.config import ServeConfig
@@ -200,7 +202,7 @@ def trace_overhead(
     eng = Engine(cfg, params, serve, trace=recorder)
     modes = {"untraced": None, "traced": recorder}
     for label, trace in modes.items():
-        eng.set_tracing(trace)
+        eng.set_tracing(trace, telemetry=trace is not None)
         for _ in range(2):
             warm = make_requests(0)
             for r in warm:
@@ -221,7 +223,7 @@ def trace_overhead(
             if rep % 2:
                 order.reverse()
             for label, trace in order:
-                eng.set_tracing(trace)
+                eng.set_tracing(trace, telemetry=trace is not None)
                 measured = make_requests(n_requests)
                 for r in measured:
                     eng.submit(r)

@@ -5,7 +5,7 @@
     plan = build_plan(model_cfg, context_len)      # static layouts, cached
     backend = get_backend(model_cfg.sparse.backend)
     store = backend.build_store(keys, plan.layout(l), method, quant)
-    out, page_table = backend.decode(q, k, v, store, plan.layout(l), sparse)
+    out, page_table, pages = backend.decode(q, k, v, store, plan.layout(l), sparse)
 
 Registered backends: ``"dense"`` (full-attention oracle), ``"reference"``
 (pure jnp), ``"pallas"`` (interpret off-TPU, Mosaic on TPU).

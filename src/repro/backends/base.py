@@ -473,9 +473,11 @@ class AttentionBackend:
         paged attention.  q [B, n_q, D]; k/v paged
         ``[B, n_kv, n_pages, page, D]`` (the cache's native layout) or
         dense ``[B, n_kv, S, D]`` ->
-        (out [B, n_q, D], page_table [B, H, P_sel]).
+        (out [B, n_q, D], page_table [B, H, P_sel], pages [B] int32), where
+        ``pages`` counts the KV pages attention read per slot, summed over
+        heads (the valid entries of the page table).
 
-        With ``collect_tel=True`` the return gains a third element: per-slot
+        With ``collect_tel=True`` the return gains a fourth element: per-slot
         sparsity counters ``[B, 4]`` (:func:`selection_telemetry`) derived
         from the SAME estimation scores the selection just ranked — no
         second pass over the store, so telemetry costs only a top-k over the
@@ -498,6 +500,7 @@ class AttentionBackend:
         out = self.attend(
             q, k, v, page_table, page_valid, la.page_size, seq_len
         )
+        pages = page_valid.sum(axis=(1, 2), dtype=jnp.int32)
         if collect_tel:
             tel = selection_telemetry(
                 scores, la, seq_len=seq_len,
@@ -505,8 +508,8 @@ class AttentionBackend:
                 local_pages=sparse.local_pages,
                 ranked=ranked,
             )
-            return out, page_table, tel
-        return out, page_table
+            return out, page_table, pages, tel
+        return out, page_table, pages
 
 
 # ---------------------------------------------------------------------------

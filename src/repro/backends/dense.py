@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.backends.reference import ReferenceBackend
+from repro.core.stacked import as_arrays
 from repro.core.sparse_attention import as_dense, dense_decode_attention
 
 
@@ -28,9 +29,16 @@ class DenseBackend(ReferenceBackend):
         self, q, k, v, store, layout, sparse, seq_len=None, collect_tel=False
     ) -> Tuple[jax.Array, ...]:
         out = dense_decode_attention(q, as_dense(k), as_dense(v), seq_len=seq_len)
+        # full attention reads every live page of every KV head
+        la = as_arrays(layout)
+        live = la.context_len if seq_len is None else seq_len
+        pages = jnp.broadcast_to(
+            k.shape[1] * -(-jnp.asarray(live, jnp.int32) // la.page_size),
+            (q.shape[0],),
+        )
         if collect_tel:           # no selection on the dense path
-            return out, None, None
-        return out, None
+            return out, None, pages, None
+        return out, None, pages
 
     def prefill_attention(
         self, q, k, v, score_store, layout, sparse,

@@ -20,6 +20,7 @@ from __future__ import annotations
 from typing import List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.backends.base import AttentionBackend, CentroidStore
@@ -124,7 +125,7 @@ class PallasBackend(AttentionBackend):
 
         rq = rank_query(q, sparse.centroid_method, q.shape[-1])
         with jax.named_scope("absparse.fused_decode"):
-            out, table, _ = kernel_partition.fused_decode(
+            out, table, valid = kernel_partition.fused_decode(
                 q, rq, k, v, store, layout,
                 sink_pages=sparse.sink_pages,
                 local_pages=sparse.local_pages,
@@ -132,6 +133,7 @@ class PallasBackend(AttentionBackend):
                 max_pages_per_block=sparse.max_block_size // sparse.page_size,
                 interpret=self._interp(),
             )
+        pages = valid.sum(axis=(1, 2), dtype=jnp.int32)
         if collect_tel:
             # the fused kernel keeps scores in-register; re-run the (cheap)
             # estimation stage to derive counters from the identical score
@@ -142,5 +144,5 @@ class PallasBackend(AttentionBackend):
                 sink_pages=sparse.sink_pages,
                 local_pages=sparse.local_pages,
             )
-            return out, table, tel
-        return out, table
+            return out, table, pages, tel
+        return out, table, pages

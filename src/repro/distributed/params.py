@@ -232,8 +232,8 @@ _PLANTED_CACHE_PREFIXES = (
     "_layouts",
     "_offsets",
     "_telemetry",
-    "_ptel",
-    "_ptelq",
+    "_decode_pages",
+    "_prefill_blocks",
     "_sel_pages",
     "_pre_pages",
 )

@@ -12,8 +12,9 @@ exercised.  The run ends with the engine's lifecycle-metrics snapshot
 
 Observability (see README "Observability"): ``--trace out.json`` records
 the full run timeline — per-sequence lifecycle spans, engine tick spans,
-memory-tier migrations, pool/residency/sparsity counter tracks — as Chrome
-trace-event JSON, loadable at https://ui.perfetto.dev.  ``--metrics-interval
+memory-tier migrations, pool/residency/sparsity counter tracks (it turns
+the engine's opt-in telemetry on) — as Chrome trace-event JSON, loadable
+at https://ui.perfetto.dev.  ``--metrics-interval
 N`` appends a structured metrics-snapshot JSONL line every N ticks to
 ``--metrics-out`` (default stdout).
 """
@@ -168,7 +169,7 @@ def main():
         pool_pages=args.pool_pages,
         hbm_pages=args.hbm_pages,
         host_pages=args.host_pages,
-    ), mesh=mesh, trace=trace)
+    ), mesh=mesh, trace=trace, telemetry=trace is not None)
     injector = None
     if args.chaos_seed is not None or args.chaos_plan is not None:
         from repro.resilience import FaultInjector, default_storm, load_plan

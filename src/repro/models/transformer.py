@@ -646,10 +646,11 @@ class Transformer:
         stk = cache.get("_layouts")
         all_offs = cache.get("_offsets")
         use_sp = cfg.sparse.sparse_prefill and "pcodes" in cache["pos0"]
-        # opt-in prefill sparsity telemetry (repro.obs): the engine plants
-        # "_ptel" [n_layers] and each sparse layer reports the number of
-        # (query block, key block) pairs its kernel actually attended.
-        collect_ptel = use_sp and "_ptel" in cache
+        # prefill sparsity counter (repro.obs): the engine plants
+        # "_prefill_blocks" [n_layers] uint32 and every sparse chunk adds the
+        # (query block, key block) pairs its kernel attended, per layer — a
+        # running total, so the host reads it whenever it next syncs.
+        count_blocks = use_sp and "_prefill_blocks" in cache
         if use_sp:
             sp_ppb_max = cfg.sparse.max_block_size // cfg.sparse.page_size
             bmax = cfg.sparse.max_block_size
@@ -710,8 +711,8 @@ class Transformer:
                     n_valid=offset + n_valid, chunk_offset=offset,
                     max_pages_per_block=sp_ppb_max,
                 )
-                if collect_ptel:
-                    new_entry["_ptelq"] = jnp.sum(n_att).astype(jnp.int32)
+                if count_blocks:
+                    new_entry["_blocksq"] = jnp.sum(n_att).astype(jnp.uint32)
                 h = layers.out_project(
                     p["attn"], jnp.moveaxis(attn_o, 1, 2), cfg
                 )
@@ -770,8 +771,10 @@ class Transformer:
                 ),
             )
             entry = new_cyc["pos0"]
-            if collect_ptel:
-                cache["_ptel"] = entry.pop("_ptelq")      # [n_cycles] int32
+            if count_blocks:                              # [n_cycles] uint32
+                cache["_prefill_blocks"] = (
+                    cache["_prefill_blocks"] + entry.pop("_blocksq")
+                )
             cache["pos0"] = entry
         x = layers.rms_norm(params["final_norm"], x, cfg.norm_eps)
         h_last = jnp.take(x[0], n_valid - 1, axis=0)      # last valid row
@@ -912,6 +915,10 @@ class Transformer:
         # "_telemetry" [n_layers, B, 4] and every sparse attention layer
         # reports [blocks, pages, forced, budget] per slot.
         collect_tel = stk is not None and "_telemetry" in cache
+        # decode page counter (repro.obs): the engine plants "_decode_pages"
+        # [n_layers, B] int32 and every sparse attention layer reports the
+        # KV pages its attention read per slot (the backend's own count).
+        count_pages = stk is not None and "_decode_pages" in cache
 
         def run_layer(p, kind, x, entry, lay, offs):
             h = layers.rms_norm(p["norm1"], x, cfg.norm_eps)
@@ -920,6 +927,7 @@ class Transformer:
                 h, new_entry = self._attn_decode(
                     p["attn"], h, entry, lay, offs, positions,
                     collect=collect, collect_tel=collect_tel,
+                    count_pages=count_pages,
                 )
             elif kind == "local_attn":
                 h, new_entry = self._local_attn_decode(
@@ -957,6 +965,8 @@ class Transformer:
             pre_acc = jnp.zeros_like(cache["_pre_pages"])
         if collect_tel:
             tel_acc = jnp.zeros_like(cache["_telemetry"])   # [L, B, 4]
+        if count_pages:
+            pages_acc = jnp.zeros_like(cache["_decode_pages"])   # [L, B]
         if self.plan.n_cycles > 0:
             cyc_cache_in = {f"pos{i}": cache[f"pos{i}"] for i in range(len(pat))}
             x, new_cyc = jax.lax.scan(
@@ -969,10 +979,16 @@ class Transformer:
                 if collect and kind == "attn":
                     sel_acc |= jnp.any(entry.pop("_selq"), axis=0)
                     pre_acc |= jnp.any(entry.pop("_preq"), axis=0)
+                # layer index of cycle c, position i is c*len(pat)+i
+                rows = jnp.arange(self.plan.n_cycles) * len(pat) + i
                 if collect_tel and kind == "attn":
-                    # layer index of cycle c, position i is c*len(pat)+i
-                    rows = jnp.arange(self.plan.n_cycles) * len(pat) + i
                     tel_acc = tel_acc.at[rows].set(entry.pop("_telq"))
+                if count_pages and kind == "attn":
+                    got = entry.pop("_pagesq")             # [n_cycles, B]
+                    if len(pat) == 1 and not self.plan.rest_kinds:
+                        pages_acc = got      # one layer a cycle: rows in order
+                    else:
+                        pages_acc = pages_acc.at[rows].set(got)
                 cache[f"pos{i}"] = entry
         for i, kind in enumerate(self.plan.rest_kinds):
             lay_idx = self.plan.n_cycles * len(pat) + i
@@ -986,12 +1002,16 @@ class Transformer:
                 pre_acc |= new_entry.pop("_preq")
             if collect_tel and kind == "attn":
                 tel_acc = tel_acc.at[lay_idx].set(new_entry.pop("_telq"))
+            if count_pages and kind == "attn":
+                pages_acc = pages_acc.at[lay_idx].set(new_entry.pop("_pagesq"))
             cache["rest"][i] = new_entry
         if collect:
             cache["_sel_pages"] = sel_acc
             cache["_pre_pages"] = pre_acc
         if collect_tel:
             cache["_telemetry"] = tel_acc
+        if count_pages:
+            cache["_decode_pages"] = pages_acc
 
         x = layers.rms_norm(params["final_norm"], x, cfg.norm_eps)
         logits = self.unembed(params, x[:, 0])
@@ -1002,7 +1022,7 @@ class Transformer:
     # -- decode helpers ---------------------------------------------------
 
     def _attn_decode(self, p, h, entry, lay, offs, positions, collect=False,
-                     collect_tel=False):
+                     collect_tel=False, count_pages=False):
         cfg = self.cfg
         B = h.shape[0]
         hd = cfg.resolved_head_dim
@@ -1072,14 +1092,16 @@ class Transformer:
             # sparsity counters piggyback on the estimation scores the
             # decode itself ranks (staged: same tensor; fused: an identical
             # recompute inside the backend) — no second pass over the store.
-            out, _, new_entry["_telq"] = self.backend.decode(
+            out, _, pages, new_entry["_telq"] = self.backend.decode(
                 q, k_cache, v_cache, store, lay, cfg.sparse, seq_len=live,
                 collect_tel=True,
             )
         else:
-            out, _ = self.backend.decode(
+            out, _, pages = self.backend.decode(
                 q, k_cache, v_cache, store, lay, cfg.sparse, seq_len=live
             )
+        if count_pages:
+            new_entry["_pagesq"] = pages
         if collect:
             # re-run the (cheap) estimation stage against the post-append
             # store — identical scores to the ones backend.decode just

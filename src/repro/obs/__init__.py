@@ -15,14 +15,22 @@ from repro.obs.trace import (
     PID_MEMORY,
     PID_SCHED,
     PID_SEQ,
+    SPAN_NAMES,
+    Span,
     TraceEvent,
     TraceRecorder,
+    instant,
+    profiling,
 )
 from repro.obs.validate import validate_chrome_trace
 
 __all__ = [
     "TraceRecorder",
     "TraceEvent",
+    "Span",
+    "SPAN_NAMES",
+    "instant",
+    "profiling",
     "PID_SCHED",
     "PID_ENGINE",
     "PID_MEMORY",

@@ -10,10 +10,11 @@ The jit'd decode step emits one ``[n_layers, B, 4]`` int32 array per tick
   chosen by score ranking,
 - ``BUDGET``: the layer's total top-K block budget (selection capacity).
 
-Sparse prefill similarly emits per-layer attended-block counts.  Both ride
-along on host transfers the engine already makes every tick, so enabling
-telemetry adds zero extra device syncs; disabling it removes the arrays
-from the cache entirely.
+The decode array is opt-in: enabling it changes the decode program, and
+the engine reads it after the tick's token read, which has already waited
+for the step.  Sparse prefill's per-layer attended-block counts come from
+a running total every prefill chunk keeps (see ``Engine``), read at the
+same token reads.
 
 :class:`SparsityAggregate` folds those per-step arrays into run-level
 statistics (per-layer sums, budget-utilization histogram) that
@@ -85,13 +86,14 @@ class SparsityAggregate:
         self._pending.clear()
 
     def update_prefill(self, attended: np.ndarray,
-                       candidates: Optional[np.ndarray] = None):
-        """Fold one prefill chunk: per-layer attended block counts plus
-        (host-computed) causal candidate counts for the same chunk."""
+                       candidates: Optional[np.ndarray] = None,
+                       chunks: int = 1):
+        """Fold ``chunks`` prefill chunks: per-layer attended block counts
+        plus (host-computed) causal candidate counts for the same chunks."""
         self.prefill_attended += np.asarray(attended, dtype=np.int64)
         if candidates is not None:
             self.prefill_candidates += np.asarray(candidates, dtype=np.int64)
-        self.prefill_chunks += 1
+        self.prefill_chunks += chunks
 
     # -- reporting -----------------------------------------------------------
 
@@ -123,22 +125,6 @@ class SparsityAggregate:
             ]
         return out
 
-    def per_layer(self) -> List[Dict[str, float]]:
-        """Per-attention-layer breakdown (layer index within attn layers)."""
-        self._fold()
-        rows = []
-        for layer in range(self.n_layers):
-            b, p, f, k = (float(v) for v in self.layer_sums[layer])
-            rows.append({
-                "layer": layer,
-                "blocks": b,
-                "pages": p,
-                "budget_utilization": b / k if k else 0.0,
-                "forced_frac": f / b if b else 0.0,
-                "prefill_attended": float(self.prefill_attended[layer]),
-            })
-        return rows
-
 
 def prefill_block_candidates(
     layouts, chunk_offset: int, n_tokens: int, block_q: int
@@ -158,9 +144,7 @@ def prefill_block_candidates(
     ) - 1                                                    # [nQB] absolute
     out = np.zeros(len(layouts), dtype=np.int64)
     for li, lay in enumerate(layouts):
-        per_head = 0
-        for h, bs in enumerate(lay.block_sizes):
-            nb = int(lay.n_blocks[h])
-            per_head += int(np.minimum(q_ends // int(bs) + 1, nb).sum())
-        out[li] = per_head
+        bs = np.asarray(lay.block_sizes, dtype=np.int64)[:, None]    # [H, 1]
+        nb = np.asarray(lay.n_blocks, dtype=np.int64)[:, None]
+        out[li] = np.minimum(q_ends[None, :] // bs + 1, nb).sum()
     return out

@@ -26,15 +26,22 @@ produces Chrome trace-event JSON loadable in Perfetto (ui.perfetto.dev) or
   ``residency`` counter tracks,
 - pid ``kernels``: per-step sparsity counter tracks (blocks attended,
   pages gathered, budget utilization).
+
+:class:`Span` and :func:`instant` are the one way the serving stack marks
+its timeline.  Each always opens a ``jax.profiler.TraceAnnotation``, so a
+profiler capture holds the span on the device trace's clock, with its
+metadata as event stats; given a recorder, the same call also records
+into the ring.  :data:`SPAN_NAMES` lists the engine's spans.
 """
 from __future__ import annotations
 
 import json
 import time
 from collections import deque
-from contextlib import contextmanager
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 #: Perfetto process-group ids, one per subsystem.
 PID_SCHED = 1
@@ -42,6 +49,23 @@ PID_ENGINE = 2
 PID_MEMORY = 3
 PID_SEQ = 4
 PID_KERNEL = 5
+
+#: the engine's spans, outermost first (see ``repro.serving.engine``):
+#: a tick; the scheduler's plan; one prefill chunk; a prompt's completion
+#: (store refresh, first token); the batched decode step; a sampling
+#: dispatch; a blocking device->host read; the host loop that commits a
+#: decode step's tokens; one compile of an engine step.
+SPAN_NAMES = (
+    "engine.tick",
+    "sched.plan",
+    "prefill.chunk",
+    "prefill.finish",
+    "engine.decode",
+    "engine.sample",
+    "engine.wait",
+    "engine.commit",
+    "engine.compile",
+)
 
 PROCESS_NAMES = {
     PID_SCHED: "scheduler",
@@ -101,17 +125,12 @@ class TraceRecorder:
             self.dropped += 1
         self._events.append(ev)
 
-    @contextmanager
-    def span(self, name: str, pid: int, tid: int = 0, args: Optional[dict] = None):
-        """Scoped duration span: records ONE complete ("X") event at exit,
-        so ring eviction can never leave a dangling half-span."""
-        t0 = self.clock()
-        try:
-            yield self
-        finally:
-            self._push(TraceEvent(
-                name, "X", t0, pid, tid, dur=self.clock() - t0, args=args
-            ))
+    def span(self, name: str, pid: int, tid: int = 0,
+             args: Optional[dict] = None) -> "Span":
+        """Scoped duration span (a :class:`Span` on this recorder): records
+        ONE complete ("X") event at exit, so ring eviction can never leave
+        a dangling half-span."""
+        return Span(name, self, pid=pid, tid=tid, **(args or {}))
 
     def begin(self, name: str, pid: int, tid: int = 0,
               args: Optional[dict] = None):
@@ -125,7 +144,7 @@ class TraceRecorder:
 
     def instant(self, name: str, pid: int, tid: int = 0,
                 args: Optional[dict] = None):
-        self._push(TraceEvent(name, "i", self.clock(), pid, tid, args=args))
+        instant(name, self, pid=pid, tid=tid, **(args or {}))
 
     def counter(self, name: str, values: Dict[str, float], pid: int = PID_MEMORY):
         """Sample a counter track: ``values`` maps series name -> value."""
@@ -201,3 +220,77 @@ class TraceRecorder:
             json.dump(self.to_chrome(), f)
             f.write("\n")
         return path
+
+
+# -- the span API -------------------------------------------------------------
+
+
+def profiling() -> bool:
+    """Whether a profiler session is recording host annotations now."""
+    return TraceAnnotation.is_enabled()
+
+
+class Span:
+    """Scoped span: a ``jax.profiler.TraceAnnotation`` always, and with a
+    ``recorder`` one complete ("X") ring event at exit.
+
+    ``step`` makes it a step annotation (``StepTraceAnnotation`` with
+    ``step_num=step``).  ``meta`` is the span's metadata; :meth:`set` adds
+    metadata known only inside the span, written at exit.  Metadata values
+    are numbers or strings (a profiler stat each), so callers compute them
+    only while :func:`profiling` or a recorder is attached."""
+
+    __slots__ = ("name", "meta", "_rec", "_pid", "_tid", "_ann", "_late", "_t0")
+
+    def __init__(self, name: str, recorder: Optional[TraceRecorder] = None, *,
+                 pid: int = PID_ENGINE, tid: int = 0,
+                 step: Optional[int] = None, **meta):
+        if step is not None:
+            meta["step_num"] = step
+            self._ann = StepTraceAnnotation(name, **meta)
+        else:
+            self._ann = TraceAnnotation(name, **meta)
+        self.name = name
+        self.meta = meta
+        self._rec = recorder
+        self._pid = pid
+        self._tid = tid
+        self._late: Optional[Dict[str, Any]] = None
+
+    def set(self, **meta):
+        if self._late is None:
+            self._late = meta
+        else:
+            self._late.update(meta)
+
+    def __enter__(self) -> "Span":
+        self._ann.__enter__()
+        if self._rec is not None:
+            self._t0 = self._rec.clock()
+        return self
+
+    def __exit__(self, *exc):
+        late = self._late
+        if late:
+            self._ann.set_metadata(**late)
+        self._ann.__exit__(*exc)
+        rec = self._rec
+        if rec is not None:
+            args = {**self.meta, **late} if late else self.meta
+            rec._push(TraceEvent(
+                self.name, "X", self._t0, self._pid, self._tid,
+                dur=rec.clock() - self._t0, args=args or None,
+            ))
+        return False
+
+
+def instant(name: str, recorder: Optional[TraceRecorder] = None, *,
+            pid: int = PID_ENGINE, tid: int = 0, **meta):
+    """A point event: a zero-length profiler annotation, and with a
+    ``recorder`` an instant ("i") ring event."""
+    with TraceAnnotation(name, **meta):
+        pass
+    if recorder is not None:
+        recorder._push(TraceEvent(
+            name, "i", recorder.clock(), pid, tid, args=meta or None
+        ))

@@ -53,11 +53,13 @@ from repro.obs.telemetry import (
     prefill_block_candidates,
 )
 from repro.obs.trace import (
-    PID_ENGINE,
     PID_KERNEL,
     PID_MEMORY,
     PID_SCHED,
+    Span,
     TraceRecorder,
+    instant,
+    profiling,
 )
 from repro.resilience import (
     DEVICE_FAULTS,
@@ -125,12 +127,15 @@ class CompiledStep:
     argument signature, inside the engine's sharding context, so a failure
     to compile raises :class:`StepCompileError` and only a failure to RUN
     reaches the ladder as a step fault.  ``compile_seconds`` accumulates
-    the time spent compiling."""
+    the time spent compiling; each compile is an ``engine.compile`` span
+    (on ``recorder()``'s ring too, where that returns one)."""
 
-    def __init__(self, name: str, fn, ctx, donate_argnums=()):
+    def __init__(self, name: str, fn, ctx, donate_argnums=(),
+                 recorder: Callable[[], Optional[TraceRecorder]] = lambda: None):
         self._jit = jax.jit(fn, donate_argnums=donate_argnums)
         self._ctx = ctx
         self._name = name
+        self._recorder = recorder
         self._compiled: Dict[tuple, Callable] = {}
         self.compile_seconds = 0.0
 
@@ -140,7 +145,9 @@ class CompiledStep:
         if exe is None:
             t0 = time.perf_counter()
             try:
-                with self._ctx():
+                with Span("engine.compile", self._recorder(),
+                          program=self._name,
+                          signatures=len(self._compiled) + 1), self._ctx():
                     exe = self._jit.lower(*args).compile()
             except Exception as exc:
                 raise StepCompileError(
@@ -164,7 +171,6 @@ _COUNTER_KEYS = {
     "pool": ("used_pages", "free_pages"),
     "queue": ("waiting", "running"),
     "residency": ("hbm_pages", "host_pages"),
-    "resilience": ("retries", "degradations", "requests_failed"),
 }
 
 
@@ -179,7 +185,7 @@ class Engine:
         mesh=None,
         shard_rules: Optional[Dict] = None,
         trace: Optional[TraceRecorder] = None,
-        telemetry: Optional[bool] = None,
+        telemetry: bool = False,
     ):
         """Batch capacity and context length come from ``serve_cfg``
         (``ServeConfig.max_batch`` / ``ServeConfig.max_context``) — the
@@ -200,12 +206,16 @@ class Engine:
         ``trace`` (a :class:`~repro.obs.trace.TraceRecorder`) turns on
         timeline recording across every subsystem — scheduler, engine,
         memory manager, prefix cache all emit through this one recorder.
-        ``telemetry`` turns on device-side sparsity counters (defaults to
-        following ``trace``; requires the sparse decode path): the decode
-        step emits a per-layer ``[blocks, pages, forced, budget]`` array
-        that rides along on the host transfers the engine already makes.
-        Both default OFF, and when off the cache carries no telemetry
-        entries at all — the traced/untraced compiled steps are identical.
+        The engine's spans (:data:`repro.obs.trace.SPAN_NAMES`) are profiler
+        annotations in every run, recorder or not.  Two step counters run
+        in every run too, so the compiled steps are the same traced or
+        not: the KV pages each decode layer read per slot, and the sparse
+        prefill blocks each layer attended (a running total).  They are
+        read only while a recorder, a profiler session or telemetry is on,
+        and then at the tick's own token reads: they add no device sync.
+        ``telemetry`` (default OFF; requires the sparse decode path) turns
+        on the opt-in counters: the decode step then emits a per-layer
+        ``[blocks, pages, forced, budget]`` array — a step of its own.
         """
         self.cfg = model_cfg
         self.serve = serve_cfg
@@ -290,6 +300,27 @@ class Engine:
         self.steps: Dict[str, CompiledStep] = {}
 
         self.cache = self.model.init_cache(self.max_batch, self.max_context)
+        self._chunkable = (
+            serve_cfg.prefill_chunk > 0
+            and self.model.supports_chunked_prefill()
+        )
+        #: sparse prefill active => chunk boundaries and reused prefix spans
+        #: must align to the query-block size (chunked selection is then
+        #: token-identical to single-shot sparse prefill).
+        self._sparse_prefill = (
+            model_cfg.sparse.sparse_prefill
+            and self._chunkable
+            and self.model.use_sparse(self.max_context)
+        )
+        L = model_cfg.n_layers
+        # the step counters (see the docstring above): planted whether or
+        # not anything reads them, so tracing never changes a program.
+        if self.model.use_sparse(self.max_context):
+            self.cache["_decode_pages"] = jnp.zeros(
+                (L, self.max_batch), jnp.int32
+            )
+        if self._sparse_prefill:
+            self.cache["_prefill_blocks"] = jnp.zeros((L,), jnp.uint32)
         if mesh is not None:
             # allocate device state mesh-wide: KV pool batch x kv-head
             # sharded, store codes batch-sharded (ragged rows whole), plan
@@ -309,10 +340,6 @@ class Engine:
         self.metrics.trace = trace
         # last emitted value per counter track (see _trace_counters dedup).
         self._last_counters: Dict[str, tuple] = {}
-        self._chunkable = (
-            serve_cfg.prefill_chunk > 0
-            and self.model.supports_chunked_prefill()
-        )
         self.prefix_cache = (
             PrefixCache(self.pool)
             if (serve_cfg.enable_prefix_cache and self._chunkable)
@@ -320,14 +347,6 @@ class Engine:
         )
         if self.prefix_cache is not None:
             self.prefix_cache.trace = trace
-        #: sparse prefill active => chunk boundaries and reused prefix spans
-        #: must align to the query-block size (chunked selection is then
-        #: token-identical to single-shot sparse prefill).
-        self._sparse_prefill = (
-            model_cfg.sparse.sparse_prefill
-            and self._chunkable
-            and self.model.use_sparse(self.max_context)
-        )
         self.scheduler = Scheduler(
             serve_cfg, self.pool, self.prefix_cache, self.metrics,
             chunkable=self._chunkable,
@@ -371,10 +390,25 @@ class Engine:
             self.cache["_pre_pages"] = jnp.zeros((self.max_batch, nP), bool)
             self.memory = MemoryManager(self, self.pool)
         # opt-in device-side sparsity telemetry (repro.obs): plant the
-        # per-layer counter outputs so the jit'd steps emit them; they ride
-        # along on the per-tick host syncs (zero extra transfers when off).
+        # per-layer counter outputs so the jit'd decode step emits them.
         self._telemetry_on = False
-        self._plan_layouts = None
+        # -- reading the step counters (see _read_prefill_blocks) -------------
+        #: whether this tick reads the counters and fills span metadata
+        self._obs = False
+        #: host value of the prefill block total at its last read; None
+        #: while no read since observing began gives a baseline.
+        self._blocks_seen: Optional[np.ndarray] = (
+            np.zeros((L,), np.uint32) if self._sparse_prefill else None
+        )
+        #: causal candidate blocks and chunks dispatched since that read
+        self._cands_unread = np.zeros((L,), np.int64)
+        self._chunks_unread = 0
+        #: metadata the counters add to the current tick's span
+        self._tick_meta: Dict[str, int] = {}
+        self._plan_layouts = (
+            self.model.attention_plan(self.max_context).layouts
+            if self._sparse_prefill else None
+        )
         # raw (ts, tel, slots) samples awaiting export-time materialization
         # into "sparsity" counter events (see _flush_sparsity_counters).
         self._tel_pending: List[tuple] = []
@@ -398,15 +432,16 @@ class Engine:
     def set_tracing(
         self,
         trace: Optional[TraceRecorder],
-        telemetry: Optional[bool] = None,
+        telemetry: bool = False,
     ):
         """Attach/detach the trace recorder and device-side telemetry on a
-        live engine.  Telemetry toggling adds/removes the counter entries
-        from the decode cache, which swaps the jit'd step signature — the
-        first tick after a toggle compiles that variant unless it already
-        ran.  The overhead benchmark uses this to A/B traced vs untraced on
-        ONE engine (same params / cache buffers), which removes per-engine
-        allocation bias from the comparison."""
+        live engine.  A recorder leaves every compiled step as it is.
+        Telemetry toggling adds/removes the counter entries from the decode
+        cache, which swaps the jit'd step signature — the first tick after
+        a toggle compiles that variant unless it already ran.  The overhead
+        benchmark uses this to A/B traced vs untraced on ONE engine (same
+        params / cache buffers), which removes per-engine allocation bias
+        from the comparison."""
         self.trace = trace
         self.metrics.trace = trace
         self._last_counters = {}
@@ -415,8 +450,6 @@ class Engine:
                 lambda t=trace: self._flush_sparsity_counters(t)
             )
             self._tel_flush_recorder = trace
-        if telemetry is None:
-            telemetry = trace is not None
         on = bool(telemetry and self.model.use_sparse(self.max_context))
         if on == self._telemetry_on:
             return
@@ -427,17 +460,10 @@ class Engine:
             self.cache["_telemetry"] = jnp.zeros(
                 (L, self.max_batch, N_COUNTERS), jnp.int32
             )
-            if self._sparse_prefill:
-                self.cache["_ptel"] = jnp.zeros((L,), jnp.int32)
             if self.metrics.sparsity is None:
                 self.metrics.sparsity = SparsityAggregate(L)
-            if self._plan_layouts is None:
-                self._plan_layouts = self.model.attention_plan(
-                    self.max_context
-                ).layouts
         else:
             self.cache.pop("_telemetry", None)
-            self.cache.pop("_ptel", None)
 
     # -- fault injection / degradation ladder (repro.resilience) -------------
 
@@ -648,7 +674,8 @@ class Engine:
         return sharding_rules(self.mesh, self.shard_rules)
 
     def _step(self, name: str, fn, donate_argnums=()) -> CompiledStep:
-        step = CompiledStep(name, fn, self._shard_ctx, donate_argnums)
+        step = CompiledStep(name, fn, self._shard_ctx, donate_argnums,
+                            recorder=lambda: self.trace)
         self.steps[name] = step
         return step
 
@@ -761,30 +788,27 @@ class Engine:
         n = len(ch.tokens)
         buf = np.zeros((self._chunk_len,), np.int32)
         buf[:n] = ch.tokens
-        ctx = (
-            self.trace.span(
-                "prefill.chunk", PID_ENGINE,
-                args={"seq": seq.seq_id, "offset": ch.offset, "tokens": n},
-            )
-            if self.trace is not None
-            else nullcontext()
+        meta = (
+            {"seq": seq.seq_id, "offset": ch.offset, "tokens": n}
+            if self._obs else {}
         )
-        with ctx:
+        with Span("prefill.chunk", self.trace, **meta):
             logits, self.cache = self._rung_step_fns(rung)[1](
                 self.params, self.cache, np.int32(seq.slot), buf,
                 np.int32(ch.offset), np.int32(n),
             )
-            if self._telemetry_on and self._sparse_prefill:
-                attended = np.asarray(self.cache["_ptel"])
-                cands = prefill_block_candidates(
+            if self._obs and self._sparse_prefill:
+                # the denominator of the attended share; the numerator is
+                # the device counter, read at the next token read.
+                self._cands_unread += prefill_block_candidates(
                     self._plan_layouts, ch.offset, n,
                     self.cfg.sparse.prefill_block_q,
                 )
-                self.metrics.on_prefill_sparsity(attended, cands)
-        self._seq_len[seq.slot] = ch.offset + n
-        self.metrics.on_prefill(n)
-        if ch.is_last:
-            self._finish_prefill(seq, logits[None])
+                self._chunks_unread += 1
+            self._seq_len[seq.slot] = ch.offset + n
+            self.metrics.on_prefill(n)
+            if ch.is_last:
+                self._finish_prefill(seq, logits[None])
 
     def _prefill_monolithic(self, seq: SeqState):
         """Fallback for models without chunked-prefill support (recurrent /
@@ -848,51 +872,62 @@ class Engine:
         :class:`SamplerAnomaly` before the refresh / prefix-cache insert
         side effects, so a ladder re-run of the chunk starts from the same
         state the failed attempt saw."""
-        if seq.replay:
-            # resumed: the first committed token is the next decode input;
-            # its sample was already taken in the original run, so the
-            # prompt logits are discarded (the remaining replay tokens are
-            # drained by _decode_tick, one forced input per tick).
-            tok = seq.replay.pop(0)
-            self.metrics.on_replay_token(seq.seq_id)
-            resumed = True
-        else:
-            first, fin = self._sample(
-                self.key,
-                np.asarray([seq.seq_id], np.int32),
-                np.asarray([len(seq.req.output)], np.int32),
-                logits,
-            )
-            if not bool(np.asarray(fin)[0]):
-                self.metrics.on_sampler_anomaly(1)
-                raise SamplerAnomaly([seq.seq_id], detail="prefill logits")
-            tok = int(np.asarray(first)[0])
-            resumed = False
-        if self.scheduler._seq_chunkable(seq):
-            if self.model.use_sparse(self.max_context):
-                self.cache = self._refresh(
-                    self.cache, np.int32(seq.slot)
-                )
-            if self.prefix_cache is not None:
-                tokens = seq.prefill_tokens
-                n_pages = len(tokens) // self.pool.page_size
-                if n_pages:
-                    pages = self.pool.table(seq.seq_id).physical[:n_pages]
-                    self.prefix_cache.insert(
-                        tokens, pages, self._page_snapshot_fn(seq.slot, n_pages)
+        with Span("prefill.finish", self.trace,
+                  **({"seq": seq.seq_id} if self._obs else {})):
+            if seq.replay:
+                # resumed: the first committed token is the next decode
+                # input; its sample was already taken in the original run,
+                # so the prompt logits are discarded (the remaining replay
+                # tokens are drained by _decode_tick, one forced input per
+                # tick).
+                tok = seq.replay.pop(0)
+                self.metrics.on_replay_token(seq.seq_id)
+                resumed = True
+            else:
+                self._copy_counters("_prefill_blocks")
+                with Span("engine.sample", self.trace):
+                    first, fin = self._sample(
+                        self.key,
+                        np.asarray([seq.seq_id], np.int32),
+                        np.asarray([len(seq.req.output)], np.int32),
+                        logits,
                     )
-        if not resumed:
-            seq.req.output.append(tok)
-            self.metrics.on_first_token(seq.seq_id)
-            self.metrics.on_decode_token(seq.seq_id)
-        self._tokens_buf[seq.slot] = tok
-        seq.state = DECODE
-        if self._is_finished(seq):
-            self._retire(seq)
-        else:
-            # checkpoint on decode entry: every restorable sequence carries
-            # a watermark from its first committed token on.
-            self._take_checkpoint(seq)
+                with Span("engine.wait", self.trace):
+                    finite = bool(np.asarray(fin)[0])
+                    tok = int(np.asarray(first)[0])
+                    self._read_prefill_blocks()
+                if not finite:
+                    self.metrics.on_sampler_anomaly(1)
+                    raise SamplerAnomaly(
+                        [seq.seq_id], detail="prefill logits"
+                    )
+                resumed = False
+            if self.scheduler._seq_chunkable(seq):
+                if self.model.use_sparse(self.max_context):
+                    self.cache = self._refresh(
+                        self.cache, np.int32(seq.slot)
+                    )
+                if self.prefix_cache is not None:
+                    tokens = seq.prefill_tokens
+                    n_pages = len(tokens) // self.pool.page_size
+                    if n_pages:
+                        table = self.pool.table(seq.seq_id)
+                        self.prefix_cache.insert(
+                            tokens, table.physical[:n_pages],
+                            self._page_snapshot_fn(seq.slot, n_pages),
+                        )
+            if not resumed:
+                seq.req.output.append(tok)
+                self.metrics.on_first_token(seq.seq_id)
+                self.metrics.on_decode_token(seq.seq_id)
+            self._tokens_buf[seq.slot] = tok
+            seq.state = DECODE
+            if self._is_finished(seq):
+                self._retire(seq)
+            else:
+                # checkpoint on decode entry: every restorable sequence
+                # carries a watermark from its first committed token on.
+                self._take_checkpoint(seq)
 
     def _page_snapshot_fn(self, slot: int, n_pages: int):
         """Lazy host snapshot of one slot's prompt-span KV, sliced per page
@@ -903,14 +938,19 @@ class Engine:
         def fn(i: int):
             if not memo:
                 entry = self.cache["pos0"]
-                if entry["k"].ndim == 6:  # paged cache: slice whole pages
-                    memo["k"] = np.asarray(entry["k"][:, slot, :, :n_pages])
-                    memo["v"] = np.asarray(entry["v"][:, slot, :, :n_pages])
-                    memo["paged"] = True
-                else:
-                    memo["k"] = np.asarray(entry["k"][:, slot, :, : n_pages * ps])
-                    memo["v"] = np.asarray(entry["v"][:, slot, :, : n_pages * ps])
-                    memo["paged"] = False
+                with Span("engine.wait", self.trace):
+                    if entry["k"].ndim == 6:  # paged cache: whole pages
+                        memo["k"] = np.asarray(entry["k"][:, slot, :, :n_pages])
+                        memo["v"] = np.asarray(entry["v"][:, slot, :, :n_pages])
+                        memo["paged"] = True
+                    else:
+                        memo["k"] = np.asarray(
+                            entry["k"][:, slot, :, : n_pages * ps]
+                        )
+                        memo["v"] = np.asarray(
+                            entry["v"][:, slot, :, : n_pages * ps]
+                        )
+                        memo["paged"] = False
             if memo["paged"]:
                 return {"k": memo["k"][:, :, i], "v": memo["v"][:, :, i]}
             return {
@@ -955,6 +995,7 @@ class Engine:
         logits, self.cache = self._rung_step_fns(rung)[0](
             self.params, self.cache, jnp.asarray(self._tokens_buf)
         )
+        self._copy_counters("_decode_pages", "_prefill_blocks")
         if self._fault is not None:
             rows = self._fault.poison_rows(
                 self.metrics.ticks, [(s.seq_id, s.slot) for s in active]
@@ -968,9 +1009,17 @@ class Engine:
         for s in active:
             sids[s.slot] = s.seq_id
             poss[s.slot] = len(s.req.output)
-        toks, fin = self._sample(self.key, sids, poss, logits)
-        res["tokens"] = np.asarray(toks)
-        res["finite"] = np.asarray(fin)
+        with Span("engine.sample", self.trace):
+            toks, fin = self._sample(self.key, sids, poss, logits)
+        with Span("engine.wait", self.trace):
+            res["tokens"] = np.asarray(toks)
+            res["finite"] = np.asarray(fin)
+            if self._obs:
+                pages = self.cache.get("_decode_pages")
+                if pages is not None:
+                    live = [s.slot for s in active]
+                    res["pages"] = int(np.asarray(pages)[:, live].sum())
+                self._read_prefill_blocks()
         bad = [s.seq_id for s in active if not res["finite"][s.slot]]
         if bad:
             self.metrics.on_sampler_anomaly(len(bad))
@@ -982,107 +1031,178 @@ class Engine:
         ]
         if not active:
             return 0
-        mem = self.memory
-        if mem is not None:
-            # {logical: physical} pages whose bytes sit in the host tier at
-            # step launch; a selection overlapping them read poison and the
-            # sequence must stall and re-run.
-            host_before = {
-                s.seq_id: mem.pool.host_resident_logical(s.seq_id)
-                for s in active
+        meta = {}
+        if self._obs:
+            meta = {
+                "slots": len(active),
+                "seqs": " ".join(str(s.seq_id) for s in active),
+                "live_tokens": int(
+                    sum(int(self._seq_len[s.slot]) + 1 for s in active)
+                ),
             }
-        res: Dict[str, np.ndarray] = {}
-        self._with_ladder(
-            lambda exc: (
-                [s for s in active if s.seq_id in exc.seq_ids]
-                if isinstance(exc, SamplerAnomaly)
-                else list(active)
-            ),
-            lambda rung: self._attempt_decode(rung, active, res),
-        )
-        if "tokens" not in res:
-            # a device fault reached the ladder floor before any attempt
-            # produced tokens: every implicated sequence was restored or
-            # failed above; there is nothing to commit this tick.
-            return len(active)
-        nt, fin = res["tokens"], res["finite"]
-        if mem is not None:
-            sel = np.asarray(self.cache["_sel_pages"])
-            pre = np.asarray(self.cache["_pre_pages"])
-        if self._telemetry_on:
-            # ONE owned copy upfront: np.asarray alone returns a zero-copy
-            # view of the device buffer, and every downstream read of that
-            # view (fancy indexing, reductions) pays uncached-memory cost —
-            # in situ that is several times the price of this 256-byte copy.
-            # Everything downstream is deferred off the tick: the metrics
-            # aggregate folds lazily at snapshot time, and the per-step
-            # trace counters are queued raw and materialized by the
-            # recorder's export-time flush hook (_flush_sparsity_counters).
-            tel = np.array(self.cache["_telemetry"])     # [L, B, 4] owned
-            live_slots = [s.slot for s in active]
-            self.metrics.on_sparsity(tel, live_slots, owned=True)
-            if self.trace is not None:
-                self._tel_pending.append(
-                    (self.trace.clock(), tel, live_slots)
-                )
-        for seq in active:
-            slot = seq.slot
-            if self.scheduler.running.get(seq.seq_id) is not seq or slot < 0:
-                continue    # restored / failed at the ladder floor
-            if not fin[slot]:
-                continue    # anomalous row (already charged above)
-            if mem is not None and not mem.on_step(
-                seq,
-                np.nonzero(sel[slot])[0],
-                np.nonzero(pre[slot])[0],
-                host_before[seq.seq_id],
-            ):
-                # host-tier miss: discard the sampled token, don't advance —
-                # next tick re-runs this slot's step byte-identically once
-                # the missing pages are promoted.  Only this sequence
-                # stalls; the rest of the batch commits below.
-                continue
-            if seq.replay:
-                # resume replay: this step rebuilt one committed token's KV
-                # through the decode path (byte-identical by induction); the
-                # sampled token is discarded and the next committed token is
-                # forced as input.  Once the queue drains, the following
-                # step's sample lands at position len(output) with the same
-                # fold_in key the original run would have used.
-                self._tokens_buf[slot] = seq.replay.pop(0)
-                self._seq_len[slot] += 1
-                self.metrics.on_replay_token(seq.seq_id)
-                continue
-            tok = int(nt[slot])
-            seq.req.output.append(tok)
-            self._tokens_buf[slot] = tok
-            self._seq_len[slot] += 1
-            self.metrics.on_decode_token(seq.seq_id)
-            if self._is_finished(seq):
-                self._retire(seq)
-            else:
-                ck = seq.checkpoint
-                if ck is None or (
-                    len(seq.req.output) - ck.n_output
-                    >= self.resilience.checkpoint_interval
-                ):
-                    self._take_checkpoint(seq)
-        # host lengths are authoritative (the batched step incremented
-        # every slot, including ones still prefilling or stalled).
-        self.cache = dict(self.cache)
-        self.cache["seq_len"] = jnp.asarray(self._seq_len)
+        with Span("engine.decode", self.trace, **meta) as sp:
+            mem = self.memory
+            if mem is not None:
+                # {logical: physical} pages whose bytes sit in the host tier
+                # at step launch; a selection overlapping them read poison
+                # and the sequence must stall and re-run.
+                host_before = {
+                    s.seq_id: mem.pool.host_resident_logical(s.seq_id)
+                    for s in active
+                }
+            res: Dict[str, object] = {}
+            self._with_ladder(
+                lambda exc: (
+                    [s for s in active if s.seq_id in exc.seq_ids]
+                    if isinstance(exc, SamplerAnomaly)
+                    else list(active)
+                ),
+                lambda rung: self._attempt_decode(rung, active, res),
+            )
+            if "tokens" not in res:
+                # a device fault reached the ladder floor before any attempt
+                # produced tokens: every implicated sequence was restored or
+                # failed above; there is nothing to commit this tick.
+                return len(active)
+            if "pages" in res:
+                sp.set(pages=res["pages"])
+            nt, fin = res["tokens"], res["finite"]
+            if mem is not None:
+                with Span("engine.wait", self.trace):
+                    sel = np.asarray(self.cache["_sel_pages"])
+                    pre = np.asarray(self.cache["_pre_pages"])
+            if self._telemetry_on:
+                # ONE owned copy upfront: np.asarray alone returns a
+                # zero-copy view of the device buffer, and every downstream
+                # read of that view (fancy indexing, reductions) pays
+                # uncached-memory cost — in situ that is several times the
+                # price of this 256-byte copy.  Everything downstream is
+                # deferred off the tick: the metrics aggregate folds lazily
+                # at snapshot time, and the per-step trace counters are
+                # queued raw and materialized by the recorder's export-time
+                # flush hook (_flush_sparsity_counters).
+                with Span("engine.wait", self.trace):
+                    tel = np.array(self.cache["_telemetry"])     # [L, B, 4]
+                live_slots = [s.slot for s in active]
+                self.metrics.on_sparsity(tel, live_slots, owned=True)
+                if self.trace is not None:
+                    self._tel_pending.append(
+                        (self.trace.clock(), tel, live_slots)
+                    )
+            with Span("engine.commit", self.trace):
+                for seq in active:
+                    slot = seq.slot
+                    if (self.scheduler.running.get(seq.seq_id) is not seq
+                            or slot < 0):
+                        continue    # restored / failed at the ladder floor
+                    if not fin[slot]:
+                        continue    # anomalous row (already charged above)
+                    if mem is not None and not mem.on_step(
+                        seq,
+                        np.nonzero(sel[slot])[0],
+                        np.nonzero(pre[slot])[0],
+                        host_before[seq.seq_id],
+                    ):
+                        # host-tier miss: discard the sampled token, don't
+                        # advance — next tick re-runs this slot's step
+                        # byte-identically once the missing pages are
+                        # promoted.  Only this sequence stalls; the rest of
+                        # the batch commits below.
+                        continue
+                    if seq.replay:
+                        # resume replay: this step rebuilt one committed
+                        # token's KV through the decode path (byte-identical
+                        # by induction); the sampled token is discarded and
+                        # the next committed token is forced as input.  Once
+                        # the queue drains, the following step's sample
+                        # lands at position len(output) with the same
+                        # fold_in key the original run would have used.
+                        self._tokens_buf[slot] = seq.replay.pop(0)
+                        self._seq_len[slot] += 1
+                        self.metrics.on_replay_token(seq.seq_id)
+                        continue
+                    tok = int(nt[slot])
+                    seq.req.output.append(tok)
+                    self._tokens_buf[slot] = tok
+                    self._seq_len[slot] += 1
+                    self.metrics.on_decode_token(seq.seq_id)
+                    if self._is_finished(seq):
+                        self._retire(seq)
+                    else:
+                        ck = seq.checkpoint
+                        if ck is None or (
+                            len(seq.req.output) - ck.n_output
+                            >= self.resilience.checkpoint_interval
+                        ):
+                            self._take_checkpoint(seq)
+                # host lengths are authoritative (the batched step
+                # incremented every slot, including ones still prefilling or
+                # stalled).
+                self.cache = dict(self.cache)
+                self.cache["seq_len"] = jnp.asarray(self._seq_len)
         return len(active)
 
     def step(self) -> int:
         """One engine tick: admit, prefill chunks, decode, retire.
         Returns the number of occupied slots."""
+        self._obs = obs = (
+            self.trace is not None or self._telemetry_on or profiling()
+        )
+        if not obs:
+            self._forget_counters()
+        m = self.metrics
+        p0 = m.prefill_tokens_computed
+        self._tick_meta = {}
+        with Span("engine.tick", self.trace, step=m.ticks) as sp:
+            n = self._step_body()
+            if obs:
+                sp.set(prefill_tokens=m.prefill_tokens_computed - p0,
+                       **self._tick_meta)
         if self.trace is not None:
-            with self.trace.span("engine.tick", PID_ENGINE,
-                                 args={"tick": self.metrics.ticks}):
-                n = self._step_body()
             self._trace_counters()
-            return n
-        return self._step_body()
+        return n
+
+    # -- step counters ---------------------------------------------------------
+
+    def _copy_counters(self, *keys: str):
+        """Start the host copies of the step counters a token read is about
+        to take (only while observing; the read itself stays where the
+        tick already waits)."""
+        if self._obs:
+            for k in keys:
+                c = self.cache.get(k)
+                if c is not None:
+                    c.copy_to_host_async()
+
+    def _forget_counters(self):
+        """Drop the unread counter state: the next read sets a baseline."""
+        self._blocks_seen = None
+        self._cands_unread[:] = 0
+        self._chunks_unread = 0
+
+    def _read_prefill_blocks(self):
+        """At a token read while observing: fold the growth of the sparse
+        prefill block total since the last read, with the candidate blocks
+        of the chunks dispatched meanwhile, into this tick's metadata (and
+        the telemetry aggregate).  The first read after a break in
+        observing only sets the baseline."""
+        c = self.cache.get("_prefill_blocks")
+        if not self._obs or c is None:
+            return
+        now = np.asarray(c)
+        seen, self._blocks_seen = self._blocks_seen, now
+        chunks, cands = self._chunks_unread, self._cands_unread.copy()
+        self._cands_unread[:] = 0
+        self._chunks_unread = 0
+        if seen is None or not chunks:
+            return
+        attended = (now - seen).astype(np.int64)   # uint32: wraps correctly
+        self.metrics.on_prefill_sparsity(attended, cands, chunks)
+        meta = self._tick_meta
+        meta["prefill_blocks"] = meta.get("prefill_blocks", 0) + int(
+            attended.sum())
+        meta["prefill_candidates"] = meta.get("prefill_candidates", 0) + int(
+            cands.sum())
 
     def _flush_sparsity_counters(self, trace: TraceRecorder):
         """Materialize queued per-step sparsity samples into "sparsity"
@@ -1120,10 +1240,6 @@ class Engine:
             ("pool", PID_MEMORY, (self.pool.used_pages, self.pool.free_pages)),
             ("queue", PID_SCHED,
              (len(self.scheduler.waiting), len(self.scheduler.running))),
-            ("resilience", PID_ENGINE,
-             (self.metrics.retries,
-              sum(self.metrics.degradations.values()),
-              len(self.metrics.requests_failed))),
         ) + ((
             ("residency", PID_MEMORY,
              (self.metrics.hbm_resident_pages,
@@ -1161,12 +1277,8 @@ class Engine:
         if not running:
             return
         victim = self.scheduler.choose_victim(running)
-        if self.trace is not None:
-            self.trace.instant(
-                "engine.watchdog", PID_ENGINE,
-                args={"victim_seq": victim.seq_id,
-                      "idle_ticks": self.resilience.watchdog_ticks},
-            )
+        instant("engine.watchdog", self.trace, victim_seq=victim.seq_id,
+                idle_ticks=self.resilience.watchdog_ticks)
         self.scheduler.preempt(victim)
         if self.memory is not None:
             self.memory.forget(victim.seq_id)
@@ -1229,25 +1341,23 @@ class Engine:
             ]
             if starved:
                 victim = self.scheduler.choose_victim(starved)
-                if self.trace is not None:
-                    self.trace.instant(
-                        "mem.starvation_breaker", PID_MEMORY,
-                        args={"victim_seq": victim.seq_id},
-                    )
+                instant("mem.starvation_breaker", self.trace, pid=PID_MEMORY,
+                        victim_seq=victim.seq_id)
                 self.scheduler.preempt(victim)
                 self.memory.forget(victim.seq_id)
                 self.slots[victim.slot] = None
                 self._seq_len[victim.slot] = 0
                 victim.slot = -1
         free = [i for i, s in enumerate(self.slots) if s is None]
-        plan = self.scheduler.plan_tick(free)
+        with Span("sched.plan", self.trace, pid=PID_SCHED) as sp:
+            plan = self.scheduler.plan_tick(free)
+            if self._obs:
+                sp.set(admitted=len(plan.admitted), chunks=len(plan.chunks))
         for adm in plan.admitted:
-            if self.trace is not None:
-                self.trace.instant(
-                    "sched.admit", PID_SCHED,
-                    args={"seq": adm.seq.seq_id, "slot": adm.slot,
-                          "prefix_tokens": adm.prefix_tokens},
-                )
+            if self._obs:
+                instant("sched.admit", self.trace, pid=PID_SCHED,
+                        seq=adm.seq.seq_id, slot=adm.slot,
+                        prefix_tokens=adm.prefix_tokens)
             self._install(adm)
         for ch in plan.chunks:
             self._run_chunk(ch)
@@ -1266,11 +1376,9 @@ class Engine:
             self.slots[seq.slot] = None
             self._seq_len[seq.slot] = 0
             seq.slot = -1
-        if self.trace is not None:
-            with self.trace.span("engine.decode", PID_ENGINE):
-                decoded = self._decode_tick()
-        else:
-            decoded = self._decode_tick()
+        decoded = self._decode_tick()
+        if self._obs:
+            self._tick_meta["decoding"] = decoded
         if self.memory is not None:
             self.memory.end_tick()
         return decoded

@@ -368,10 +368,11 @@ class ServingMetrics:
         if self.sparsity is not None:
             self.sparsity.update_decode(tel, slots, owned=owned)
 
-    def on_prefill_sparsity(self, attended, candidates=None):
-        """Fold one prefill chunk's per-layer attended-block counts."""
+    def on_prefill_sparsity(self, attended, candidates=None, chunks=1):
+        """Fold the per-layer attended-block counts of ``chunks`` prefill
+        chunks."""
         if self.sparsity is not None:
-            self.sparsity.update_prefill(attended, candidates)
+            self.sparsity.update_prefill(attended, candidates, chunks)
 
     # -- aggregation ---------------------------------------------------------
 

@@ -112,15 +112,18 @@ def test_backend_parity_page_tables_and_outputs(quant):
     q, k, v = _qkv()
     seq_len = jnp.array([S, S // 2], jnp.int32)
 
-    outs, tables = {}, {}
+    outs, tables, pages = {}, {}, {}
     for name in ("reference", "pallas"):
         be = get_backend(name)
         store = be.build_store(k, lay, "quest", quant=quant)
-        out, table = be.decode(q, k, v, store, lay, sparse, seq_len=seq_len)
+        out, table, pages[name] = be.decode(
+            q, k, v, store, lay, sparse, seq_len=seq_len
+        )
         outs[name] = np.asarray(out)
         tables[name] = np.asarray(table)
 
     np.testing.assert_array_equal(tables["reference"], tables["pallas"])
+    np.testing.assert_array_equal(pages["reference"], pages["pallas"])
     np.testing.assert_allclose(
         outs["reference"], outs["pallas"], atol=1e-4, rtol=1e-4
     )
@@ -135,14 +138,16 @@ def test_backends_match_dense_oracle_at_full_budget(quant):
     q, k, v = _qkv(seed=1)
 
     dense = get_backend("dense")
-    out_d, table_d = dense.decode(q, k, v, None, lay, sparse)
+    out_d, table_d, pages_d = dense.decode(q, k, v, None, lay, sparse)
     assert table_d is None, "dense oracle has no page table"
+    # full attention reads every page of every KV head
+    assert np.all(np.asarray(pages_d) == k.shape[1] * S // 16)
     out_d = np.asarray(out_d)
 
     for name in ("reference", "pallas"):
         be = get_backend(name)
         store = be.build_store(k, lay, "quest", quant=quant)
-        out, _ = be.decode(q, k, v, store, lay, sparse)
+        out, _, _ = be.decode(q, k, v, store, lay, sparse)
         np.testing.assert_allclose(
             np.asarray(out), out_d, atol=2e-5, rtol=1e-4,
         )

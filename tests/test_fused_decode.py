@@ -47,7 +47,7 @@ def _page_sets(table, valid):
 def _staged_and_fused(lay, cfg, quant, seq_len, seed=0):
     q, k, v = _qkv(seed)
     store = PALLAS.build_store(k, lay, cfg.centroid_method, quant=quant)
-    out_s, _ = PALLAS.decode(q, k, v, store, lay, cfg, seq_len=seq_len)
+    out_s, _, _ = PALLAS.decode(q, k, v, store, lay, cfg, seq_len=seq_len)
     rq = rank_query(q, cfg.centroid_method, D)
     out_f, tbl_f, vld_f = ops.fused_decode(
         q, rq, k, v, store, lay,
@@ -130,9 +130,11 @@ def test_fused_backend_knob_is_config_only():
     store = PALLAS.build_store(k, lay, "quest", quant="int4_asym")
     staged_cfg = SparseConfig(token_budget=512)
     fused_cfg = dataclasses.replace(staged_cfg, fused_decode=True)
-    out_s, _ = PALLAS.decode(q, k, v, store, lay, staged_cfg)
-    out_f, tbl = PALLAS.decode(q, k, v, store, lay, fused_cfg)
+    out_s, _, pages_s = PALLAS.decode(q, k, v, store, lay, staged_cfg)
+    out_f, tbl, pages_f = PALLAS.decode(q, k, v, store, lay, fused_cfg)
     assert tbl.shape == (B, N_KV, lay.selected_pages)
+    # the fused kernel's valid mask counts the pages the staged table reads
+    np.testing.assert_array_equal(np.asarray(pages_s), np.asarray(pages_f))
     np.testing.assert_allclose(
         np.asarray(out_s), np.asarray(out_f), atol=1e-5
     )
@@ -149,8 +151,8 @@ def test_fused_dma_window_covers_oversized_blocks():
     q, k, v = _qkv(seed=11)
     store = PALLAS.build_store(k, lay, "quest", quant="int4_asym")
     seq_len = jnp.array([S, S // 2], jnp.int32)
-    out_s, _ = PALLAS.decode(q, k, v, store, lay, cfg, seq_len=seq_len)
-    out_f, _ = PALLAS.decode(q, k, v, store, lay, fused_cfg, seq_len=seq_len)
+    out_s, _, _ = PALLAS.decode(q, k, v, store, lay, cfg, seq_len=seq_len)
+    out_f, _, _ = PALLAS.decode(q, k, v, store, lay, fused_cfg, seq_len=seq_len)
     np.testing.assert_allclose(
         np.asarray(out_s), np.asarray(out_f), atol=1e-5
     )

@@ -190,9 +190,12 @@ def test_fused_kernel_pipeline_matches_reference_pipeline():
     ref_be = get_backend("reference")
     store_ref = ref_be.build_store(k, lay, "quest", quant="none")
     store_krn = PALLAS.build_store(k, lay, "quest", quant="none")
-    out_ref, tbl_ref = ref_be.decode(q, k, v, store_ref, lay, cfg, seq_len=seq_len)
-    out_krn, tbl_krn = PALLAS.decode(q, k, v, store_krn, lay, cfg, seq_len=seq_len)
+    out_ref, tbl_ref, pg_ref = ref_be.decode(
+        q, k, v, store_ref, lay, cfg, seq_len=seq_len)
+    out_krn, tbl_krn, pg_krn = PALLAS.decode(
+        q, k, v, store_krn, lay, cfg, seq_len=seq_len)
     np.testing.assert_array_equal(np.asarray(tbl_ref), np.asarray(tbl_krn))
+    np.testing.assert_array_equal(np.asarray(pg_ref), np.asarray(pg_krn))
     np.testing.assert_allclose(
         np.asarray(out_ref), np.asarray(out_krn), atol=1e-5
     )

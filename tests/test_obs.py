@@ -5,8 +5,10 @@ schema via the shipped validator, deferred counter flush hooks, lifecycle
 span stack discipline on :class:`ServingMetrics`, and the
 :func:`selection_telemetry` counter math against the selection path it
 mirrors.  Engine layer: one traced serve smoke (spans + deferred sparsity
-counters end-to-end), live ``set_tracing`` toggling, and fused-vs-staged
-decode counter parity.
+counters end-to-end), live ``set_tracing`` toggling, fused-vs-staged
+decode counter parity, the engine's spans in a profiler capture, the
+same compiled steps traced or not, the always-on decode page counter
+against the opt-in telemetry, and one compile span per step signature.
 """
 
 import dataclasses
@@ -36,7 +38,7 @@ from repro.obs import (
     prefill_block_candidates,
     validate_chrome_trace,
 )
-from repro.obs.trace import PID_ENGINE, PID_SEQ
+from repro.obs.trace import PID_ENGINE, PID_SEQ, SPAN_NAMES, Span
 from repro.serving import Engine, Request
 from repro.serving.metrics import ServingMetrics
 
@@ -80,6 +82,18 @@ def test_span_records_complete_event_on_virtual_clock():
     assert outer.ts < inner.ts
     assert outer.ts + outer.dur > inner.ts + inner.dur
     assert outer.args == {"tick": 3}
+
+
+def test_span_metadata_set_inside_reaches_the_ring():
+    rec = TraceRecorder(clock=FakeClock())
+    with Span("engine.tick", rec, step=4, tokens=2) as sp:
+        sp.set(pages=7)
+        sp.set(pages=9, slots=1)
+    with Span("engine.decode"):          # no recorder: annotation only
+        pass
+    (ev,) = rec.events()
+    assert ev.name == "engine.tick" and ev.ph == "X"
+    assert ev.args == {"step_num": 4, "tokens": 2, "pages": 9, "slots": 1}
 
 
 def test_ring_eviction_counts_dropped_and_export_stays_valid():
@@ -308,9 +322,10 @@ def test_traced_engine_produces_valid_trace_and_telemetry(setup, tmp_path):
     )
     rec = TraceRecorder()
     eng = Engine(
-        cfg, params, ServeConfig(max_batch=2, max_context=512), trace=rec
+        cfg, params, ServeConfig(max_batch=2, max_context=512), trace=rec,
+        telemetry=True,
     )
-    assert "_telemetry" in eng.cache          # telemetry follows trace
+    assert "_telemetry" in eng.cache          # telemetry is opt-in
     _run_batch(eng, cfg)
 
     # sparsity counters are DEFERRED: queued on the hot path, materialized
@@ -340,9 +355,13 @@ def test_traced_engine_produces_valid_trace_and_telemetry(setup, tmp_path):
     assert snap["blocks_per_step"] > 0
     assert 0 < snap["budget_utilization"] <= 1.0
     assert 0 <= snap["forced_frac"] <= 1.0
-    # sparse prefill telemetry rode along too.
+    # the sparse prefill block counter, read at the token reads, folded in
+    # every chunk: the same totals the tick spans carry.
     assert snap["prefill_chunks"] > 0
     assert 0 < snap["prefill_blocks_frac"] <= 1.0
+    ticks = [e.args for e in rec.events() if e.name == "engine.tick"]
+    assert sum(a.get("prefill_blocks", 0) for a in ticks) == (
+        snap["prefill_blocks_attended"])
 
 
 def test_set_tracing_toggles_live_engine(setup):
@@ -351,19 +370,27 @@ def test_set_tracing_toggles_live_engine(setup):
     # default OFF: no recorder, no telemetry entries in the decode cache.
     assert eng.trace is None and "_telemetry" not in eng.cache
     _run_batch(eng, cfg, n=2, base_rid=0)
+    decode = eng.steps["decode"]
+    n_programs = len(decode.compiled_texts())
 
     rec = TraceRecorder()
     eng.set_tracing(rec)
-    assert "_telemetry" in eng.cache
+    # a recorder leaves the decode cache, and so the program, as it was
+    assert "_telemetry" not in eng.cache
     _run_batch(eng, cfg, n=2, base_rid=10)
+    assert len(decode.compiled_texts()) == n_programs
     assert len(rec) > 0
     assert validate_chrome_trace(rec.to_chrome()) == []
     # export ran the flush hook, so deferred counters are in the ring now.
     traced_len = len(rec)
 
-    eng.set_tracing(None)
-    assert "_telemetry" not in eng.cache and eng.metrics.trace is None
+    eng.set_tracing(None, telemetry=True)      # the opt-in counters
+    assert "_telemetry" in eng.cache and eng.metrics.trace is None
     _run_batch(eng, cfg, n=2, base_rid=20)
+    assert eng.metrics.snapshot()["sparsity_steps"] > 0
+    eng.set_tracing(None)
+    assert "_telemetry" not in eng.cache
+    _run_batch(eng, cfg, n=2, base_rid=30)
     assert len(rec) == traced_len          # detached recorder stays frozen
 
 
@@ -387,3 +414,136 @@ def test_fused_and_staged_decode_report_identical_counters(setup):
     for key in ("sparsity_steps", "blocks_per_step", "pages_per_step",
                 "budget_utilization", "forced_frac"):
         assert staged[key] == pytest.approx(fused[key]), key
+
+
+def _sparse_prefill_cfg(cfg, **sparse):
+    return dataclasses.replace(
+        cfg, sparse=dataclasses.replace(
+            cfg.sparse, sparse_prefill=True, **sparse)
+    )
+
+
+def _host_spans(log_dir):
+    """(name, start_ns, end_ns, stats) of every engine span in the newest
+    capture under ``log_dir``."""
+    from pathlib import Path
+
+    path = sorted(Path(log_dir).rglob("*.xplane.pb"))[-1]
+    pd = jax.profiler.ProfileData.from_file(str(path))
+    out = []
+    for plane in pd.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in SPAN_NAMES:
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns, dict(ev.stats)))
+    return out
+
+
+def test_profiler_capture_holds_engine_spans_nested_with_metadata(
+        setup, tmp_path):
+    cfg, params = setup
+    cfg = _sparse_prefill_cfg(cfg)
+    # no recorder: the spans reach the capture as profiler annotations
+    eng = Engine(cfg, params, ServeConfig(max_batch=2, max_context=512))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _run_batch(eng, cfg, n=3, new_tokens=4)
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_spans(tmp_path)
+    by = {n: [s for s in spans if s[0] == n] for n in SPAN_NAMES}
+    assert all(by[n] for n in SPAN_NAMES), {n: len(v) for n, v in by.items()}
+
+    def inside(span, parents):
+        return any(p[1] <= span[1] and span[2] <= p[2] for p in parents)
+
+    nesting = {
+        "sched.plan": ("engine.tick",),
+        "prefill.chunk": ("engine.tick",),
+        "engine.decode": ("engine.tick",),
+        "prefill.finish": ("prefill.chunk",),
+        "engine.sample": ("engine.decode", "prefill.finish"),
+        "engine.wait": ("engine.decode", "prefill.finish"),
+        "engine.commit": ("engine.decode",),
+        "engine.compile": ("engine.tick",),
+    }
+    for child, parents in nesting.items():
+        outer = [s for p in parents for s in by[p]]
+        assert all(inside(s, outer) for s in by[child]), child
+
+    ticks = by["engine.tick"]
+    assert sorted(t[3]["step_num"] for t in ticks) == list(range(len(ticks)))
+    assert sum(t[3]["prefill_tokens"] for t in ticks) == 3 * 96
+    assert sum(t[3].get("prefill_blocks", 0) for t in ticks) > 0
+    assert all(t[3].get("prefill_blocks", 0) <= t[3].get(
+        "prefill_candidates", 0) for t in ticks)
+    plans = by["sched.plan"]
+    assert sum(p[3]["admitted"] for p in plans) == 3
+    chunks = by["prefill.chunk"]
+    assert {c[3]["seq"] for c in chunks} == {0, 1, 2}
+    assert sum(c[3]["tokens"] for c in chunks) == 3 * 96
+    assert {f[3]["seq"] for f in by["prefill.finish"]} == {0, 1, 2}
+    decodes = [d[3] for d in by["engine.decode"] if "slots" in d[3]]
+    assert decodes and all(
+        d["pages"] > 0 and d["live_tokens"] > 96 and
+        len(str(d["seqs"]).split()) == d["slots"] for d in decodes)
+    assert {c[3]["program"] for c in by["engine.compile"]} >= {
+        "decode", "prefill_chunk", "sample"}
+
+
+def test_traced_and_untraced_engines_compile_identical_programs(setup):
+    cfg, params = setup
+    cfg = _sparse_prefill_cfg(cfg)
+    texts = []
+    for rec in (None, TraceRecorder()):
+        eng = Engine(cfg, params, ServeConfig(max_batch=2, max_context=512),
+                     trace=rec)
+        _run_batch(eng, cfg, n=2, new_tokens=4)
+        texts.append({name: step.compiled_texts()
+                      for name, step in eng.steps.items()})
+    untraced, traced = texts
+    for name in ("decode", "prefill_chunk"):
+        assert untraced[name], name
+        assert traced[name] == untraced[name], name
+    assert traced == untraced
+
+
+@pytest.mark.parametrize("rung", ["fused", "staged"])
+def test_decode_page_counter_matches_telemetry_pages(setup, rung):
+    cfg, params = setup
+    cfg = dataclasses.replace(cfg, sparse=dataclasses.replace(
+        cfg.sparse, backend="pallas", fused_decode=rung == "fused"))
+    rec = TraceRecorder()
+    eng = Engine(cfg, params,
+                 ServeConfig(max_batch=2, max_context=512, temperature=0.0),
+                 trace=rec, telemetry=True)
+    assert eng._ladder[0][0] == rung
+    _run_batch(eng, cfg, n=2, prompt=80, new_tokens=4)
+    # the opt-in telemetry's PAGES column over the live slots, per tick
+    tel_pages = [int(tel[:, slots, PAGES].sum())
+                 for _, tel, slots in eng._tel_pending]
+    span_pages = [e.args["pages"] for e in rec.events()
+                  if e.name == "engine.decode" and e.args
+                  and "pages" in e.args]
+    assert tel_pages and span_pages == tel_pages
+
+
+def test_compile_span_fires_once_per_new_signature(setup):
+    cfg, params = setup
+    rec = TraceRecorder()
+    eng = Engine(cfg, params, ServeConfig(max_batch=2, max_context=512),
+                 trace=rec)
+    _run_batch(eng, cfg, n=2, new_tokens=4)
+    compiles = [e.args for e in rec.events() if e.name == "engine.compile"]
+    for name, step in eng.steps.items():
+        mine = [a["signatures"] for a in compiles if a["program"] == name]
+        assert mine == list(range(1, len(step.compiled_texts()) + 1)), name
+    assert {a["program"] for a in compiles} == {
+        n for n, s in eng.steps.items() if s.compiled_texts()}
+    # the same shapes again: nothing new compiles, so no new span
+    _run_batch(eng, cfg, n=2, new_tokens=4, base_rid=10)
+    assert sum(e.name == "engine.compile" for e in rec.events()) == len(
+        compiles)

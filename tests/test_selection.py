@@ -77,7 +77,7 @@ def test_sparse_equals_dense_at_full_budget():
     for method in ("mean", "quest", "arkvale"):
         cfg = SparseConfig(token_budget=S, centroid_method=method)
         store = backend.build_store(k, lay, method, quant="none")
-        out_s, _ = backend.decode(q, k, v, store, lay, cfg)
+        out_s, _, _ = backend.decode(q, k, v, store, lay, cfg)
         out_d = dense_decode_attention(q, k, v)
         np.testing.assert_allclose(
             np.asarray(out_s), np.asarray(out_d), atol=2e-5, rtol=1e-4,
