@@ -62,6 +62,14 @@ from repro.core.stacked import LayoutArrays, as_arrays, stack_layouts
 # ---------------------------------------------------------------------------
 
 
+def at_layer(x: jax.Array, layer) -> jax.Array:
+    """Layer ``layer`` of an array stacked over layers; ``x`` itself when
+    ``layer`` is None."""
+    if layer is None:
+        return x
+    return jax.lax.dynamic_index_in_dim(x, layer, keepdims=False)
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclass(frozen=True)
 class CentroidStore:
@@ -103,6 +111,18 @@ class CentroidStore:
             if arr is not None:
                 n += arr.size * arr.dtype.itemsize
         return n
+
+    def at_layer(self, layer) -> "CentroidStore":
+        """Layer ``layer``'s store when ``codes`` is stacked over layers
+        ``[L, B, rows, Cw]``, as the decode step's layer scan carries it
+        (``scale``/``zero`` are that layer's already); ``self`` when
+        ``layer`` is None."""
+        if layer is None:
+            return self
+        return CentroidStore(
+            at_layer(self.codes, layer), self.scale, self.zero,
+            self.bits, self.symmetric,
+        )
 
     def dequantize(self, layout) -> jax.Array:
         """-> ``[B, total_rows, Dp]`` f32 rank keys (reference-path view of
@@ -324,13 +344,16 @@ class AttentionBackend:
         offsets: jax.Array,
         seq_len: jax.Array,
         sparse: SparseConfig,
+        layer=None,
     ) -> CentroidStore:
         """Incremental decode-time update: refresh the rank-key row of the
-        block containing the newest token (frozen affine params)."""
+        block containing the newest token (frozen affine params).  With
+        ``layer`` given, ``k_cache`` and ``store.codes`` are stacked over
+        layers and the row is written in place at ``layer``."""
         from repro.backends.store import refresh_tail_codes
 
         codes = refresh_tail_codes(
-            store, k_cache, layout, offsets, seq_len, sparse
+            store, k_cache, layout, offsets, seq_len, sparse, layer
         )
         return CentroidStore(
             codes, store.scale, store.zero, store.bits, store.symmetric
@@ -468,6 +491,7 @@ class AttentionBackend:
         sparse: SparseConfig,
         seq_len: Optional[jax.Array] = None,
         collect_tel: bool = False,
+        layer=None,
     ) -> Tuple[jax.Array, ...]:
         """Full AB-Sparse decode step: estimation -> adaptive top-k ->
         paged attention.  q [B, n_q, D]; k/v paged
@@ -475,13 +499,17 @@ class AttentionBackend:
         dense ``[B, n_kv, S, D]`` ->
         (out [B, n_q, D], page_table [B, H, P_sel], pages [B] int32), where
         ``pages`` counts the KV pages attention read per slot, summed over
-        heads (the valid entries of the page table).
+        heads (the valid entries of the page table).  With ``layer`` given,
+        k/v and ``store.codes`` are stacked over layers (the decode step's
+        carried pools) and the step attends layer ``layer``; this staged
+        path, a parity oracle, reads a slice of them.
 
         With ``collect_tel=True`` the return gains a fourth element: per-slot
         sparsity counters ``[B, 4]`` (:func:`selection_telemetry`) derived
         from the SAME estimation scores the selection just ranked — no
         second pass over the store, so telemetry costs only a top-k over the
         (small) block-score tensor."""
+        k, v, store = at_layer(k, layer), at_layer(v, layer), store.at_layer(layer)
         la = as_arrays(layout)
         n_kv = k.shape[1]
         rq = rank_query(q, sparse.centroid_method, q.shape[-1])

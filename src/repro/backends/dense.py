@@ -13,6 +13,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.backends.base import at_layer
 from repro.backends.reference import ReferenceBackend
 from repro.core.stacked import as_arrays
 from repro.core.sparse_attention import as_dense, dense_decode_attention
@@ -21,13 +22,16 @@ from repro.core.sparse_attention import as_dense, dense_decode_attention
 class DenseBackend(ReferenceBackend):
     name = "dense"
 
-    def append(self, store, k_cache, layout, offsets, seq_len, sparse):
+    def append(self, store, k_cache, layout, offsets, seq_len, sparse,
+               layer=None):
         # centroids are never read on the dense path; skip the tail refresh.
         return store
 
     def decode(
-        self, q, k, v, store, layout, sparse, seq_len=None, collect_tel=False
+        self, q, k, v, store, layout, sparse, seq_len=None, collect_tel=False,
+        layer=None,
     ) -> Tuple[jax.Array, ...]:
+        k, v = at_layer(k, layer), at_layer(v, layer)
         out = dense_decode_attention(q, as_dense(k), as_dense(v), seq_len=seq_len)
         # full attention reads every live page of every KV head
         la = as_arrays(layout)

@@ -110,16 +110,19 @@ class PallasBackend(AttentionBackend):
             )
 
     def decode(
-        self, q, k, v, store, layout, sparse, seq_len=None, collect_tel=False
+        self, q, k, v, store, layout, sparse, seq_len=None, collect_tel=False,
+        layer=None,
     ) -> Tuple[jax.Array, ...]:
         """Fused single-launch decode when ``sparse.fused_decode`` is set;
         otherwise the shared staged pipeline (the parity oracle).  Under an
         active sharding context the fused launch is shard_map'd over the
-        ``(data, model)`` mesh (:mod:`repro.distributed.kernel_partition`)."""
+        ``(data, model)`` mesh (:mod:`repro.distributed.kernel_partition`).
+        With ``layer`` given, the fused launch reads the stacked pools in
+        place (see :meth:`AttentionBackend.decode`)."""
         if not sparse.fused_decode:
             return super().decode(
                 q, k, v, store, layout, sparse, seq_len,
-                collect_tel=collect_tel,
+                collect_tel=collect_tel, layer=layer,
             )
         from repro.distributed import kernel_partition
 
@@ -131,14 +134,16 @@ class PallasBackend(AttentionBackend):
                 local_pages=sparse.local_pages,
                 seq_len=seq_len,
                 max_pages_per_block=sparse.max_block_size // sparse.page_size,
-                interpret=self._interp(),
+                interpret=self._interp(), layer=layer,
             )
         pages = valid.sum(axis=(1, 2), dtype=jnp.int32)
         if collect_tel:
             # the fused kernel keeps scores in-register; re-run the (cheap)
             # estimation stage to derive counters from the identical score
             # tensor — this is what makes fused/staged counter parity exact.
-            scores = self.scores(rq, store, layout, k.shape[1])
+            scores = self.scores(
+                rq, store.at_layer(layer), layout, table.shape[1]
+            )
             tel = selection_telemetry(
                 scores, layout, seq_len=seq_len,
                 sink_pages=sparse.sink_pages,

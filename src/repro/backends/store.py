@@ -293,6 +293,7 @@ def refresh_tail_codes(
     offsets: jax.Array,
     seq_len: jax.Array,
     sparse: SparseConfig,
+    layer=None,
 ) -> jax.Array:
     """Recompute + requantize the rank-key row of the block containing the
     newest token, for every head (vectorized, static shapes) -> new codes.
@@ -300,28 +301,38 @@ def refresh_tail_codes(
     The max-candidate-sized window containing the token is pooled at each
     candidate size; the row for each head is selected by its (possibly
     layer-dynamic) block size.  Positions beyond ``seq_len`` are neutralized
-    (-inf/+inf for max/min, zero-weight for mean).
+    (-inf/+inf for max/min, zero-weight for mean).  With ``layer`` given,
+    ``k_cache`` ``[L, B, n_kv, nP, page, hd]`` and the store's codes are
+    stacked over layers: the window is read from, and the row written into,
+    layer ``layer`` where it lies, and the stacked codes come back.
     """
     la = as_arrays(layout)
     codes, scale, zero = store.codes, store.scale, store.zero
     method = sparse.centroid_method
     page = sparse.page_size
-    if k_cache.ndim == 4:
-        B, n_kv, S_max, hd = k_cache.shape
-        k_cache = k_cache.reshape(B, n_kv, S_max // page, page, hd)
-    B, n_kv, n_pages, _, hd = k_cache.shape
+    if layer is None:
+        if k_cache.ndim == 4:
+            B, n_kv, S_max, hd = k_cache.shape
+            k_cache = k_cache.reshape(B, n_kv, S_max // page, page, hd)
+        k_cache, codes = k_cache[None], codes[None]
+    _, B, n_kv, n_pages, _, hd = k_cache.shape
+    lyr = 0 if layer is None else layer
     Dp = padded_rank_key_width(hd, method)
     Wmax = max(sparse.candidate_block_sizes)
     w0 = (seq_len // Wmax) * Wmax                        # [B]
 
     # gather the window [B, n_kv, Wmax, hd] — Wmax is page-aligned, so the
-    # slice runs over whole pages of the paged cache.
+    # slice runs over whole pages of the paged cache.  One dynamic slice a
+    # slot (not a vmapped gather), so the pool keeps the layout it has
+    # everywhere else.
     wp = Wmax // page
-    win = jax.vmap(
-        lambda kc, p0: jax.lax.dynamic_slice(
-            kc, (0, p0, 0, 0), (n_kv, wp, page, hd)
+    win = jnp.concatenate([
+        jax.lax.dynamic_slice(
+            k_cache, (lyr, b, 0, w0[b] // page, 0, 0),
+            (1, 1, n_kv, wp, page, hd),
         )
-    )(k_cache, w0 // page).reshape(B, n_kv, Wmax, hd)
+        for b in range(B)
+    ]).reshape(B, n_kv, Wmax, hd)
     pos = w0[:, None] + jnp.arange(Wmax)[None]           # [B, Wmax]
     ok = (pos <= seq_len[:, None])[:, None, :, None]     # include new tok
     winf = win.astype(jnp.float32)
@@ -369,4 +380,5 @@ def refresh_tail_codes(
 
     rows = offsets[None, :] + (seq_len[:, None] // bsz[None, :])  # [B, n_kv]
     bidx = jnp.arange(B)[:, None]
-    return codes.at[bidx, rows].set(new_codes)
+    codes = codes.at[lyr, bidx, rows].set(new_codes)
+    return codes[0] if layer is None else codes

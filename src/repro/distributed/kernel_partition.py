@@ -40,6 +40,7 @@ same kernel.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -147,18 +148,21 @@ def _layout_specs(la: LayoutArrays, ha: Optional[str]) -> LayoutArrays:
     return LayoutArrays(*children, *aux)
 
 
-def _store_spec_tree(store, ba, ha, *, head_aligned_params: bool):
+def _store_spec_tree(store, ba, ha, *, head_aligned_params: bool,
+                     stacked: bool = False):
     """Spec pytree for a CentroidStore, built by mapping over the store
     itself so None leaves (f32 stores carry no scale/zero) keep the tree
     structure.  ``codes [B, rows, Cw]`` shard batch only (ragged per-head
-    row segments stay whole).  ``head_aligned_params`` says which store
+    row segments stay whole); ``stacked`` codes carry a leading layer axis,
+    never sharded.  ``head_aligned_params`` says which store
     kind the CALLER holds — the decode store's per-head ``[B, n_kv, Dp]``
     affine params shard the head axis, the prefill score segment's per-row
     ``[B, rows, 1]`` params replicate their row axis (an explicit flag, not
     shape sniffing: the two layouts can coincide on degenerate shapes)."""
     pspec = P(ba, ha, None) if head_aligned_params else P(ba, None, None)
+    cspec = P(None, ba, None, None) if stacked else P(ba, None, None)
     leaves, treedef = jax.tree_util.tree_flatten(store)
-    specs = [P(ba, None, None) if i == 0 else pspec for i, _ in enumerate(leaves)]
+    specs = [cspec if i == 0 else pspec for i, _ in enumerate(leaves)]
     return jax.tree_util.tree_unflatten(treedef, specs)
 
 
@@ -179,21 +183,26 @@ def fused_decode(
     seq_len: Optional[jax.Array] = None,
     max_pages_per_block: Optional[int] = None,
     interpret: Optional[bool] = None,
+    layer=None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Mesh-partitioned :func:`repro.kernels.ops.fused_decode`.
 
     Under an active sharding context with a shardable axis the launch is
     shard_map'd (batch over ``data``, kv heads over ``model``); otherwise
-    this is exactly the single-device entry point.  The returned attention
-    output is re-gathered over heads (see module docstring); the page
-    table/valid stay kv-head-sharded.
+    this is exactly the single-device entry point.  With ``layer`` given,
+    ``k``/``v``/``store.codes`` are stacked over layers (see
+    :func:`repro.kernels.ops.fused_decode`); the layer axis is never
+    sharded.  The returned attention output is re-gathered over heads (see
+    module docstring); the page table/valid stay kv-head-sharded.
     """
     ctx = current_context()
     la = as_arrays(layout)
-    kp = as_paged(k, la.page_size)
-    vp = as_paged(v, la.page_size)
+    kp, vp, codes, layer = ops.stacked_operands(
+        k, v, store.codes, la.page_size, layer
+    )
+    store = dataclasses.replace(store, codes=codes)
     B = q.shape[0]
-    n_kv = kp.shape[1]
+    n_kv = kp.shape[2]
 
     ba = ha = None
     if ctx is not None:
@@ -204,7 +213,7 @@ def fused_decode(
             sink_pages=sink_pages, local_pages=local_pages,
             seq_len=seq_len,
             max_pages_per_block=max_pages_per_block,
-            interpret=interpret,
+            interpret=interpret, layer=layer,
         )
     mesh = ctx.mesh
 
@@ -213,29 +222,32 @@ def fused_decode(
     else:
         seq_len = jnp.broadcast_to(jnp.asarray(seq_len, jnp.int32), (B,))
 
-    def local_call(q_l, rq_l, kp_l, vp_l, store_l, la_l, seq_l):
+    def local_call(q_l, rq_l, kp_l, vp_l, store_l, la_l, seq_l, layer_l):
         return ops.fused_decode(
             q_l, rq_l, kp_l, vp_l, store_l, la_l,
             sink_pages=sink_pages, local_pages=local_pages,
             seq_len=seq_l,
             max_pages_per_block=max_pages_per_block,
-            interpret=interpret,
+            interpret=interpret, layer=layer_l,
         )
 
     qs = P(ba, ha, None)
-    kvs = P(ba, ha, None, None, None)
+    kvs = P(None, ba, ha, None, None, None)
     out, table, valid = jax.shard_map(
         local_call,
         mesh=mesh,
         in_specs=(
             qs, qs, kvs, kvs,
-            _store_spec_tree(store, ba, ha, head_aligned_params=True),
+            _store_spec_tree(
+                store, ba, ha, head_aligned_params=True, stacked=True
+            ),
             _layout_specs(la, ha),
             P(ba),
+            P(),
         ),
         out_specs=(qs, P(ba, ha, None), P(ba, ha, None)),
         check_vma=False,
-    )(q, rq, kp, vp, store, la, seq_len)
+    )(q, rq, kp, vp, store, la, seq_len, jnp.asarray(layer, jnp.int32))
     # head-gather for bitwise-identical downstream reductions (out_project
     # sums over the FULL head axis in the single-device order).
     out = jax.lax.with_sharding_constraint(

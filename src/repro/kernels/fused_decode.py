@@ -60,14 +60,15 @@ def _fused_decode_kernel(
     bsz_ref,                   # [H] int32 block size (tokens)
     ppb_ref,                   # [H] int32 pages per block
     seq_len_ref,               # [B] int32
+    layer_ref,                 # [1] int32 layer of the stacked pools
     # -- array inputs
-    codes_ref,                 # [B, R, Cw] store codes (HBM/ANY)
+    codes_ref,                 # [L, B, R, Cw] store codes (HBM/ANY)
     scale_ref,                 # [1, 1, 1, Dp] f32
     zero_ref,                  # [1, 1, 1, Dp] f32
     rq_ref,                    # [1, 1, g, Dp] f32 rank queries
     q_ref,                     # [1, 1, g, D]
-    k_ref,                     # [B, H, n_pages, ps, D] paged pool (HBM/ANY)
-    v_ref,                     # [B, H, n_pages, ps, D] (HBM/ANY)
+    k_ref,                     # [L, B, H, n_pages, ps, D] paged pools (HBM/ANY)
+    v_ref,                     # [L, B, H, n_pages, ps, D] (HBM/ANY)
     # -- outputs
     o_ref,                     # [1, 1, g, D]
     tbl_ref,                   # [1, 1, 1, P_sel] int32
@@ -90,6 +91,7 @@ def _fused_decode_kernel(
     bsz = bsz_ref[h]
     ppb = ppb_ref[h]
     sl = seq_len_ref[b]
+    lyr = layer_ref[0]
 
     # ---- phase 1: score the head's centroid segment ------------------------
     # SEG-row window (static size) with a dynamic start; when the segment is
@@ -98,7 +100,7 @@ def _fused_decode_kernel(
     start = pl.multiple_of(jnp.minimum(row_off, total_rows - seg), row_align)
     adj = row_off - start
     cdma = pltpu.make_async_copy(
-        codes_ref.at[b, pl.ds(start, seg)], codes_scr, csem
+        codes_ref.at[lyr, b, pl.ds(start, seg)], codes_scr, csem
     )
     cdma.start()
     cdma.wait()
@@ -142,11 +144,11 @@ def _fused_decode_kernel(
         pg = page_start(p)
         return (
             pltpu.make_async_copy(
-                k_ref.at[b, h, pl.ds(pg, ppb_max)], kbuf.at[slot],
+                k_ref.at[lyr, b, h, pl.ds(pg, ppb_max)], kbuf.at[slot],
                 sem.at[slot, 0],
             ),
             pltpu.make_async_copy(
-                v_ref.at[b, h, pl.ds(pg, ppb_max)], vbuf.at[slot],
+                v_ref.at[lyr, b, h, pl.ds(pg, ppb_max)], vbuf.at[slot],
                 sem.at[slot, 1],
             ),
         )
@@ -228,10 +230,10 @@ def _fused_decode_kernel(
 def fused_decode(
     q: jax.Array,              # [B, n_q, D]
     rq: jax.Array,             # [B, n_q, Dp] rank queries
-    k_pages: jax.Array,        # [B, n_kv, n_pages, page, D]
-    v_pages: jax.Array,        # [B, n_kv, n_pages, page, D]
-    codes: jax.Array,          # [B, total_rows, Cw] store codes
-    scale: jax.Array,          # [B, n_kv, Dp] f32
+    k_pages: jax.Array,        # [L, B, n_kv, n_pages, page, D] stacked pools
+    v_pages: jax.Array,        # [L, B, n_kv, n_pages, page, D]
+    codes: jax.Array,          # [L, B, total_rows, Cw] stacked store codes
+    scale: jax.Array,          # [B, n_kv, Dp] f32 (layer ``layer``'s)
     zero: jax.Array,           # [B, n_kv, Dp] f32
     row_off: jax.Array,        # [H] int32 descriptor arrays ----------------
     n_blocks: jax.Array,       # [H] int32
@@ -239,6 +241,7 @@ def fused_decode(
     bsz: jax.Array,            # [H] int32
     ppb: jax.Array,            # [H] int32
     seq_len: jax.Array,        # [B] int32
+    layer: jax.Array,          # int32 scalar: the layer the call reads
     *,
     page_size: int,
     ppb_max: int,
@@ -255,14 +258,17 @@ def fused_decode(
 
     One launch covers every (sequence, kv head) cell of the ragged grid;
     the selected SET of blocks per head is identical to the staged
-    estimation -> ``lax.top_k`` -> expansion pipeline.
+    estimation -> ``lax.top_k`` -> expansion pipeline.  The KV pools and
+    store codes arrive stacked over layers, as the decode step's layer
+    scan carries them, and the DMAs address layer ``layer`` in place: no
+    layer slice exists as a buffer.
     """
     B, n_q, D = q.shape
-    n_kv = k_pages.shape[1]
-    n_pages = k_pages.shape[2]
+    n_kv = k_pages.shape[2]
+    n_pages = k_pages.shape[3]
     g = n_q // n_kv
     Dp = rq.shape[-1]
-    total_rows = codes.shape[1]
+    total_rows = codes.shape[2]
     rq4 = rq.astype(jnp.float32).reshape(B, n_kv, g, Dp)
     q4 = q.reshape(B, n_kv, g, D)
 
@@ -286,7 +292,7 @@ def fused_decode(
     param_spec = pl.BlockSpec((1, 1, 1, Dp), lambda b, h, *_: (b, h, 0, 0))
     row_spec = pl.BlockSpec((1, 1, 1, p_sel), lambda b, h, *_: (b, h, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=7,
         grid=(B, n_kv),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),          # codes
@@ -327,6 +333,7 @@ def fused_decode(
         bsz.astype(jnp.int32),
         ppb.astype(jnp.int32),
         seq_len.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1),
         codes,
         scale.astype(jnp.float32).reshape(B, n_kv, 1, Dp),
         zero.astype(jnp.float32).reshape(B, n_kv, 1, Dp),

@@ -170,6 +170,18 @@ def _dma_window(layout, la, max_pages_per_block, kernel: str) -> int:
 # ---------------------------------------------------------------------------
 
 
+def stacked_operands(k, v, codes, page_size: int, layer=None):
+    """The fused decode kernel's operand form: KV pools
+    ``[L, B, n_kv, nP, page, D]`` and store codes ``[L, B, rows, Cw]``
+    stacked over layers, plus the layer to read.  With ``layer=None`` the
+    inputs are one layer's (KV paged or dense 4-D) and gain a unit layer
+    axis, a bitcast."""
+    if layer is None:
+        return (as_paged(k, page_size)[None], as_paged(v, page_size)[None],
+                codes[None], 0)
+    return k, v, codes, layer
+
+
 def fused_decode(
     q: jax.Array,               # [B, n_q, D]
     rq: jax.Array,              # [B, n_q, Dp] rank queries
@@ -182,13 +194,18 @@ def fused_decode(
     seq_len: Optional[jax.Array] = None,
     max_pages_per_block: Optional[int] = None,
     interpret: Optional[bool] = None,
+    layer=None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Single-launch AB-Sparse decode (estimation -> top-k -> attention).
 
     ``max_pages_per_block`` is the static DMA window (pages) of the fused
     inner loop; it defaults to the layout's own maximum, which requires the
     layout arrays to be concrete — inside a layer scan pass it explicitly
-    (e.g. from ``SparseConfig.candidate_block_sizes``).
+    (e.g. from ``SparseConfig.candidate_block_sizes``).  With ``layer``
+    given, ``k``/``v`` are the paged pools of every layer
+    ``[L, B, n_kv, nP, page, D]`` and ``store.codes`` is ``[L, B, rows, Cw]``
+    (``scale``/``zero`` are that layer's): the kernel reads layer ``layer``
+    where it lies.
     -> (out [B, n_q, D], page_table [B, H, P_sel], page_valid [B, H, P_sel]).
     """
     from repro.core.stacked import as_arrays
@@ -197,8 +214,9 @@ def fused_decode(
     if interpret is None:
         interpret = default_interpret()
     la = as_arrays(layout)
-    kp = as_paged(k, la.page_size)
-    vp = as_paged(v, la.page_size)
+    kp, vp, codes, layer = stacked_operands(
+        k, v, store.codes, la.page_size, layer
+    )
     B = q.shape[0]
     if seq_len is None:
         seq_len = jnp.full((B,), la.context_len, jnp.int32)
@@ -214,9 +232,9 @@ def fused_decode(
     else:
         scale, zero = store.scale, store.zero
     return fd.fused_decode(
-        q, rq, kp, vp, store.codes, scale, zero,
+        q, rq, kp, vp, codes, scale, zero,
         la.row_offsets, la.n_blocks, la.top_k,
-        la.block_sizes, la.pages_per_block, seq_len,
+        la.block_sizes, la.pages_per_block, seq_len, layer,
         page_size=la.page_size,
         ppb_max=max_pages_per_block,
         bits=store.bits,

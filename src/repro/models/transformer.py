@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.backends import AttentionPlan, CentroidStore, build_plan, get_backend
+from repro.backends.base import at_layer
 from repro.config import ModelConfig
 from repro.core.centroids import rank_query
 from repro.core.quantization import store_bits, store_symmetric
@@ -34,6 +35,11 @@ from repro.distributed.sharding import constrain
 from repro.models import layers, moe as moe_mod, rglru, rwkv6
 
 Cache = Dict[str, Any]
+
+#: per layer kind, the cache leaves a decode step writes one token of: they
+#: ride the decode layer scan's carry and are updated in place.
+_IN_PLACE = {"attn": ("k", "v", "codes")}
+
 
 def _attn_chunk(S: int, target: int = 512) -> int:
     """Largest chunk <= target that divides S (prefix-extended sequences
@@ -920,25 +926,27 @@ class Transformer:
         # KV pages its attention read per slot (the backend's own count).
         count_pages = stk is not None and "_decode_pages" in cache
 
-        def run_layer(p, kind, x, entry, lay, offs):
+        def run_layer(p, kind, x, entry, pools, layer, lay, offs):
+            """-> (x, pools, writes): ``pools`` the stacked leaves updated
+            in place at ``layer``, ``writes`` the layer's other outputs."""
             h = layers.rms_norm(p["norm1"], x, cfg.norm_eps)
-            new_entry = dict(entry)
+            writes = {}
             if kind == "attn":
-                h, new_entry = self._attn_decode(
-                    p["attn"], h, entry, lay, offs, positions,
+                h, pools, writes = self._attn_decode(
+                    p["attn"], h, entry, pools, layer, lay, offs, positions,
                     collect=collect, collect_tel=collect_tel,
                     count_pages=count_pages,
                 )
             elif kind == "local_attn":
-                h, new_entry = self._local_attn_decode(
+                h, writes = self._local_attn_decode(
                     p["attn"], h, entry, positions
                 )
             elif kind == "rglru":
-                h, (new_entry["h"], new_entry["conv"]) = rglru.rglru_decode(
+                h, (writes["h"], writes["conv"]) = rglru.rglru_decode(
                     p["rec"], h, (entry["h"], entry["conv"]), cfg
                 )
             elif kind == "rwkv":
-                h, (new_entry["S"], new_entry["xprev"]) = rwkv6.rwkv_decode(
+                h, (writes["S"], writes["xprev"]) = rwkv6.rwkv_decode(
                     p["tmix"], h, (entry["S"], entry["xprev"]), cfg
                 )
             x = x + h
@@ -947,18 +955,32 @@ class Transformer:
                 h, _ = moe_mod.moe_ffn(p["ffn"], h, cfg, group_size=B)
             else:
                 h = layers.mlp(p["ffn"], h, cfg.activation)
-            return x + h, new_entry
+            return x + h, pools, writes
 
-        def cycle_fn(x, xs):
+        # A cache leaf that a layer writes one token of per step (the
+        # attention KV pools and the centroid codes the append refreshes)
+        # rides the layer scan's carry, stacked over cycles, and is updated
+        # in place at the cycle's index: no layer slice, copy or write-back
+        # of the pool.  Every other leaf is scanned over; what a layer
+        # returns leaves as the scan's output, and what it only reads
+        # (store scale/zero, the prefill scoring segment) is returned as it
+        # came in.
+        def pooled(kind, entry):
+            return {k: entry[k] for k in _IN_PLACE.get(kind, ()) if k in entry}
+
+        def cycle_fn(carry, xs):
+            x, pools = carry
             cyc_params, cyc_cache, cyc_idx = xs
-            new_cache = {}
+            pools, writes = dict(pools), {}
             for i, kind in enumerate(pat):
+                pos = f"pos{i}"
                 lay = stk.layer(cyc_idx) if (stk is not None and kind == "attn") else None
                 offs = offsets[cyc_idx] if (offsets is not None and kind == "attn") else None
-                x, new_cache[f"pos{i}"] = run_layer(
-                    cyc_params[f"pos{i}"], kind, x, cyc_cache[f"pos{i}"], lay, offs
+                x, pools[pos], writes[pos] = run_layer(
+                    cyc_params[pos], kind, x, cyc_cache[pos], pools[pos],
+                    cyc_idx, lay, offs,
                 )
-            return x, new_cache
+            return (x, pools), writes
 
         if collect:
             sel_acc = jnp.zeros_like(cache["_sel_pages"])
@@ -968,43 +990,58 @@ class Transformer:
         if count_pages:
             pages_acc = jnp.zeros_like(cache["_decode_pages"])   # [L, B]
         if self.plan.n_cycles > 0:
-            cyc_cache_in = {f"pos{i}": cache[f"pos{i}"] for i in range(len(pat))}
-            x, new_cyc = jax.lax.scan(
+            pools_in, cyc_cache_in = {}, {}
+            for i, kind in enumerate(pat):
+                entry = cache[f"pos{i}"]
+                pools_in[f"pos{i}"] = pooled(kind, entry)
+                cyc_cache_in[f"pos{i}"] = {
+                    k: a for k, a in entry.items()
+                    if k not in pools_in[f"pos{i}"]
+                }
+            (x, pools), written = jax.lax.scan(
                 cycle_fn,
-                x,
+                (x, pools_in),
                 (params["cycles"], cyc_cache_in, jnp.arange(self.plan.n_cycles)),
             )
             for i, kind in enumerate(pat):
-                entry = new_cyc[f"pos{i}"]
+                got = dict(written[f"pos{i}"])
                 if collect and kind == "attn":
-                    sel_acc |= jnp.any(entry.pop("_selq"), axis=0)
-                    pre_acc |= jnp.any(entry.pop("_preq"), axis=0)
+                    sel_acc |= jnp.any(got.pop("_selq"), axis=0)
+                    pre_acc |= jnp.any(got.pop("_preq"), axis=0)
                 # layer index of cycle c, position i is c*len(pat)+i
                 rows = jnp.arange(self.plan.n_cycles) * len(pat) + i
                 if collect_tel and kind == "attn":
-                    tel_acc = tel_acc.at[rows].set(entry.pop("_telq"))
+                    tel_acc = tel_acc.at[rows].set(got.pop("_telq"))
                 if count_pages and kind == "attn":
-                    got = entry.pop("_pagesq")             # [n_cycles, B]
+                    pagesq = got.pop("_pagesq")            # [n_cycles, B]
                     if len(pat) == 1 and not self.plan.rest_kinds:
-                        pages_acc = got      # one layer a cycle: rows in order
+                        pages_acc = pagesq   # one layer a cycle: rows in order
                     else:
-                        pages_acc = pages_acc.at[rows].set(got)
-                cache[f"pos{i}"] = entry
+                        pages_acc = pages_acc.at[rows].set(pagesq)
+                cache[f"pos{i}"] = {
+                    **cache[f"pos{i}"], **pools[f"pos{i}"], **got
+                }
         for i, kind in enumerate(self.plan.rest_kinds):
             lay_idx = self.plan.n_cycles * len(pat) + i
             lay = stk.layer(lay_idx) if (stk is not None and kind == "attn") else None
             offs = offsets[lay_idx] if (offsets is not None and kind == "attn") else None
-            x, new_entry = run_layer(
-                params["rest"][i], kind, x, cache["rest"][i], lay, offs
+            entry = cache["rest"][i]
+            # an unscanned layer's pools take a unit layer axis (a bitcast)
+            pools = {k: a[None] for k, a in pooled(kind, entry).items()}
+            x, pools, got = run_layer(
+                params["rest"][i], kind, x, entry, pools, 0, lay, offs
             )
+            got = dict(got)
             if collect and kind == "attn":
-                sel_acc |= new_entry.pop("_selq")
-                pre_acc |= new_entry.pop("_preq")
+                sel_acc |= got.pop("_selq")
+                pre_acc |= got.pop("_preq")
             if collect_tel and kind == "attn":
-                tel_acc = tel_acc.at[lay_idx].set(new_entry.pop("_telq"))
+                tel_acc = tel_acc.at[lay_idx].set(got.pop("_telq"))
             if count_pages and kind == "attn":
-                pages_acc = pages_acc.at[lay_idx].set(new_entry.pop("_pagesq"))
-            cache["rest"][i] = new_entry
+                pages_acc = pages_acc.at[lay_idx].set(got.pop("_pagesq"))
+            cache["rest"][i] = {
+                **entry, **{k: a[0] for k, a in pools.items()}, **got
+            }
         if collect:
             cache["_sel_pages"] = sel_acc
             cache["_pre_pages"] = pre_acc
@@ -1021,8 +1058,13 @@ class Transformer:
 
     # -- decode helpers ---------------------------------------------------
 
-    def _attn_decode(self, p, h, entry, lay, offs, positions, collect=False,
-                     collect_tel=False, count_pages=False):
+    def _attn_decode(self, p, h, entry, pools, layer, lay, offs, positions,
+                     collect=False, collect_tel=False, count_pages=False):
+        """-> (h, pools, outputs).  ``pools`` holds this pattern position's
+        ``k``/``v`` (and, sparse, the store ``codes``) stacked over layers;
+        the new token's K/V and its block's centroid row are written into
+        them in place at ``layer``.  ``entry`` holds the layer's read-only
+        leaves; ``outputs`` the per-layer counters and masks."""
         cfg = self.cfg
         B = h.shape[0]
         hd = cfg.resolved_head_dim
@@ -1040,50 +1082,52 @@ class Transformer:
         q = constrain(q, "batch", None, "head_dim")
         k_new = constrain(k_new, "batch", "kv_heads", "head_dim")
         v_new = constrain(v_new, "batch", "kv_heads", "head_dim")
-        k_cache = entry["k"]     # dense [B, n_kv, S, hd] or paged [.., nP, ps, hd]
-        v_cache = entry["v"]
-        bidx = jnp.arange(B)
-        if k_cache.ndim == 5:    # paged (sparse-active) cache
-            ps = k_cache.shape[3]
-            k_cache = k_cache.at[bidx, :, seq_len // ps, seq_len % ps].set(
-                k_new.astype(k_cache.dtype)
-            )
-            v_cache = v_cache.at[bidx, :, seq_len // ps, seq_len % ps].set(
-                v_new.astype(v_cache.dtype)
-            )
-            k_cache = constrain(
-                k_cache, "batch", "kv_heads", "kv_pages", None, "head_dim"
-            )
-            v_cache = constrain(
-                v_cache, "batch", "kv_heads", "kv_pages", None, "head_dim"
-            )
+        # dense [L, B, n_kv, S, hd] or paged [L, B, n_kv, nP, ps, hd]
+        k_all, v_all = pools["k"], pools["v"]
+        if k_all.ndim == 6:      # paged (sparse-active) cache
+            ps = k_all.shape[4]
+            where = (seq_len // ps, seq_len % ps)
+            axes = (None, "batch", "kv_heads", "kv_pages", None, "head_dim")
         else:
-            k_cache = k_cache.at[bidx, :, seq_len].set(k_new.astype(k_cache.dtype))
-            v_cache = v_cache.at[bidx, :, seq_len].set(v_new.astype(v_cache.dtype))
-            k_cache = constrain(k_cache, "batch", "kv_heads", "kv_pages", "head_dim")
-            v_cache = constrain(v_cache, "batch", "kv_heads", "kv_pages", "head_dim")
-        new_entry = dict(entry)
-        new_entry["k"] = k_cache
-        new_entry["v"] = v_cache
+            where = (seq_len,)
+            axes = (None, "batch", "kv_heads", "kv_pages", "head_dim")
+
+        def write(pool, new):
+            # one dynamic-update-slice a slot, not a scatter: a scatter
+            # makes the TPU compiler pick a layout of its own for the pool,
+            # and the pool then crosses layouts (whole-pool copies) on its
+            # way to and from the kernel.
+            new = new.astype(pool.dtype)
+            for b in range(B):
+                start = (layer, b, 0, *(w[b] for w in where), 0)
+                upd = new[b].reshape((1, 1, -1) + (1,) * len(where) + (hd,))
+                pool = jax.lax.dynamic_update_slice(pool, upd, start)
+            return constrain(pool, *axes)
+
+        k_all, v_all = write(k_all, k_new), write(v_all, v_new)
+        pools = {**pools, "k": k_all, "v": v_all}
+        outputs = {}
         live = seq_len + 1
 
         if lay is None:
-            out = dense_decode_attention(q, k_cache, v_cache, seq_len=live)
+            out = dense_decode_attention(
+                q, at_layer(k_all, layer), at_layer(v_all, layer), seq_len=live
+            )
             out = constrain(out, "batch", None, "head_dim")
-            return layers.out_project(p, out[:, None], cfg), new_entry
+            return layers.out_project(p, out[:, None], cfg), pools, outputs
 
         # --- AB-Sparse path: plan/execute through the attention backend ---
         quant = cfg.sparse.quant
         store = CentroidStore(
-            entry["codes"], entry["scale"], entry["zero"],
+            pools["codes"], entry["scale"], entry["zero"],
             store_bits(quant), store_symmetric(quant),
         )
         # refresh the centroid row of the block containing the new token,
         # then estimation -> adaptive top-k -> paged attention.
         store = self.backend.append(
-            store, k_cache, lay, offs, seq_len, cfg.sparse
+            store, k_all, lay, offs, seq_len, cfg.sparse, layer=layer
         )
-        new_entry["codes"] = store.codes
+        pools["codes"] = store.codes
         # head-gather before the out projection: under a serving mesh the
         # kernel output arrives kv-head-sharded, and out_project must reduce
         # over the FULL head axis in single-device order for the sharded
@@ -1092,16 +1136,17 @@ class Transformer:
             # sparsity counters piggyback on the estimation scores the
             # decode itself ranks (staged: same tensor; fused: an identical
             # recompute inside the backend) — no second pass over the store.
-            out, _, pages, new_entry["_telq"] = self.backend.decode(
-                q, k_cache, v_cache, store, lay, cfg.sparse, seq_len=live,
-                collect_tel=True,
+            out, _, pages, outputs["_telq"] = self.backend.decode(
+                q, k_all, v_all, store, lay, cfg.sparse, seq_len=live,
+                collect_tel=True, layer=layer,
             )
         else:
             out, _, pages = self.backend.decode(
-                q, k_cache, v_cache, store, lay, cfg.sparse, seq_len=live
+                q, k_all, v_all, store, lay, cfg.sparse, seq_len=live,
+                layer=layer,
             )
         if count_pages:
-            new_entry["_pagesq"] = pages
+            outputs["_pagesq"] = pages
         if collect:
             # re-run the (cheap) estimation stage against the post-append
             # store — identical scores to the ones backend.decode just
@@ -1109,17 +1154,19 @@ class Transformer:
             # the attention stage gathered, plus the margin prediction.
             sp = cfg.sparse
             rq = rank_query(q, sp.centroid_method, q.shape[-1])
-            est = self.backend.scores(rq, store, lay, k_cache.shape[1])
+            est = self.backend.scores(
+                rq, store.at_layer(layer), lay, k_all.shape[2]
+            )
             sel_mask, pre_mask = selected_page_masks(
                 est, lay, seq_len=live,
                 sink_pages=sp.sink_pages, local_pages=sp.local_pages,
                 margin_blocks=sp.prefetch_margin_blocks,
                 max_pages_per_block=sp.max_block_size // sp.page_size,
             )
-            new_entry["_selq"] = sel_mask
-            new_entry["_preq"] = pre_mask
+            outputs["_selq"] = sel_mask
+            outputs["_preq"] = pre_mask
         out = constrain(out, "batch", None, "head_dim")
-        return layers.out_project(p, out[:, None], cfg), new_entry
+        return layers.out_project(p, out[:, None], cfg), pools, outputs
 
     def _local_attn_decode(self, p, h, entry, positions):
         """Sliding-window decode with a ring-buffer KV cache."""
@@ -1145,10 +1192,9 @@ class Transformer:
         )
         valid = (pos_in_slot >= 0) & (pos_in_slot > seq_len[:, None] - W)
         out = self._masked_dense_decode(q, k_cache, v_cache, valid)
-        new_entry = dict(entry)
-        new_entry["k"] = k_cache
-        new_entry["v"] = v_cache
-        return layers.out_project(p, out[:, None], cfg), new_entry
+        return layers.out_project(p, out[:, None], cfg), {
+            "k": k_cache, "v": v_cache,
+        }
 
     @staticmethod
     def _masked_dense_decode(q, k, v, valid):

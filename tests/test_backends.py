@@ -129,6 +129,36 @@ def test_backend_parity_page_tables_and_outputs(quant):
     )
 
 
+@pytest.mark.parametrize("layer", [0, 2])
+def test_append_refreshes_stacked_codes_at_layer(layer):
+    """Decode-time append on KV and codes stacked over layers (the decode
+    scan's carried form) writes layer ``layer``'s row as the append on that
+    layer's own slice does, and leaves the other layers as they were."""
+    from repro.core.stacked import as_arrays
+
+    L = 3
+    lay = layout_for(BLOCK_SIZES, S, 16, BUDGET)
+    la, offs = as_arrays(lay), jnp.asarray(lay.offsets[:-1])
+    sparse = SparseConfig(token_budget=BUDGET)
+    be = get_backend("reference")
+    ks = [_qkv(seed=30 + l)[1] for l in range(L)]
+    stores = [be.build_store(k, lay, "quest", quant="int4_asym") for k in ks]
+    paged = lambda k: k.reshape(B, N_KV, S // 16, 16, D)
+    codes = jnp.stack([st.codes for st in stores])
+    seq_len = jnp.array([S - 100, 777], jnp.int32)   # mid-block: rows change
+    got = be.append(
+        dataclasses.replace(stores[layer], codes=codes),
+        jnp.stack([paged(k) for k in ks]), la, offs, seq_len, sparse,
+        layer=jnp.int32(layer),
+    ).codes
+    want = be.append(
+        stores[layer], paged(ks[layer]), la, offs, seq_len, sparse
+    ).codes
+    assert not np.array_equal(want, stores[layer].codes)
+    for l in range(L):
+        np.testing.assert_array_equal(got[l], want if l == layer else codes[l])
+
+
 @pytest.mark.parametrize("quant", ["none", "int8_asym", "int4_asym"])
 def test_backends_match_dense_oracle_at_full_budget(quant):
     """Every sparse backend == the dense oracle when the budget covers the
@@ -202,3 +232,66 @@ def test_model_backend_swap_is_config_only():
     # but both must be finite and same-shaped (same cache structure).
     assert l_dense.shape == l_ref.shape
     assert np.isfinite(l_dense).all() and np.isfinite(l_ref).all()
+
+
+def test_decode_step_returns_read_only_cache_leaves_as_they_came():
+    """The decode layer scan carries the leaves a step writes (KV pools,
+    centroid codes) and scans over the rest: the leaves a step only reads
+    (store scale/zero, the prefill scoring segment, the plan descriptors)
+    leave the step as the very values that came in, not as scan outputs."""
+    import repro.models as models
+    from repro.configs import get_config, smoke_variant
+
+    base = smoke_variant(get_config("llama3.2-3b"))
+    cfg = dataclasses.replace(
+        base, sparse=dataclasses.replace(
+            base.sparse, token_budget=64, sparse_prefill=True
+        ),
+    )
+    model = models.Transformer(cfg)
+    params = jax.eval_shape(model.init, KEY)
+    cache = jax.eval_shape(lambda: model.init_cache(2, 192))
+    tokens = jax.ShapeDtypeStruct((2,), jnp.int32)
+    jaxpr = jax.make_jaxpr(model.decode_step)(params, cache, tokens).jaxpr
+    n_params = len(jax.tree.leaves(params))
+    paths = [jax.tree_util.keystr(p)
+             for p, _ in jax.tree_util.tree_flatten_with_path(cache)[0]]
+    ins = dict(zip(paths, jaxpr.invars[n_params:]))
+    outs = dict(zip(paths, jaxpr.outvars[1:]))
+    read_only = [p for p in paths if not p.endswith(
+        ("['k']", "['v']", "['codes']", "['seq_len']"))]
+    assert any(p.endswith("['pcodes']") for p in read_only)
+    for p in read_only:
+        assert outs[p] is ins[p], p
+    for p in paths:
+        if p.endswith(("['k']", "['v']", "['codes']")):
+            assert outs[p] is not ins[p], p
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "unpaged"])
+def test_decode_steps_continue_prefill_through_stacked_pools(paged):
+    """Each layer writes its new token into its own layer of the pools the
+    decode scan carries: two decode steps continue a prefill as a longer
+    prefill does.  Full attention over the sparse plan's paged cache (the
+    dense backend) and over an inactive plan's unpaged cache."""
+    import repro.models as models
+    from repro.configs import get_config, smoke_variant
+
+    base = smoke_variant(get_config("llama3.2-3b"))
+    cfg = dataclasses.replace(
+        base, sparse=dataclasses.replace(
+            base.sparse, token_budget=64, backend="dense", enabled=paged
+        ),
+    )
+    model = models.Transformer(cfg)
+    params = model.init(KEY)
+    S, ctx = 127, 192
+    tokens = jax.random.randint(KEY, (2, S + 2), 0, cfg.vocab_size)
+    _, cache = model.prefill(params, tokens[:, :S], max_context=ctx)
+    assert (cache["pos0"]["k"].ndim == 6) == paged
+    for n in (S, S + 1):
+        logits, cache = model.decode_step(params, cache, tokens[:, n])
+        want, _ = model.prefill(params, tokens[:, :n + 1], max_context=ctx)
+        np.testing.assert_allclose(
+            np.asarray(logits), np.asarray(want), atol=3e-5, rtol=1e-3
+        )

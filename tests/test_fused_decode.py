@@ -180,29 +180,109 @@ def test_fused_accepts_prepaged_cache_view():
     )
 
 
+@pytest.mark.parametrize("layer", ["first", "last"])
+def test_fused_reads_stacked_pools_at_layer(layer):
+    """The decode step hands the kernel every layer's pools and store codes
+    stacked, with the layer to read: that call must equal the call on the
+    layer's own slice, with layers holding different KV (a wrong layer
+    offset reads another layer's pages and scores)."""
+    L = 3
+    lay = layout_for(NONUNIFORM, S, 16, 512)
+    cfg = SparseConfig(token_budget=512)
+    seq_len = jnp.array([S - 1, 777], jnp.int32)
+    qkv = [_qkv(seed=20 + l) for l in range(L)]
+    q = qkv[0][0]
+    rq = rank_query(q, cfg.centroid_method, D)
+    stores = [
+        PALLAS.build_store(k, lay, cfg.centroid_method, quant="int4_asym")
+        for _, k, _ in qkv
+    ]
+    paged = lambda a: a.reshape(B, N_KV, S // 16, 16, D)
+    k_all = jnp.stack([paged(k) for _, k, _ in qkv])
+    v_all = jnp.stack([paged(v) for _, _, v in qkv])
+    l = 0 if layer == "first" else L - 1
+    store_l = stores[l]
+    stacked = dataclasses.replace(
+        store_l, codes=jnp.stack([st.codes for st in stores])
+    )
+    kw = dict(sink_pages=cfg.sink_pages, local_pages=cfg.local_pages,
+              seq_len=seq_len, interpret=True)
+    got = ops.fused_decode(
+        q, rq, k_all, v_all, stacked, lay, layer=jnp.int32(l), **kw
+    )
+    want = ops.fused_decode(
+        q, rq, paged(qkv[l][1]), paged(qkv[l][2]), store_l, lay, **kw
+    )
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def _stack_slots(caches):
+    """Single-sequence caches (one max_context) -> one cache whose batch
+    slots hold them, at their own lengths."""
+    cache = dict(caches[0])
+    for key in cache:
+        if key.startswith("pos"):
+            cache[key] = jax.tree.map(
+                lambda *a: jnp.concatenate(a, axis=1), *[c[key] for c in caches]
+            )
+    cache["seq_len"] = jnp.concatenate([c["seq_len"] for c in caches])
+    return cache
+
+
 def test_fused_end_to_end_decode_step_matches_staged():
     """Model-level: a smoke Transformer with backend="pallas" produces the
-    same decode logits with the fused launch as with the staged pipeline
-    (paged cache, layer scan, store append included)."""
+    same decode logits and caches with the fused launch as with the staged
+    pipeline and the reference backend (paged cache, layer scan, store
+    append included) over several steps, with slots at different lengths:
+    one starts at a page's last token (``seq_len % 16 == 15``) and the
+    others cross a page boundary on the way."""
     from repro.configs import get_config, smoke_variant
     from repro.models import Transformer
 
     base = smoke_variant(get_config("llama3.2-3b"))
+    lens, steps = (300, 271, 287), 6
 
-    def logits(fused):
+    def run(backend, fused):
         cfg = dataclasses.replace(
             base,
             sparse=dataclasses.replace(
-                base.sparse, token_budget=128, backend="pallas",
+                base.sparse, token_budget=128, backend=backend,
                 fused_decode=fused,
             ),
         )
         model = Transformer(cfg)
         params = model.init(KEY)
-        tokens = jax.random.randint(KEY, (1, 319), 0, cfg.vocab_size)
-        _, cache = model.prefill(params, tokens[:, :-1], max_context=320)
-        return np.asarray(model.decode_step(params, cache, tokens[:, -1])[0])
+        caches = []
+        for i, n in enumerate(lens):
+            tokens = jax.random.randint(
+                jax.random.fold_in(KEY, i), (1, n), 0, cfg.vocab_size
+            )
+            caches.append(model.prefill(params, tokens, max_context=320)[1])
+        cache = _stack_slots(caches)
+        cache["_decode_pages"] = jnp.zeros((cfg.n_layers, len(lens)), jnp.int32)
+        step = jax.jit(model.decode_step)
+        tok = jnp.array([1, 2, 3], jnp.int32)
+        logits, pages = [], []
+        for _ in range(steps):
+            out, cache = step(params, cache, tok)
+            logits.append(np.asarray(out))
+            pages.append(np.asarray(cache["_decode_pages"]))
+            tok = jnp.asarray([5, 7, 11], jnp.int32)
+        return logits, pages, jax.tree.map(np.asarray, cache)
 
-    l_staged = logits(False)
-    l_fused = logits(True)
-    np.testing.assert_allclose(l_staged, l_fused, atol=2e-4, rtol=1e-4)
+    fused = run("pallas", True)
+    for other in (run("pallas", False), run("reference", False)):
+        for a, b in zip(fused[0], other[0]):
+            np.testing.assert_allclose(a, b, atol=2e-4, rtol=1e-4)
+        for a, b in zip(fused[1], other[1]):
+            np.testing.assert_array_equal(a, b)
+        c_f, c_o = fused[2], other[2]
+        np.testing.assert_array_equal(c_f["seq_len"], np.add(lens, steps))
+        np.testing.assert_array_equal(c_f["seq_len"], c_o["seq_len"])
+        for key in ("codes", "scale", "zero"):
+            np.testing.assert_array_equal(c_f["pos0"][key], c_o["pos0"][key])
+        for key in ("k", "v"):
+            np.testing.assert_allclose(
+                c_f["pos0"][key], c_o["pos0"][key], atol=2e-4, rtol=1e-4
+            )

@@ -9,6 +9,7 @@ context and a mixed 16/32/64 block-size layout — the shapes the serving
 engine hands each kernel inside its layer scan.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -138,3 +139,101 @@ def test_sparse_prefill_compiles_for_v5e(sds, layout):
         sds((), jnp.int32),
         _layout_args(sds, layout),
     )
+
+
+def _hlo_instructions(hlo: str):
+    """-> [(name, dims of the result or of a tuple's first element, opcode,
+    operand names, line)] over every computation; names are module-unique."""
+    pat = re.compile(
+        r"^\s*(?:ROOT )?%(\S+) = \(?\w+\[([\d,]*)\][^=]*? ([\w-]+)\(([^)]*)\)"
+    )
+    out = []
+    for line in hlo.splitlines():
+        m = pat.match(line)
+        if m:
+            dims = tuple(int(d) for d in m.group(2).split(",") if d)
+            operands = re.findall(r"%([\w.-]+)", m.group(4))
+            out.append((m.group(1), dims, m.group(3), operands, line))
+    return out
+
+
+def test_decode_step_updates_kv_pools_in_place_on_v5e(sds):
+    """The decode step's layer scan carries the stacked KV pools and writes
+    each new token in place: the compiled program holds no copy, slice,
+    update-slice or allocation of one layer's pool or of the whole stack,
+    and the donated pools are the step's outputs.  At these widths the
+    whole stack fits the chip's fast memory (memory space ``S(1)``), and the
+    compiler may stage it there with an asynchronous copy in and out; that
+    is exempt, as a deployment-sized pool (gigabytes) never fits."""
+    import dataclasses
+
+    from repro.backends import get_backend
+    from repro.models import Transformer
+
+    L, batch, ctx = 3, 2, 2048
+    cfg = dataclasses.replace(
+        CFG, n_layers=L, d_model=512, d_ff=1024, vocab_size=1024,
+        head_dim=D,
+        sparse=dataclasses.replace(
+            SP, backend="pallas", fused_decode=True, sparse_prefill=True,
+            token_budget=512, block_sizes=(MIXED,) * L,
+        ),
+    )
+    model = Transformer(cfg, context_len=ctx)
+    as_sds = lambda a: sds(a.shape, a.dtype)
+    params = jax.tree.map(
+        as_sds, jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    )
+    cache = jax.tree.map(
+        as_sds, jax.eval_shape(lambda: model.init_cache(batch, ctx))
+    )
+    cache["_decode_pages"] = sds((L, batch), jnp.int32)
+    backend = get_backend("pallas")
+    interpret, backend.interpret = backend.interpret, False
+    try:
+        # the engine's form: the cache donated
+        hlo = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
+            params, cache, sds((batch,), jnp.int32)
+        ).compile().as_text()
+    finally:
+        backend.interpret = interpret
+
+    assert "tpu_custom_call" in hlo
+    layer = (batch, NKV, ctx // PS, PS, D)
+    pool_shapes = {layer, (1,) + layer, (L,) + layer}
+    shapes = {name: dims for name, dims, *_ in _hlo_instructions(hlo)}
+    bad = []
+    for name, dims, op, operands, line in _hlo_instructions(hlo):
+        if op == "copy-start" and "S(1)}" in line.split("copy-start(")[0]:
+            hit = False            # staged in, or out of, fast memory
+        elif op in ("copy", "copy-start", "dynamic-slice") or (
+            op == "custom-call" and "AllocateBuffer" in line
+        ):
+            hit = dims in pool_shapes
+        elif op == "fusion":       # a materialised layer slice
+            hit = dims in pool_shapes - {(L,) + layer} or (
+                name.startswith("copy") and dims in pool_shapes
+            )
+        elif op == "dynamic-update-slice":   # a layer written into a stack
+            hit = shapes.get(operands[1]) in pool_shapes
+        else:
+            hit = False
+        if hit:
+            bad.append(f"{op} {name} {dims}")
+    assert not bad, bad
+
+    # the donated stacked k/v parameters of the entry computation alias
+    # outputs
+    entry = hlo[hlo.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    params_at = [
+        int(m.group(1))
+        for m in re.finditer(
+            r"= bf16\[" + ",".join(map(str, (L,) + layer))
+            + r"\][^\n]*? parameter\((\d+)\)", entry,
+        )
+    ]
+    assert len(params_at) == 2, params_at
+    header = hlo[:hlo.index("\n")]
+    for p in params_at:
+        assert re.search(rf"\({p}, \{{\}}, (may|must)-alias\)", header), p
