@@ -8,6 +8,22 @@ compared on the tokens it was served: each was returned to its client.  At every
 how far the served token's logit lies below the reference's best.  The
 number compared is the widest gap; the limit is the cell's, set between
 the program's readings and the control's (``cells/<cell>.json``).
+
+The reference is the module ``references/<reference>.py`` that the
+configuration file names.  It imports nothing of the program, and gives:
+
+- ``weights(params, cfg)``: the seeded weights, drawn by the harness
+  (``model.init_params``, in the program's parameter layout, nothing the
+  program made), in the reference's own form; ``cfg`` is the cell's
+  ``ModelConfig``, read for its shape.
+- ``logits(weights, spec, block_sizes, tokens, n_prompt, positions,
+  max_context, lower=False)``: float32 logits ``[len(positions), vocab]``
+  at ``positions`` of ``tokens`` (the prompt's ``n_prompt`` tokens, then
+  the served tokens fed back), over a ``max_context``-token frame.
+  ``spec`` is the configuration file; ``block_sizes`` the committed
+  assignment, one row for each global-attention layer in order
+  (``model.sparse_layers``); ``lower`` computes the control, one precision
+  below the configuration's.
 """
 from __future__ import annotations
 
@@ -19,28 +35,6 @@ import numpy as np
 
 def reference_module(spec: dict):
     return importlib.import_module(f"references.{spec['reference']}")
-
-
-def reference_weights(params, cfg) -> dict:
-    """The seeded weights in the reference's per-layer form (the arrays are
-    the harness's own draw; see ``model.init_params``)."""
-    cyc = params["cycles"]["pos0"]
-    attn, ffn = cyc["attn"], cyc["ffn"]
-    layers = []
-    for l in range(cfg.n_layers):
-        p = {
-            "norm1": cyc["norm1"]["scale"][l], "norm2": cyc["norm2"]["scale"][l],
-            "wq": attn["wq"]["w"][l], "wk": attn["wk"]["w"][l],
-            "wv": attn["wv"]["w"][l], "wo": attn["wo"]["w"][l],
-            "gate": ffn["gate"]["w"][l], "up": ffn["up"]["w"][l],
-            "down": ffn["down"]["w"][l],
-        }
-        if cfg.qkv_bias:
-            p.update(bq=attn["wq"]["b"][l], bk=attn["wk"]["b"][l],
-                     bv=attn["wv"]["b"][l])
-        layers.append(p)
-    return {"embed": params["embed"], "final_norm": params["final_norm"]["scale"],
-            "lm_head": params["lm_head"], "layers": layers}
 
 
 def sample(served: Sequence[tuple], seed: int, min_requests: int,

@@ -1,4 +1,4 @@
-"""Work of one fused sparse decode call (one layer, the whole batch).
+"""Work of one fused sparse decode call (one global layer, the whole batch).
 
 Counted from the shapes and the configuration, never from the kernel, so
 the count stays the same whatever implements the work.  Per live slot of
@@ -17,15 +17,17 @@ import numpy as np
 KERNEL = "absparse_fused_decode"
 
 
-def call(cfg, layer: int, live_lengths, elem_bytes: int = 2):
-    """-> (flops, bytes) of one call over slots of these live lengths."""
+def call(cfg, sparse_layer: int, live_lengths, elem_bytes: int = 2):
+    """-> (flops, bytes) of one call over slots of these live lengths, on
+    the ``sparse_layer``-th global-attention layer (``model.sparse_layers``),
+    which reads that row of the block-size assignment."""
     sp = cfg.sparse
     hd, n_q, n_kv = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
     g = n_q // n_kv
     dp = 2 * hd                               # Quest rank key: [max, min]
     code_bytes = dp * 4 // 8 if sp.quant.startswith("int4") else dp
     L = np.asarray(live_lengths, np.int64)[:, None]            # [slots, 1]
-    b = np.asarray(sp.layer_block_sizes(layer, n_kv), np.int64)[None]
+    b = np.asarray(sp.layer_block_sizes(sparse_layer, n_kv), np.int64)[None]
     rows = -(-L // b)                                          # ceil
     sel = np.minimum(L, sp.token_budget)
     flops = 2 * rows * dp * g + 4 * g * sel * hd

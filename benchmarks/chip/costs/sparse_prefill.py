@@ -1,4 +1,4 @@
-"""Work of one sparse prefill call (one layer, one chunk of one prompt).
+"""Work of one sparse prefill call (one global layer, one chunk of one prompt).
 
 Counted from the shapes and the configuration, never from the kernel.  The
 chunk's tokens form query blocks of ``prefill_block_q``; per query block
@@ -25,8 +25,10 @@ def _blocks(q_start: int, q_end: int, b: int, sp):
     return causal, forced
 
 
-def call(cfg, layer: int, offset: int, n: int, elem_bytes: int = 2):
-    """-> (flops, bytes) of one call on tokens [offset, offset + n)."""
+def call(cfg, sparse_layer: int, offset: int, n: int, elem_bytes: int = 2):
+    """-> (flops, bytes) of one call on tokens [offset, offset + n), on the
+    ``sparse_layer``-th global-attention layer (``model.sparse_layers``),
+    which reads that row of the block-size assignment."""
     sp = cfg.sparse
     hd, n_q, n_kv = cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads
     g = n_q // n_kv
@@ -37,7 +39,7 @@ def call(cfg, layer: int, offset: int, n: int, elem_bytes: int = 2):
     for qs in range(offset, offset + n, bq):
         q_end = min(qs + bq, offset + n) - 1
         nq = q_end - qs + 1
-        for b in sp.layer_block_sizes(layer, n_kv):
+        for b in sp.layer_block_sizes(sparse_layer, n_kv):
             k_h = sp.token_budget // b
             k_sel = math.ceil(k_h * sp.prefill_topk_scale)
             causal, forced = _blocks(qs, q_end, b, sp)

@@ -1,7 +1,9 @@
 """Fused decode kernel: least time the chip could take for the work it
 did (max of FLOPs / peak FLOP/s and bytes / peak bandwidth, summed over
-calls) over the kernel's summed device time."""
+calls, one a global-attention layer a step) over the kernel's summed
+device time."""
 from costs import fused_decode
+from model import sparse_layers
 
 
 def read(ctx):
@@ -13,8 +15,8 @@ def read(ctx):
     least = flops_bound = 0.0
     calls = 0
     for lens in steps:
-        for layer in range(ctx.cfg.n_layers):
-            f, b = fused_decode.call(ctx.cfg, layer, lens)
+        for i in range(len(sparse_layers(ctx.cfg))):
+            f, b = fused_decode.call(ctx.cfg, i, lens)
             least += max(f / pf, b / pb)
             flops_bound += f / pf
             calls += 1

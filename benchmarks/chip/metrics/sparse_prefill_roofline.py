@@ -1,6 +1,8 @@
 """Sparse prefill kernel: least time for the work it did (max of FLOPs /
-peak and bytes / bandwidth, summed over calls) over its device time."""
+peak and bytes / bandwidth, summed over calls, one a global-attention
+layer a chunk) over its device time."""
 from costs import sparse_prefill
+from model import sparse_layers
 
 
 def read(ctx):
@@ -12,8 +14,8 @@ def read(ctx):
     least = flops_bound = 0.0
     calls = 0
     for offset, size in chunks:
-        for layer in range(ctx.cfg.n_layers):
-            f, b = sparse_prefill.call(ctx.cfg, layer, offset, size)
+        for i in range(len(sparse_layers(ctx.cfg))):
+            f, b = sparse_prefill.call(ctx.cfg, i, offset, size)
             least += max(f / pf, b / pb)
             flops_bound += f / pf
             calls += 1

@@ -213,6 +213,28 @@ def _layer(p, x, n_prompt, n_live, bsz, meta, lower):
     return x + _mm(mlp, p["down"], lower)
 
 
+def weights(params, cfg) -> dict:
+    """The seeded weights (the program's parameter layout, one stacked
+    position ``pos0``) as the per-layer dicts ``logits`` takes."""
+    cyc = params["cycles"]["pos0"]
+    attn, ffn = cyc["attn"], cyc["ffn"]
+    layers = []
+    for l in range(cfg.n_layers):
+        p = {
+            "norm1": cyc["norm1"]["scale"][l], "norm2": cyc["norm2"]["scale"][l],
+            "wq": attn["wq"]["w"][l], "wk": attn["wk"]["w"][l],
+            "wv": attn["wv"]["w"][l], "wo": attn["wo"]["w"][l],
+            "gate": ffn["gate"]["w"][l], "up": ffn["up"]["w"][l],
+            "down": ffn["down"]["w"][l],
+        }
+        if cfg.qkv_bias:
+            p.update(bq=attn["wq"]["b"][l], bk=attn["wk"]["b"][l],
+                     bv=attn["wv"]["b"][l])
+        layers.append(p)
+    return {"embed": params["embed"], "final_norm": params["final_norm"]["scale"],
+            "lm_head": params["lm_head"], "layers": layers}
+
+
 @partial(jax.jit, static_argnames=("eps", "lower"))
 def _head(x, norm, w, eps, lower):
     return _mm(_rms(x, norm.astype(jnp.float32), eps), w, lower)

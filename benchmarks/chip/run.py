@@ -272,7 +272,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     gc.collect()
     jax.clear_caches()
     t_ref = clock()
-    weights = check.reference_weights(init_params(cfg, seed), cfg)
+    weights = check.reference_module(cell.config).weights(
+        init_params(cfg, seed), cfg)
     result = check.compare(weights, cell.config, cfg.sparse.block_sizes, items,
                            tr["max_context"], control=control)
     ref_s = clock() - t_ref

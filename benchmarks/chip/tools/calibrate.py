@@ -1,5 +1,6 @@
 #!/usr/bin/env python3
-"""Write the per-(layer, KV-head) block-size assignment into a config file.
+"""Write the per-(layer, KV-head) block-size assignment into a config file:
+one row for each global-attention layer (``model.sparse_layers``), in order.
 
 Runs the program's own offline calibration (``calibrate_for_config``: the
 Eq.-2 rule over the synthetic head-role generator, under the file's tau,
@@ -27,7 +28,7 @@ SEQ_LEN, BUDGET, SEED = 1024, 256, 0
 
 
 def dump(spec: dict) -> str:
-    """JSON with one line per layer of the assignment."""
+    """JSON with one line per row of the assignment."""
     rows = spec.pop("block_sizes")
     text = json.dumps(spec, indent=2)[:-2]
     body = ",\n".join(f"    {json.dumps(r)}" for r in rows)
@@ -43,21 +44,23 @@ def main():
     import jax
     import numpy as np
 
-    from model import load_config_file, model_config
+    from model import architecture, load_config_file, model_config, sparse_layers
     from repro.core import calibrate_for_config
 
     path = HERE / "configs" / f"{args.config}.json"
     spec = load_config_file(args.config)
-    n_kv, n_layers = spec["num_key_value_heads"], spec["num_hidden_layers"]
-    spec["block_sizes"] = [[64] * n_kv] * n_layers        # placeholder
+    n_kv, n_sparse = (spec["num_key_value_heads"],
+                      len(sparse_layers(architecture(spec))))
+    spec["block_sizes"] = [[64] * n_kv] * n_sparse        # placeholder
     cfg = model_config(spec)
     cfg = dataclasses.replace(cfg, sparse=dataclasses.replace(
         cfg.sparse, token_budget=BUDGET, block_sizes=None))
     _, res = calibrate_for_config(jax.random.PRNGKey(SEED), cfg,
                                   seq_len=SEQ_LEN, n_samples=1)
-    sizes, counts = np.unique(res.block_sizes, return_counts=True)
+    rows = [res.as_tuple()[l] for l in sparse_layers(cfg)]
+    sizes, counts = np.unique(rows, return_counts=True)
     spec = load_config_file(args.config)
-    spec["block_sizes"] = [list(row) for row in res.as_tuple()]
+    spec["block_sizes"] = [list(row) for row in rows]
     spec["block_sizes_made_by"] = (
         f"repro.core.calibrate_for_config(PRNGKey({SEED}), cfg, "
         f"seq_len={SEQ_LEN}, n_samples=1) with token_budget={BUDGET}, on the "

@@ -102,7 +102,7 @@ def one(workload: str, seed: int, seconds: float, trace_seconds: float,
     import run
     import spans
     import tracing
-    from model import model_config
+    from model import model_config, sparse_layers
 
     cell = run.load_cell(ROOT, workload)
     if trace_seconds:
@@ -129,7 +129,8 @@ def one(workload: str, seed: int, seconds: float, trace_seconds: float,
         got.update(
             host_tick_ms=spans.host_tick_ms(sp, win),
             decode_attended_fraction=spans.decode_attended_fraction(
-                sp, win, cfg.sparse.page_size, cfg.n_kv_heads, cfg.n_layers),
+                sp, win, cfg.sparse.page_size, cfg.n_kv_heads,
+                len(sparse_layers(cfg))),
             prefill_attended_fraction=spans.prefill_attended_fraction(sp, win),
             idle_by_span=spans.idle_by_span(tr, sp),
             ticks=len(ticks),
