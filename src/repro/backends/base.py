@@ -70,6 +70,14 @@ def at_layer(x: jax.Array, layer) -> jax.Array:
     return jax.lax.dynamic_index_in_dim(x, layer, keepdims=False)
 
 
+def at_rows(x: jax.Array, layer, slot, n: int) -> jax.Array:
+    """Rows ``slot .. slot + n`` of layer ``layer`` of an array stacked over
+    layers and slots; ``x`` itself when ``layer`` is None."""
+    if layer is None:
+        return x
+    return jax.lax.dynamic_slice_in_dim(at_layer(x, layer), slot, n)
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclass(frozen=True)
 class CentroidStore:
@@ -413,15 +421,20 @@ class AttentionBackend:
         chunk_end: jax.Array,
         sparse: SparseConfig,
         window: int,
+        layer=None,
+        slot=None,
     ) -> "CentroidStore":
         """Incremental scoring-segment update: re-encode the rows of blocks
-        completed by ``[chunk_start, chunk_end)`` (chunked prefill)."""
+        completed by ``[chunk_start, chunk_end)`` (chunked prefill).  With
+        ``layer`` and ``slot`` given, ``k_cache`` is stacked over layers and
+        slots and the window is read at ``(layer, slot)`` in place."""
         from repro.backends.store import refresh_score_rows
 
         codes, scale, zero = refresh_score_rows(
             score_store.codes, score_store.scale, score_store.zero,
             k_cache, layout, offsets, chunk_start, chunk_end, sparse, window,
             bits=score_store.bits, symmetric=score_store.symmetric,
+            layer=layer, slot=slot,
         )
         return CentroidStore(
             codes, scale, zero, score_store.bits, score_store.symmetric
@@ -459,6 +472,8 @@ class AttentionBackend:
         n_valid: Optional[jax.Array] = None,  # [B] live tokens after chunk
         chunk_offset=0,                       # abs pos of q[..., 0, :]
         max_pages_per_block: Optional[int] = None,
+        layer=None,
+        slot=None,
     ) -> Tuple[jax.Array, Optional[jax.Array]]:
         """Query-block sparse prefill attention: each query block attends
         forced (sink + local/diagonal) blocks plus its top-scored blocks.
@@ -466,10 +481,15 @@ class AttentionBackend:
         (:func:`repro.kernels.ops.sparse_prefill_reference` — same shared
         preamble as the kernel entry point); the Pallas backend overrides
         with the fused kernel.  ``chunk_offset`` must be a multiple of
-        ``sparse.prefill_block_q`` (chunked replay).
+        ``sparse.prefill_block_q`` (chunked replay).  With ``layer`` and
+        ``slot`` given, k/v are stacked over layers and slots (the prefill
+        chunk's carried pools) and query row ``b`` attends pool row
+        ``slot + b`` of layer ``layer``; this oracle reads a slice of them.
         -> (out [B, Hq, Sq, D], n_attended [B, n_kv, nQB])."""
         from repro.kernels import ops
 
+        k = at_rows(k, layer, slot, q.shape[0])
+        v = at_rows(v, layer, slot, q.shape[0])
         rq = rank_query(q, sparse.centroid_method, q.shape[-1])
         return ops.sparse_prefill_reference(
             q, rq, k, v, score_store, layout,

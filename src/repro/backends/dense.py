@@ -13,7 +13,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.backends.base import at_layer
+from repro.backends.base import at_layer, at_rows
 from repro.backends.reference import ReferenceBackend
 from repro.core.stacked import as_arrays
 from repro.core.sparse_attention import as_dense, dense_decode_attention
@@ -47,11 +47,13 @@ class DenseBackend(ReferenceBackend):
     def prefill_attention(
         self, q, k, v, score_store, layout, sparse,
         n_valid=None, chunk_offset=0,
-        max_pages_per_block=None,
+        max_pages_per_block=None, layer=None, slot=None,
     ):
         """Full-attention prefill oracle: every query attends its whole
         causal prefix; selection is ignored.  This is what the sparse
         prefill parity suite compares against at generous budgets."""
+        k = at_rows(k, layer, slot, q.shape[0])
+        v = at_rows(v, layer, slot, q.shape[0])
         kd = as_dense(k).astype(jnp.float32)
         vd = as_dense(v).astype(jnp.float32)
         B, Hq, Sq, D = q.shape

@@ -85,13 +85,15 @@ class PallasBackend(AttentionBackend):
     def prefill_attention(
         self, q, k, v, score_store, layout, sparse,
         n_valid=None, chunk_offset=0,
-        max_pages_per_block=None,
+        max_pages_per_block=None, layer=None, slot=None,
     ) -> Tuple[jax.Array, Optional[jax.Array]]:
         """Query-block sparse flash prefill in ONE Pallas launch
         (:mod:`repro.kernels.sparse_prefill`); the base-class jnp oracle
         remains the parity reference.  Under an active sharding context the
         launch is shard_map'd over the ``(data, model)`` mesh
-        (:mod:`repro.distributed.kernel_partition`)."""
+        (:mod:`repro.distributed.kernel_partition`).  With ``layer`` and
+        ``slot`` given, the launch reads the stacked pools in place (see
+        :meth:`AttentionBackend.prefill_attention`)."""
         from repro.distributed import kernel_partition
 
         rq = rank_query(q, sparse.centroid_method, q.shape[-1])
@@ -106,7 +108,7 @@ class PallasBackend(AttentionBackend):
                 chunk_offset=chunk_offset,
                 max_pages_per_block=max_pages_per_block
                 or sparse.max_block_size // sparse.page_size,
-                interpret=self._interp(),
+                interpret=self._interp(), layer=layer, slot=slot,
             )
 
     def decode(

@@ -234,17 +234,26 @@ def refresh_score_rows(
     window: int,                       # static token window, multiple of Bmax
     bits: Optional[int] = None,
     symmetric: Optional[bool] = None,
+    layer=None,
+    slot=None,
 ):
     """Incremental prefill-scoring update: re-encode the rows of every block
     COMPLETED by the chunk ``[chunk_start, chunk_end)`` from a static-size
     K window, leaving all other rows untouched.  Blocks still partial at
     ``chunk_end`` keep their stale bytes — they are not scoreable until a
-    later chunk completes them (and that chunk's window covers them)."""
+    later chunk completes them (and that chunk's window covers them).
+    With ``layer`` and ``slot`` given, ``k_cache`` is the paged pool of
+    every layer and slot ``[L, Bp, n_kv, n_pages, page, hd]`` and the
+    window is read from rows ``slot ..`` of layer ``layer`` where they
+    lie."""
     la = as_arrays(layout)
     bits = store_bits(sparse.quant) if bits is None else bits
     symmetric = store_symmetric(sparse.quant) if symmetric is None else symmetric
     page = sparse.page_size
-    B, n_kv, n_pages, _, hd = k_cache.shape
+    B = codes.shape[0]
+    if layer is None:
+        k_cache, layer, slot = k_cache[None], 0, 0
+    n_kv, n_pages, _, hd = k_cache.shape[2:]
     S_max = n_pages * page
     bmax = sparse.max_block_size
     assert window % bmax == 0 and window <= S_max, (window, bmax, S_max)
@@ -256,9 +265,9 @@ def refresh_score_rows(
     assert window >= bmax  # caller sizes it as chunk_len + 2 * bmax
     w0 = jnp.clip((chunk_start - bmax) // bmax * bmax, 0, S_max - window)
     win = jax.lax.dynamic_slice(
-        k_cache, (0, 0, w0 // page, 0, 0),
-        (B, n_kv, window // page, page, hd),
-    )
+        k_cache, (layer, slot, 0, w0 // page, 0, 0),
+        (1, B, n_kv, window // page, page, hd),
+    )[0]
     sel_win, _ = _selected_rank_keys(win, la, sparse)   # [B, n_kv, nW, Dp]
     new_codes, new_scale, new_zero = _encode_score_rows(
         sel_win, bits, symmetric

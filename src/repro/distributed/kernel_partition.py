@@ -47,7 +47,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.sparse_attention import as_paged
 from repro.core.stacked import LayoutArrays, as_arrays
 from repro.distributed.sharding import AxisVal, current_context
 from repro.kernels import ops
@@ -276,49 +275,69 @@ def sparse_prefill(
     chunk_offset=0,
     max_pages_per_block: Optional[int] = None,
     interpret: Optional[bool] = None,
+    layer=None,
+    slot=None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Mesh-partitioned :func:`repro.kernels.ops.sparse_prefill` — same
-    partitioning contract as :func:`fused_decode` (chunked-prefill calls
-    have batch 1, which degrades the batch axis to replication while kv
-    heads still shard)."""
+    partitioning contract as :func:`fused_decode`.  With ``layer`` and
+    ``slot`` given, ``k``/``v`` are the pools stacked over layers and slots
+    (see :func:`repro.kernels.ops.sparse_prefill`); the layer axis is never
+    sharded.  A prefill chunk is one slot's queries (batch 1, replicated)
+    against pools whose slot axis shards over ``data``: every data shard
+    then launches at the slot's row local to it, clamped into its own rows,
+    and only the shard that holds the slot contributes its output to a sum
+    over the data axis (the others add zeros, so the sum is exact)."""
     ctx = current_context()
     la = as_arrays(layout)
-    kp = as_paged(k, la.page_size)
-    vp = as_paged(v, la.page_size)
+    kp, vp, _, layer = ops.stacked_operands(k, v, None, la.page_size, layer)
+    slot = jnp.asarray(0 if slot is None else slot, jnp.int32)
     B = q.shape[0]
-    n_kv = kp.shape[1]
+    n_kv = kp.shape[2]
 
-    ba = ha = None
+    ba = ha = pa = None
     if ctx is not None:
         ba, ha = shard_axes(ctx.mesh, ctx.rules, B, n_kv)
-    if ba is None and ha is None:
+        pa, _ = shard_axes(ctx.mesh, ctx.rules, kp.shape[1], n_kv)
+    if ba is None and ha is None and pa is None:
         return ops.sparse_prefill(
             q, rq, kp, vp, score_store, la,
             sink_pages=sink_pages, local_pages=local_pages,
             block_q=block_q, topk_scale=topk_scale,
             n_valid=n_valid, chunk_offset=chunk_offset,
             max_pages_per_block=max_pages_per_block,
-            interpret=interpret,
+            interpret=interpret, layer=layer, slot=slot,
         )
     mesh = ctx.mesh
+    # the data axis that holds the slot, where the queries are not sharded
+    owner = pa if ba is None else None
 
     if n_valid is None:
         n_valid = jnp.asarray(chunk_offset + q.shape[2], jnp.int32)
     n_valid = jnp.broadcast_to(jnp.asarray(n_valid, jnp.int32), (B,))
     chunk_offset = jnp.asarray(chunk_offset, jnp.int32)
 
-    def local_call(q_l, rq_l, kp_l, vp_l, store_l, la_l, nv_l, co_l):
-        return ops.sparse_prefill(
+    def local_call(q_l, rq_l, kp_l, vp_l, store_l, la_l, nv_l, co_l,
+                   layer_l, slot_l):
+        if owner is not None:
+            rows = kp_l.shape[1]
+            here = slot_l - jax.lax.axis_index(owner) * rows
+            mine = (here >= 0) & (here < rows)
+            slot_l = jnp.clip(here, 0, rows - 1)
+        out, n_att = ops.sparse_prefill(
             q_l, rq_l, kp_l, vp_l, store_l, la_l,
             sink_pages=sink_pages, local_pages=local_pages,
             block_q=block_q, topk_scale=topk_scale,
             n_valid=nv_l, chunk_offset=co_l,
             max_pages_per_block=max_pages_per_block,
-            interpret=interpret,
+            interpret=interpret, layer=layer_l, slot=slot_l,
         )
+        if owner is not None:
+            out = jax.lax.psum(jnp.where(mine, out, 0), owner)
+            n_att = jax.lax.psum(jnp.where(mine, n_att, 0), owner)
+        return out, n_att
 
     qs = P(ba, ha, None, None)
-    kvs = P(ba, ha, None, None, None)
+    kvs = P(None, ba or pa, ha, None, None, None)
     out, n_att = jax.shard_map(
         local_call,
         mesh=mesh,
@@ -328,10 +347,13 @@ def sparse_prefill(
             _layout_specs(la, ha),
             P(ba),
             P(),
+            P(),
+            P(),
         ),
         out_specs=(qs, P(ba, ha, None)),
         check_vma=False,
-    )(q, rq, kp, vp, score_store, la, n_valid, chunk_offset)
+    )(q, rq, kp, vp, score_store, la, n_valid, chunk_offset,
+      jnp.asarray(layer, jnp.int32), slot)
     out = jax.lax.with_sharding_constraint(
         out, NamedSharding(mesh, P(ba, None, None, None))
     )

@@ -171,14 +171,14 @@ def _dma_window(layout, la, max_pages_per_block, kernel: str) -> int:
 
 
 def stacked_operands(k, v, codes, page_size: int, layer=None):
-    """The fused decode kernel's operand form: KV pools
+    """The sparse kernels' operand form: KV pools
     ``[L, B, n_kv, nP, page, D]`` and store codes ``[L, B, rows, Cw]``
     stacked over layers, plus the layer to read.  With ``layer=None`` the
     inputs are one layer's (KV paged or dense 4-D) and gain a unit layer
-    axis, a bitcast."""
+    axis, a bitcast.  ``codes`` may be None (sparse prefill reads none)."""
     if layer is None:
         return (as_paged(k, page_size)[None], as_paged(v, page_size)[None],
-                codes[None], 0)
+                None if codes is None else codes[None], 0)
     return k, v, codes, layer
 
 
@@ -254,14 +254,13 @@ def fused_decode(
 
 
 def _prefill_query_blocks(
-    q, rq, kp, la, block_q, topk_scale, n_valid, chunk_offset
+    q, rq, n_kv, la, block_q, topk_scale, n_valid, chunk_offset
 ):
     """Shared preamble of the sparse-prefill kernel AND its jnp oracle:
     query-block padding/reshape, the prefill-scaled per-head K, live-length
     broadcast, and the chunk's query-block base index.  One definition so
     the two entry points cannot drift apart."""
     B, Hq, Sq, _ = q.shape
-    n_kv = kp.shape[1]
     g = Hq // n_kv
     nQB = -(-Sq // block_q)
     pad = nQB * block_q - Sq
@@ -314,7 +313,7 @@ def sparse_prefill_reference(
     vp = as_paged(v, la.page_size)
     Sq = q.shape[2]
     q6, rq6, k_sel, n_valid, qb0, _ = _prefill_query_blocks(
-        q, rq, kp, la, block_q, topk_scale, n_valid, chunk_offset
+        q, rq, kp.shape[1], la, block_q, topk_scale, n_valid, chunk_offset
     )
     rank_rows = ref.dequant_score_rows(
         score_store.codes, score_store.scale, score_store.zero,
@@ -342,6 +341,8 @@ def sparse_prefill(
     chunk_offset=0,             # absolute pos of q[..., 0, :]; block_q-aligned
     max_pages_per_block: Optional[int] = None,
     interpret: Optional[bool] = None,
+    layer=None,
+    slot=None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Single-launch query-block sparse prefill over the ragged layout.
 
@@ -349,7 +350,10 @@ def sparse_prefill(
     affine codes from :func:`repro.backends.store.build_score_rows`).
     ``chunk_offset``/``n_valid`` replay later chunks of a chunked prefill
     through the identical kernel (`n_valid` defaults to
-    ``chunk_offset + Sq``, the live length after this chunk).
+    ``chunk_offset + Sq``, the live length after this chunk).  With
+    ``layer`` and ``slot`` given, ``k``/``v`` are the paged pools of every
+    layer and slot ``[L, Bp, n_kv, nP, page, D]`` and query row ``b`` reads
+    pool row ``slot + b`` of layer ``layer`` where it lies.
     -> (out [B, Hq, Sq, D], n_attended [B, n_kv, nQB]).
     """
     from repro.core.stacked import as_arrays
@@ -358,11 +362,10 @@ def sparse_prefill(
     if interpret is None:
         interpret = default_interpret()
     la = as_arrays(layout)
-    kp = as_paged(k, la.page_size)
-    vp = as_paged(v, la.page_size)
+    kp, vp, _, layer = stacked_operands(k, v, None, la.page_size, layer)
     Sq = q.shape[2]
     q6, rq6, k_sel, n_valid, qb0, _ = _prefill_query_blocks(
-        q, rq, kp, la, block_q, topk_scale, n_valid, chunk_offset
+        q, rq, kp.shape[2], la, block_q, topk_scale, n_valid, chunk_offset
     )
     max_pages_per_block = _dma_window(
         layout, la, max_pages_per_block, "sparse_prefill"
@@ -378,6 +381,7 @@ def sparse_prefill(
         q6, rq6, kp, vp, rank_rows,
         la.row_offsets, la.n_blocks, k_sel,
         la.block_sizes, la.pages_per_block, n_valid, qb0,
+        layer, 0 if slot is None else slot,
         page_size=la.page_size,
         ppb_max=max_pages_per_block,
         block_q=block_q,

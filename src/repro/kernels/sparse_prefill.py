@@ -60,12 +60,14 @@ def _sparse_prefill_kernel(
     ppb_ref,                   # [H] int32 pages per block
     n_valid_ref,               # [B] int32 live tokens (queries AND keys)
     qb0_ref,                   # [1] int32 absolute index of query block 0
+    layer_ref,                 # [1] int32 layer of the stacked pools
+    slot_ref,                  # [1] int32 pool row of query row 0
     # -- array inputs
     rows_ref,                  # [B, R, Dp] f32 rank rows (HBM/ANY)
     rq_ref,                    # [1, 1, 1, g, BQ, Dp] rank queries
     q_ref,                     # [1, 1, 1, g, BQ, D]
-    k_ref,                     # [B, H, n_pages, ps, D] paged pool (HBM/ANY)
-    v_ref,                     # [B, H, n_pages, ps, D]
+    k_ref,                     # [L, Bp, H, n_pages, ps, D] pools (HBM/ANY)
+    v_ref,                     # [L, Bp, H, n_pages, ps, D]
     # -- outputs
     o_ref,                     # [1, 1, 1, g, BQ, D]
     nsel_ref,                  # [B, H, nQB] int32 blocks attended (SMEM)
@@ -88,6 +90,8 @@ def _sparse_prefill_kernel(
     bsz = bsz_ref[h]
     ppb = ppb_ref[h]
     nv = n_valid_ref[b]
+    lyr = layer_ref[0]
+    row = slot_ref[0] + b                              # this query row's pool row
     q_start = (qb0_ref[0] + qb) * block_q
     q_end = jnp.minimum(q_start + block_q, nv) - 1     # last live query pos
 
@@ -145,11 +149,11 @@ def _sparse_prefill_kernel(
         pg = page_start(p)
         return (
             pltpu.make_async_copy(
-                k_ref.at[b, h, pl.ds(pg, ppb_max)], kbuf.at[slot],
+                k_ref.at[lyr, row, h, pl.ds(pg, ppb_max)], kbuf.at[slot],
                 sem.at[slot, 0],
             ),
             pltpu.make_async_copy(
-                v_ref.at[b, h, pl.ds(pg, ppb_max)], vbuf.at[slot],
+                v_ref.at[lyr, row, h, pl.ds(pg, ppb_max)], vbuf.at[slot],
                 sem.at[slot, 1],
             ),
         )
@@ -230,8 +234,8 @@ def _sparse_prefill_kernel(
 def sparse_prefill(
     q: jax.Array,              # [B, n_kv, nQB, g, BQ, D]
     rq: jax.Array,             # [B, n_kv, nQB, g, BQ, Dp] rank queries
-    k_pages: jax.Array,        # [B, n_kv, n_pages, page, D]
-    v_pages: jax.Array,        # [B, n_kv, n_pages, page, D]
+    k_pages: jax.Array,        # [L, Bp, n_kv, n_pages, page, D] stacked pools
+    v_pages: jax.Array,        # [L, Bp, n_kv, n_pages, page, D]
     rank_rows: jax.Array,      # [B, total_rows, Dp] f32 dequantized rows
     row_off: jax.Array,        # [H] int32 descriptor arrays ----------------
     n_blocks: jax.Array,       # [H] int32
@@ -240,6 +244,8 @@ def sparse_prefill(
     ppb: jax.Array,            # [H] int32
     n_valid: jax.Array,        # [B] int32
     qb0: jax.Array,            # [1] int32
+    layer: jax.Array,          # int32 scalar: the layer the call reads
+    slot: jax.Array,           # int32 scalar: the pool row of query row 0
     *,
     page_size: int,
     ppb_max: int,
@@ -255,10 +261,13 @@ def sparse_prefill(
     One launch covers every (sequence, kv head, query block) cell of the
     ragged grid; the attended block SET per cell is forced-union-top-K and
     identical whether the query blocks arrive in one shot (``qb0 == 0``)
-    or chunk by chunk (``qb0 == chunk_offset // block_q``).
+    or chunk by chunk (``qb0 == chunk_offset // block_q``).  The KV pools
+    arrive stacked over layers and slots, as the prefill chunk's layer scan
+    carries them; query row ``b`` reads pool row ``slot + b`` of layer
+    ``layer`` in place, so no slice of a pool exists as a buffer.
     """
     B, n_kv, nQB, g, BQ, D = q.shape
-    n_pages = k_pages.shape[2]
+    n_pages = k_pages.shape[3]
     Dp = rq.shape[-1]
     total_rows = rank_rows.shape[1]
 
@@ -276,7 +285,7 @@ def sparse_prefill(
         scale_qk=1.0 / float(np.sqrt(D)),
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
+        num_scalar_prefetch=9,
         grid=(B, n_kv, nQB),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),      # rank rows
@@ -320,6 +329,8 @@ def sparse_prefill(
         ppb.astype(jnp.int32),
         n_valid.astype(jnp.int32),
         qb0.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        jnp.asarray(slot, jnp.int32).reshape(1),
         rank_rows.astype(jnp.float32),
         rq,
         q,

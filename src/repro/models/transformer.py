@@ -36,9 +36,54 @@ from repro.models import layers, moe as moe_mod, rglru, rwkv6
 
 Cache = Dict[str, Any]
 
-#: per layer kind, the cache leaves a decode step writes one token of: they
-#: ride the decode layer scan's carry and are updated in place.
-_IN_PLACE = {"attn": ("k", "v", "codes")}
+#: per step, and per layer kind, the cache leaves the step writes part of:
+#: they ride its layer scan's carry and are updated in place
+#: (:meth:`Transformer._scan_cycles`).  A decode step writes one token of
+#: KV and its block's centroid row; a prefill chunk writes the chunk's KV
+#: and the scoring rows of the blocks it completes.
+_IN_PLACE = {
+    "decode_step": {"attn": ("k", "v", "codes")},
+    "prefill_chunk": {"attn": ("k", "v", "pcodes", "pscale", "pzero")},
+}
+
+
+def _pooled(step: str, kind: str, entry) -> Dict[str, Any]:
+    """The leaves of ``entry`` that ``step`` carries in place."""
+    return {k: entry[k] for k in _IN_PLACE[step].get(kind, ()) if k in entry}
+
+
+def _write_chunk(pool, new, layer, slot, offset, n_valid):
+    """Write chunk rows ``new`` [C, n_kv, hd] at token positions
+    ``offset + i`` (``i < n_valid``, position < the pool's length) of
+    ``(layer, slot)`` of a pool stacked over layers and slots, dense
+    ``[L, B, n_kv, S, hd]`` or paged ``[L, B, n_kv, nP, ps, hd]``; every
+    other row keeps its bytes.  One dynamic-update-slice of the chunk's
+    window of pages (tokens, dense), not a scatter: a scatter makes the TPU
+    compiler pick a layout of its own for the pool, and the pool then
+    crosses layouts (whole-pool copies) on its way to and from the kernel.
+    The window is read, merged and written back, so padding rows leave
+    their stale bytes; its start is clamped here, where the positions are
+    computed, so the slice's own clamp never shifts the chunk."""
+    C, n_kv, hd = new.shape
+    paged = pool.ndim == 6
+    ps = pool.shape[4] if paged else 1
+    n_units = pool.shape[3]                   # pages (paged) or tokens
+    # units the chunk can touch: one more page when it is not page-aligned
+    nw = min(-(-C // ps) + (1 if paged else 0), n_units)
+    u0 = jnp.minimum(offset // ps, n_units - nw)
+    start = (layer, slot, 0, u0) + (0,) * (pool.ndim - 4)
+    size = (1, 1, n_kv, nw) + pool.shape[4:]
+    win = jax.lax.dynamic_slice(pool, start, size)
+    n = nw * ps                                   # tokens in the window
+    r = u0 * ps + jnp.arange(n) - offset          # chunk row of each token
+    ok = ((r >= 0) & (r < n_valid)).reshape((1, 1, 1) + size[3:-1] + (1,))
+    # the window's rows of the chunk: a slice of the chunk padded by a
+    # window on each side (a gather would lay the chunk, and through the
+    # update the pool, out token-major)
+    ext = jnp.pad(jnp.moveaxis(new, 0, 1), ((0, 0), (n, n), (0, 0)))
+    rows = jax.lax.dynamic_slice_in_dim(ext, n + r[0], n, axis=1)
+    rows = rows.reshape(size).astype(pool.dtype)  # [.., n_kv, nw(, ps), hd]
+    return jax.lax.dynamic_update_slice(pool, jnp.where(ok, rows, win), start)
 
 
 def _attn_chunk(S: int, target: int = 512) -> int:
@@ -589,6 +634,46 @@ class Transformer:
         cache["seq_len"] = jnp.full((B,), S_tot, jnp.int32)
         return logits, cache
 
+    # ------------------------------------------------------- layer scan
+
+    def _scan_cycles(self, step: str, layer_fn, x, params, cache: Cache):
+        """Run the pattern cycles of ``step`` as one layer scan.
+
+        The leaves ``_IN_PLACE[step]`` names for a layer kind ride the
+        scan's carry, stacked over cycles, and ``layer_fn`` updates them in
+        place at the cycle's index: no layer slice, copy or write-back of a
+        pool.  Every other leaf is scanned over; what a layer only reads is
+        not returned.  ``layer_fn(p, kind, x, entry, pools, layer) -> (x,
+        pools, writes)``.  -> ``(x, pools, written)``: per position, the
+        carried leaves and the layers' other outputs stacked over cycles."""
+        pat = self.plan.pattern
+        pools_in, xs_in = {}, {}
+        for i, kind in enumerate(pat):
+            entry = cache[f"pos{i}"]
+            pools_in[f"pos{i}"] = _pooled(step, kind, entry)
+            xs_in[f"pos{i}"] = {
+                k: a for k, a in entry.items() if k not in pools_in[f"pos{i}"]
+            }
+
+        def cycle_fn(carry, xs):
+            x, pools = carry
+            cyc_params, cyc_cache, cyc_idx = xs
+            pools, writes = dict(pools), {}
+            for i, kind in enumerate(pat):
+                pos = f"pos{i}"
+                x, pools[pos], writes[pos] = layer_fn(
+                    cyc_params[pos], kind, x, cyc_cache[pos], pools[pos],
+                    cyc_idx,
+                )
+            return (x, pools), writes
+
+        (x, pools), written = jax.lax.scan(
+            cycle_fn,
+            (x, pools_in),
+            (params["cycles"], xs_in, jnp.arange(self.plan.n_cycles)),
+        )
+        return x, pools, written
+
     # ------------------------------------------------------- chunked prefill
 
     def supports_chunked_prefill(self) -> bool:
@@ -612,10 +697,12 @@ class Transformer:
         the slot's cache and attends each chunk query to the already-written
         prefix plus the causal part of the chunk — so a prompt can be
         prefilled across many engine ticks, interleaved with decode steps
-        for the rest of the batch.  Padding rows (``>= n_valid``) produce
-        out-of-bounds scatter indices and are dropped; chunk buffers keep a
-        single compiled shape.  Centroid-store rows are NOT maintained here:
-        call :meth:`refresh_slot_store` once after the final chunk.
+        for the rest of the batch.  Padding rows (``>= n_valid``) are not
+        written; chunk buffers keep a single compiled shape.  The KV pools
+        (and, sparse, the running scoring segment) ride the layer scan's
+        carry and are written in place (``_IN_PLACE``, :func:`_write_chunk`).
+        Centroid-store rows are NOT maintained here: call
+        :meth:`refresh_slot_store` once after the final chunk.
 
         When ``SparseConfig.sparse_prefill`` is on, the chunk instead runs
         the query-block sparse prefill path: the slot's RUNNING scoring
@@ -640,15 +727,14 @@ class Transformer:
         x = params["embed"][tokens][None]                 # [1, C, d]
         rel = jnp.arange(C)
         positions = (offset + rel)[None]                  # [1, C]
-        valid = rel < n_valid
         paged = cache["pos0"]["k"].ndim == 6              # sparse-active cache
         ps = cfg.sparse.page_size
         if paged:
             S_max = cache["pos0"]["k"].shape[3] * ps
+            axes = (None, "batch", "kv_heads", "kv_pages", None, "head_dim")
         else:
             S_max = cache["pos0"]["k"].shape[3]
-        # invalid rows scatter out of bounds -> dropped (JAX semantics).
-        write_pos = jnp.where(valid, offset + rel, S_max)
+            axes = (None, "batch", "kv_heads", "kv_pages", "head_dim")
         stk = cache.get("_layouts")
         all_offs = cache.get("_offsets")
         use_sp = cfg.sparse.sparse_prefill and "pcodes" in cache["pos0"]
@@ -663,75 +749,72 @@ class Transformer:
             sp_window = min(-(-(C + 2 * bmax) // bmax) * bmax, S_max)
             sp_bits = store_bits(cfg.sparse.quant)
             sp_sym = store_symmetric(cfg.sparse.quant)
+            seg_names = ("pcodes", "pscale", "pzero")
 
-        def run_layer(p, x, entry, lay, offs):
+        def lay_of(layer):
+            return stk.layer(layer) if stk is not None else None
+
+        def offs_of(layer):
+            return all_offs[layer] if all_offs is not None else None
+
+        def run_layer(p, kind, x, entry, pools, layer):
+            """-> (x, pools, writes): ``pools`` this position's carried
+            leaves, stacked over layers and slots, written in place at
+            ``(layer, slot)``."""
             h = layers.rms_norm(p["norm1"], x, cfg.norm_eps)
             q, k, v = layers.qkv_project(p["attn"], h, cfg, positions)
-            new_entry = dict(entry)
-            # mixed scalar/array advanced indices around the head slice put
-            # the broadcast (chunk) axis first: updates are [C, n_kv, hd].
-            if paged:
-                k_cache = entry["k"].at[
-                    slot, :, write_pos // ps, write_pos % ps
-                ].set(k[0].astype(entry["k"].dtype))
-                v_cache = entry["v"].at[
-                    slot, :, write_pos // ps, write_pos % ps
-                ].set(v[0].astype(entry["v"].dtype))
-            else:
-                k_cache = entry["k"].at[slot, :, write_pos].set(
-                    k[0].astype(entry["k"].dtype)
+            pools, writes = dict(pools), {}
+            for name, new in (("k", k[0]), ("v", v[0])):
+                pools[name] = constrain(
+                    _write_chunk(pools[name], new, layer, slot, offset, n_valid),
+                    *axes,
                 )
-                v_cache = entry["v"].at[slot, :, write_pos].set(
-                    v[0].astype(entry["v"].dtype)
-                )
-            new_entry["k"] = k_cache
-            new_entry["v"] = v_cache
             if use_sp:
                 # sparse chunk: refresh the slot's running scoring segment
                 # with the blocks this chunk completes, then query-block
-                # sparse attention over the slot's paged KV.
-                kslot = k_cache[slot][None]               # [1, n_kv, nP, ps, hd]
-                vslot = v_cache[slot][None]
+                # sparse attention over the slot's paged KV, read where it
+                # lies.  The segment is a few rows a block; its slot's rows
+                # are sliced, refreshed and written back.
+                def seg_rows(a):
+                    return jax.lax.dynamic_slice(
+                        a, (layer, slot, 0, 0), (1, 1) + a.shape[2:]
+                    )[0]
+
                 sstore = CentroidStore(
-                    entry["pcodes"][slot][None],
-                    entry["pscale"][slot][None],
-                    entry["pzero"][slot][None],
-                    sp_bits, sp_sym,
+                    *(seg_rows(pools[n]) for n in seg_names), sp_bits, sp_sym
                 )
                 sstore = self.backend.refresh_score_rows(
-                    sstore, kslot, lay, offs,
+                    sstore, pools["k"], lay_of(layer), offs_of(layer),
                     offset, offset + n_valid, cfg.sparse, sp_window,
+                    layer=layer, slot=slot,
                 )
-                new_entry["pcodes"] = entry["pcodes"].at[slot].set(
-                    sstore.codes[0]
-                )
-                new_entry["pscale"] = entry["pscale"].at[slot].set(
-                    sstore.scale[0]
-                )
-                new_entry["pzero"] = entry["pzero"].at[slot].set(
-                    sstore.zero[0]
-                )
+                for n, a in zip(seg_names, (sstore.codes, sstore.scale,
+                                            sstore.zero)):
+                    pools[n] = jax.lax.dynamic_update_slice(
+                        pools[n], a[None], (layer, slot, 0, 0)
+                    )
                 attn_o, n_att = self.backend.prefill_attention(
-                    jnp.moveaxis(q, 1, 2), kslot, vslot, sstore,
-                    lay, cfg.sparse,
+                    jnp.moveaxis(q, 1, 2), pools["k"], pools["v"], sstore,
+                    lay_of(layer), cfg.sparse,
                     n_valid=offset + n_valid, chunk_offset=offset,
-                    max_pages_per_block=sp_ppb_max,
+                    max_pages_per_block=sp_ppb_max, layer=layer, slot=slot,
                 )
                 if count_blocks:
-                    new_entry["_blocksq"] = jnp.sum(n_att).astype(jnp.uint32)
+                    writes["_blocksq"] = jnp.sum(n_att).astype(jnp.uint32)
                 h = layers.out_project(
                     p["attn"], jnp.moveaxis(attn_o, 1, 2), cfg
                 )
             else:
-                # masked dense attention over the slot's rows: prefix +
-                # causal chunk.  Rows beyond offset+i are masked, so stale
-                # garbage past the live span never contributes.
-                kf = k_cache[slot].reshape(
-                    cfg.n_kv_heads, S_max, -1
-                ).astype(jnp.float32)                     # [n_kv, S, hd]
-                vf = v_cache[slot].reshape(
-                    cfg.n_kv_heads, S_max, -1
-                ).astype(jnp.float32)
+                # masked dense attention over the slot's rows of this
+                # layer: prefix + causal chunk.  Rows beyond offset+i are
+                # masked, so stale garbage past the live span never
+                # contributes.
+                def slot_rows(a):
+                    return at_layer(a, layer)[slot].reshape(
+                        cfg.n_kv_heads, S_max, -1
+                    ).astype(jnp.float32)                 # [n_kv, S, hd]
+
+                kf, vf = slot_rows(pools["k"]), slot_rows(pools["v"])
                 g = cfg.n_heads // cfg.n_kv_heads
                 hd = cfg.resolved_head_dim
                 qf = jnp.moveaxis(q, 1, 2)[0].reshape(
@@ -754,34 +837,18 @@ class Transformer:
                 h, _ = moe_mod.moe_ffn(p["ffn"], h, cfg)
             else:
                 h = layers.mlp(p["ffn"], h, cfg.activation)
-            return x + h, new_entry
-
-        def cycle_fn(x, xs):
-            cyc_params, cyc_cache, cyc_idx = xs
-            lay = stk.layer(cyc_idx) if (use_sp and stk is not None) else None
-            offs = all_offs[cyc_idx] if (use_sp and all_offs is not None) else None
-            x, new_entry = run_layer(
-                cyc_params["pos0"], x, cyc_cache["pos0"], lay, offs
-            )
-            return x, {"pos0": new_entry}
+            return x + h, pools, writes
 
         cache = dict(cache)
         if self.plan.n_cycles > 0:
-            x, new_cyc = jax.lax.scan(
-                cycle_fn,
-                x,
-                (
-                    params["cycles"],
-                    {"pos0": cache["pos0"]},
-                    jnp.arange(self.plan.n_cycles),
-                ),
+            x, pools, written = self._scan_cycles(
+                "prefill_chunk", run_layer, x, params, cache
             )
-            entry = new_cyc["pos0"]
             if count_blocks:                              # [n_cycles] uint32
                 cache["_prefill_blocks"] = (
-                    cache["_prefill_blocks"] + entry.pop("_blocksq")
+                    cache["_prefill_blocks"] + written["pos0"]["_blocksq"]
                 )
-            cache["pos0"] = entry
+            cache["pos0"] = {**cache["pos0"], **pools["pos0"]}
         x = layers.rms_norm(params["final_norm"], x, cfg.norm_eps)
         h_last = jnp.take(x[0], n_valid - 1, axis=0)      # last valid row
         logits = self.unembed(params, h_last)
@@ -957,30 +1024,10 @@ class Transformer:
                 h = layers.mlp(p["ffn"], h, cfg.activation)
             return x + h, pools, writes
 
-        # A cache leaf that a layer writes one token of per step (the
-        # attention KV pools and the centroid codes the append refreshes)
-        # rides the layer scan's carry, stacked over cycles, and is updated
-        # in place at the cycle's index: no layer slice, copy or write-back
-        # of the pool.  Every other leaf is scanned over; what a layer
-        # returns leaves as the scan's output, and what it only reads
-        # (store scale/zero, the prefill scoring segment) is returned as it
-        # came in.
-        def pooled(kind, entry):
-            return {k: entry[k] for k in _IN_PLACE.get(kind, ()) if k in entry}
-
-        def cycle_fn(carry, xs):
-            x, pools = carry
-            cyc_params, cyc_cache, cyc_idx = xs
-            pools, writes = dict(pools), {}
-            for i, kind in enumerate(pat):
-                pos = f"pos{i}"
-                lay = stk.layer(cyc_idx) if (stk is not None and kind == "attn") else None
-                offs = offsets[cyc_idx] if (offsets is not None and kind == "attn") else None
-                x, pools[pos], writes[pos] = run_layer(
-                    cyc_params[pos], kind, x, cyc_cache[pos], pools[pos],
-                    cyc_idx, lay, offs,
-                )
-            return (x, pools), writes
+        def layer_fn(p, kind, x, entry, pools, cyc_idx):
+            lay = stk.layer(cyc_idx) if (stk is not None and kind == "attn") else None
+            offs = offsets[cyc_idx] if (offsets is not None and kind == "attn") else None
+            return run_layer(p, kind, x, entry, pools, cyc_idx, lay, offs)
 
         if collect:
             sel_acc = jnp.zeros_like(cache["_sel_pages"])
@@ -990,18 +1037,8 @@ class Transformer:
         if count_pages:
             pages_acc = jnp.zeros_like(cache["_decode_pages"])   # [L, B]
         if self.plan.n_cycles > 0:
-            pools_in, cyc_cache_in = {}, {}
-            for i, kind in enumerate(pat):
-                entry = cache[f"pos{i}"]
-                pools_in[f"pos{i}"] = pooled(kind, entry)
-                cyc_cache_in[f"pos{i}"] = {
-                    k: a for k, a in entry.items()
-                    if k not in pools_in[f"pos{i}"]
-                }
-            (x, pools), written = jax.lax.scan(
-                cycle_fn,
-                (x, pools_in),
-                (params["cycles"], cyc_cache_in, jnp.arange(self.plan.n_cycles)),
+            x, pools, written = self._scan_cycles(
+                "decode_step", layer_fn, x, params, cache
             )
             for i, kind in enumerate(pat):
                 got = dict(written[f"pos{i}"])
@@ -1027,7 +1064,9 @@ class Transformer:
             offs = offsets[lay_idx] if (offsets is not None and kind == "attn") else None
             entry = cache["rest"][i]
             # an unscanned layer's pools take a unit layer axis (a bitcast)
-            pools = {k: a[None] for k, a in pooled(kind, entry).items()}
+            pools = {
+                k: a[None] for k, a in _pooled("decode_step", kind, entry).items()
+            }
             x, pools, got = run_layer(
                 params["rest"][i], kind, x, entry, pools, 0, lay, offs
             )

@@ -159,6 +159,35 @@ def test_append_refreshes_stacked_codes_at_layer(layer):
         np.testing.assert_array_equal(got[l], want if l == layer else codes[l])
 
 
+@pytest.mark.parametrize("layer,slot", [(0, 1), (2, 0)])
+def test_score_rows_refresh_reads_stacked_pool_at_layer_and_slot(layer, slot):
+    """Chunked prefill's scoring-segment refresh on the K pool stacked over
+    layers and slots (the prefill scan's carried form) reads its window at
+    ``(layer, slot)`` as the refresh on that slot's own slice does."""
+    from repro.core.stacked import as_arrays
+
+    L = 3
+    lay = layout_for(BLOCK_SIZES, S, 16, BUDGET)
+    la, offs = as_arrays(lay), jnp.asarray(lay.offsets[:-1])
+    sparse = SparseConfig(token_budget=BUDGET)
+    be = get_backend("reference")
+    paged = lambda k: k.reshape(B, N_KV, S // 16, 16, D)
+    k_all = jnp.stack([paged(_qkv(seed=50 + l)[1]) for l in range(L)])
+    k_slot = k_all[layer, slot][None]
+    store = be.prefill_score_rows(
+        jnp.zeros_like(k_slot), la, offs, sparse, quant="int4_asym"
+    )
+    chunk = (jnp.int32(512), jnp.int32(768), sparse, 256 + 2 * 64)
+    got = be.refresh_score_rows(
+        store, k_all, la, offs, *chunk,
+        layer=jnp.int32(layer), slot=jnp.int32(slot),
+    )
+    want = be.refresh_score_rows(store, k_slot, la, offs, *chunk)
+    assert not np.array_equal(want.codes, store.codes)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 @pytest.mark.parametrize("quant", ["none", "int8_asym", "int4_asym"])
 def test_backends_match_dense_oracle_at_full_budget(quant):
     """Every sparse backend == the dense oracle when the budget covers the

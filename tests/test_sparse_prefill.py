@@ -275,6 +275,35 @@ def test_chunked_token_identical_to_single_shot(quant):
     assert np.array_equal(np.asarray(chunked)[m], np.asarray(single)[m])
 
 
+@pytest.mark.parametrize("layer,slot", [(0, 1), (2, 0)])
+def test_kernel_reads_stacked_pools_at_layer_and_slot(layer, slot):
+    """The prefill chunk hands the kernel every layer's and every slot's
+    pools with the layer and slot to read: that call must equal today's
+    batch-1 call on the slot's own slice, with layers and slots holding
+    different KV (a wrong offset reads another layer's or slot's pages)."""
+    L, chunk, off = 3, 128, 256
+    lay = layout_for(NONUNIFORM, S, 16, 256)
+    cfg = SparseConfig(token_budget=256)
+    qkv = [_qkv(seed=40 + l) for l in range(L)]
+    k_all = jnp.stack([_paged(k) for _, k, _ in qkv])   # [L, B, n_kv, nP, ..]
+    v_all = jnp.stack([_paged(v) for _, _, v in qkv])
+    kp, vp = k_all[layer, slot][None], v_all[layer, slot][None]
+    q = qkv[0][0][slot:slot + 1, :, off:off + chunk]     # one slot's chunk
+    rq = rank_query(q, cfg.centroid_method, D)
+    ss = _score_store(kp, lay, cfg, "int4_asym")
+    kw = dict(
+        sink_pages=cfg.sink_pages, local_pages=cfg.local_pages, block_q=BQ,
+        n_valid=off + chunk, chunk_offset=off, interpret=True,
+    )
+    got = ops.sparse_prefill(
+        q, rq, k_all, v_all, ss, lay,
+        layer=jnp.int32(layer), slot=jnp.int32(slot), **kw
+    )
+    want = ops.sparse_prefill(q, rq, kp, vp, ss, lay, **kw)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 def test_model_prefill_backend_parity_and_chunk_identity():
     """Model-level: pallas == reference through a full Transformer, and
     prefill_chunk reproduces single-shot prefill bitwise (store included)."""

@@ -157,49 +157,60 @@ def _hlo_instructions(hlo: str):
     return out
 
 
-def test_decode_step_updates_kv_pools_in_place_on_v5e(sds):
-    """The decode step's layer scan carries the stacked KV pools and writes
-    each new token in place: the compiled program holds no copy, slice,
-    update-slice or allocation of one layer's pool or of the whole stack,
-    and the donated pools are the step's outputs.  At these widths the
-    whole stack fits the chip's fast memory (memory space ``S(1)``), and the
-    compiler may stage it there with an asynchronous copy in and out; that
-    is exempt, as a deployment-sized pool (gigabytes) never fits."""
+def _small_model(**sparse):
+    """llama3.2-3b's attention widths at three layers and a small FFN and
+    vocabulary, on the Pallas path with the mixed block sizes."""
     import dataclasses
 
-    from repro.backends import get_backend
     from repro.models import Transformer
 
-    L, batch, ctx = 3, 2, 2048
+    L = 3
     cfg = dataclasses.replace(
         CFG, n_layers=L, d_model=512, d_ff=1024, vocab_size=1024,
         head_dim=D,
         sparse=dataclasses.replace(
             SP, backend="pallas", fused_decode=True, sparse_prefill=True,
-            token_budget=512, block_sizes=(MIXED,) * L,
+            token_budget=512, block_sizes=(MIXED,) * L, **sparse,
         ),
     )
-    model = Transformer(cfg, context_len=ctx)
+    return Transformer(cfg, context_len=2048), L
+
+
+def _compile_step(sds, model, step, cache, *args):
+    """HLO text of ``step`` (params, cache donated, *args) compiled for
+    v5e, with the Pallas backend pinned to Mosaic."""
+    from repro.backends import get_backend
+
     as_sds = lambda a: sds(a.shape, a.dtype)
     params = jax.tree.map(
         as_sds, jax.eval_shape(model.init, jax.random.PRNGKey(0))
     )
-    cache = jax.tree.map(
-        as_sds, jax.eval_shape(lambda: model.init_cache(batch, ctx))
-    )
-    cache["_decode_pages"] = sds((L, batch), jnp.int32)
     backend = get_backend("pallas")
     interpret, backend.interpret = backend.interpret, False
     try:
         # the engine's form: the cache donated
-        hlo = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
-            params, cache, sds((batch,), jnp.int32)
+        return jax.jit(step, donate_argnums=(1,)).lower(
+            params, cache, *args
         ).compile().as_text()
     finally:
         backend.interpret = interpret
 
-    assert "tpu_custom_call" in hlo
-    layer = (batch, NKV, ctx // PS, PS, D)
+
+def _small_cache(sds, model, batch, ctx):
+    return jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: model.init_cache(batch, ctx)),
+    )
+
+
+def _assert_pools_in_place(hlo, layer, L):
+    """No copy, slice, update-slice or allocation of one layer's pool
+    ``layer``, its unit-layer form or the stack of ``L`` in ``hlo``, and
+    the donated stacked k/v parameters of the entry computation alias
+    outputs.  At these widths the whole stack fits the chip's fast memory
+    (memory space ``S(1)``), and the compiler may stage it there with an
+    asynchronous copy in and out; that is exempt, as a deployment-sized
+    pool (gigabytes) never fits."""
     pool_shapes = {layer, (1,) + layer, (L,) + layer}
     shapes = {name: dims for name, dims, *_ in _hlo_instructions(hlo)}
     bad = []
@@ -222,8 +233,6 @@ def test_decode_step_updates_kv_pools_in_place_on_v5e(sds):
             bad.append(f"{op} {name} {dims}")
     assert not bad, bad
 
-    # the donated stacked k/v parameters of the entry computation alias
-    # outputs
     entry = hlo[hlo.index("\nENTRY "):]
     entry = entry[:entry.index("\n}")]
     params_at = [
@@ -237,3 +246,46 @@ def test_decode_step_updates_kv_pools_in_place_on_v5e(sds):
     header = hlo[:hlo.index("\n")]
     for p in params_at:
         assert re.search(rf"\({p}, \{{\}}, (may|must)-alias\)", header), p
+
+
+def test_decode_step_updates_kv_pools_in_place_on_v5e(sds):
+    """The decode step's layer scan carries the stacked KV pools and writes
+    each new token in place: the compiled program holds no copy, slice,
+    update-slice or allocation of one layer's pool or of the whole stack,
+    and the donated pools are the step's outputs."""
+    batch, ctx = 2, 2048
+    model, L = _small_model()
+    cache = _small_cache(sds, model, batch, ctx)
+    cache["_decode_pages"] = sds((L, batch), jnp.int32)
+    hlo = _compile_step(
+        sds, model, model.decode_step, cache, sds((batch,), jnp.int32)
+    )
+    assert "tpu_custom_call" in hlo
+    _assert_pools_in_place(hlo, (batch, NKV, ctx // PS, PS, D), L)
+
+
+@pytest.mark.parametrize("branch", ["sparse", "dense"])
+def test_prefill_chunk_updates_kv_pools_in_place_on_v5e(sds, branch):
+    """The prefill chunk's layer scan carries the stacked KV pools (and the
+    running scoring segment) and writes the chunk in place at the layer
+    and slot; the sparse prefill kernel reads the pools where they lie.
+    The compiled program holds no copy, slice, update-slice or allocation
+    of one layer's pool or of the whole stack, and the donated pools are
+    the step's outputs.  The dense branch (no sparse plan: the dense cache
+    and masked attention over one slot of one layer) is held to the same."""
+    batch, ctx = 2, 2048
+    sparse = branch == "sparse"
+    model, L = _small_model(enabled=sparse)
+    cache = _small_cache(sds, model, batch, ctx)
+    if sparse:
+        cache["_prefill_blocks"] = sds((L,), jnp.uint32)
+        layer = (batch, NKV, ctx // PS, PS, D)
+    else:
+        layer = (batch, NKV, ctx, D)
+    i32 = sds((), jnp.int32)
+    hlo = _compile_step(
+        sds, model, model.prefill_chunk, cache,
+        i32, sds((CHUNK,), jnp.int32), i32, i32,
+    )
+    assert ("tpu_custom_call" in hlo) == sparse
+    _assert_pools_in_place(hlo, layer, L)
